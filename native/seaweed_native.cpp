@@ -338,9 +338,9 @@ void sn_rs_apply_mt(const uint8_t* coeffs, int out_rows, int in_rows,
 // ---------------------------------------------------------------------------
 // Fused shard append + rolling block-CRC32C (the EC encoder's write stage).
 // One call per batch replaces, per shard, a Python tobytes() copy + a
-// buffered write + a bytes-slicing CRC loop — the 87%-of-wall host overhead
-// measured in BENCH_r03. Mirrors the reference's single-pass encode+CRC
-// loop (weed/storage/erasure_coding/ec_encoder.go:427-461).
+// buffered write + a bytes-slicing CRC loop — host overhead that used to
+// dominate the encode's wall time. Mirrors the reference's single-pass
+// encode+CRC loop (weed/storage/erasure_coding/ec_encoder.go:427-461).
 // ---------------------------------------------------------------------------
 
 static int write_full(int fd, const uint8_t* p, size_t len) {

@@ -19,9 +19,7 @@ Pieces
   device (`jax.device_put(…, device)`; jit follows the committed input,
   so every staged dispatch runs on that chip).
 - :class:`ChipPool` — one per mesh-capable backend, built lazily from
-  the mesh's own device list (never calls `jax.devices()` itself — a
-  mesh backend existing proves device init already succeeded, the
-  dead-relay hang rule from `get_backend`). Each chip's backend is
+  the mesh's own device list. Each chip's backend is
   constructed on first use; when the pooled backend is a
   FallbackBackend, every chip gets its OWN FallbackBackend + breaker,
   so one chip dying fails over only ITS streams to CPU while siblings
@@ -98,8 +96,7 @@ class ChipBackend(JaxBackend):
     SHARE one RSJax codec (`rs` — jit dispatch follows the committed
     input's device, and the coeff/bit-matrix caches are lock-protected
     since PR 4), so an 8-chip pool does not pay 8 identical bit-matrix
-    constructions, and no jax device probing happens here at all (the
-    dead-relay hang rule)."""
+    constructions."""
 
     def __init__(self, ctx, device, rs=None, impl: str = "xla",
                  interpret: bool = False):
@@ -297,8 +294,8 @@ _ledgers: dict = {}
 
 def pool_for(backend) -> ChipPool | None:
     """The chip pool behind `backend`, or None when it is not a
-    multi-device (column-mesh) backend. Safe on dead relays: devices
-    come from the backend's OWN mesh, never a fresh jax.devices()."""
+    multi-device (column-mesh) backend. Its chips are the devices of
+    the backend's own mesh."""
     if backend is None:
         return None
     primary = getattr(backend, "primary", backend)
@@ -382,7 +379,7 @@ def chip_load_hint(scope: QueueScope | None = None) -> dict[str, dict]:
     heartbeats for cluster-wide placement — /cluster/status,
     sw_ec_queue_load, `placement.NodeView.ec_load`). Reads only the
     scope's existing DeviceQueues — no queue is created and no jax/
-    device state is touched (dead-relay safe)."""
+    device state is touched."""
     return resolve_scope(scope).queue_loads()
 
 

@@ -22,6 +22,14 @@ import numpy as np
 
 BLOCK_AXIS = "blocks"
 
+# The per-chip body may be a Pallas kernel: pallas_call declares no
+# varying-axes type for its output, and in interpret mode its body mixes
+# the replicated bit-matrix with the column slice, both of which
+# shard_map's varying-axes check refuses. The bodies here are
+# column-local (no collectives, no autodiff), which is all that check
+# protects.
+_CHECK_VMA = False
+
 
 def make_mesh(n_devices: int | None = None, devices=None):
     """1-D mesh over local devices (default: all of them)."""
@@ -97,12 +105,8 @@ class MeshRS:
 
     def __init__(self, rs, mesh):
         import jax
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
-
-        try:
-            from jax import shard_map
-        except ImportError:  # pre-0.8 jax
-            from jax.experimental.shard_map import shard_map
 
         self.rs = rs
         self.mesh = mesh
@@ -158,6 +162,7 @@ class MeshRS:
                     mesh=mesh,
                     in_specs=P(None, BLOCK_AXIS),
                     out_specs=P(None, BLOCK_AXIS),
+                    check_vma=_CHECK_VMA,
                 )
             )
 
@@ -177,22 +182,14 @@ class MeshRS:
         """Sharded parity dispatch; returns a device array handle."""
         return self._encode(staged)
 
-    def apply(self, bits: np.ndarray, staged, m_out: int):
-        """General GF(256) apply over the column mesh: `bits` is the
-        expanded (8*m_out x 8k) bit-matrix, replicated on every chip
-        (like the parity matrix in encode), `staged` the column-sharded
-        data. Column-independent like encode, so the split is bit-exact
-        and no collectives appear. Returns a device handle (async)."""
+    def _apply_jit(self, m_out: int, k: int):
+        """The jitted shard_map apply for one (m_out, k) coefficient
+        shape, built once."""
         import jax
-        import jax.numpy as jnp
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
-        try:
-            from jax import shard_map
-        except ImportError:  # pre-0.8 jax
-            from jax.experimental.shard_map import shard_map
-
-        key = (int(m_out), int(staged.shape[0]))
+        key = (int(m_out), int(k))
         with self._apply_jits_lock:
             fn = self._apply_jits.get(key)
         if fn is None:
@@ -214,10 +211,22 @@ class MeshRS:
                     mesh=self.mesh,
                     in_specs=(P(), P(None, BLOCK_AXIS)),
                     out_specs=P(None, BLOCK_AXIS),
+                    check_vma=_CHECK_VMA,
                 )
             )
             with self._apply_jits_lock:
                 fn = self._apply_jits.setdefault(key, fn)
+        return fn
+
+    def apply(self, bits: np.ndarray, staged, m_out: int):
+        """General GF(256) apply over the column mesh: `bits` is the
+        expanded (8*m_out x 8k) bit-matrix, replicated on every chip
+        (like the parity matrix in encode), `staged` the column-sharded
+        data. Column-independent like encode, so the split is bit-exact
+        and no collectives appear. Returns a device handle (async)."""
+        import jax.numpy as jnp
+
+        fn = self._apply_jit(m_out, staged.shape[0])
         return fn(jnp.asarray(bits), staged)
 
     def global_checksum(self, sharded) -> int:
@@ -225,12 +234,8 @@ class MeshRS:
         integrity reduction (rides ICI, never moves shard bytes)."""
         import jax
         import jax.numpy as jnp
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
-
-        try:
-            from jax import shard_map
-        except ImportError:  # pre-0.8 jax
-            from jax.experimental.shard_map import shard_map
 
         def local_sum(x):
             return jax.lax.psum(jnp.sum(x.astype(jnp.uint32)), BLOCK_AXIS)

@@ -182,7 +182,7 @@ class RemoteS3Client:
             )
         except RetryError as e:
             # callers classify on RemoteStorageError — surface the last
-            # underlying failure in that taxonomy, not the retry wrapper
+            # underlying failure as that error type, not the retry wrapper
             cause = e.__cause__
             if isinstance(cause, RemoteStorageError):
                 raise cause from e

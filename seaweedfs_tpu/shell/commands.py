@@ -384,7 +384,9 @@ def ec_encode(env: ShellEnv, args) -> str:
     p = argparse.ArgumentParser(prog="ec.encode")
     p.add_argument("-volumeId", required=True, help="id or comma-separated ids")
     p.add_argument("-collection", default="")
-    p.add_argument("-backend", default="auto")
+    # empty = the holder's own -ec.backend, like ec.rebuild: the server
+    # knows which process owns the chip, the shell does not
+    p.add_argument("-backend", default="")
     p.add_argument("-keepSource", action="store_true")
     p.add_argument("-maxParallelization", type=int, default=4)
     a = p.parse_args(args)

@@ -135,8 +135,11 @@ def test_webhook_notifier(cluster):
     try:
         filer.write_file("/n/x.bin", b"notify me")
         filer.delete_entry("/n/x.bin")
-        deadline = time.time() + 5
-        while len(received) < 3 and time.time() < deadline:  # mkdir + create + delete
+        # mkdir + create + delete. `delivered` is bumped only after the
+        # hook's response returns, so wait on it, not on `received`; the
+        # deadline has to survive six busy test workers.
+        deadline = time.time() + 30
+        while notifier.delivered < 3 and time.time() < deadline:
             time.sleep(0.05)
         assert notifier.delivered >= 3
         creates = [e for e in received if e["newEntry"] and e["newEntry"]["name"] == "x.bin"]

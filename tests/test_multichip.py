@@ -16,12 +16,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:
-    from jax import shard_map
-except ImportError:  # pre-0.8 jax
-    from jax.experimental.shard_map import shard_map
 
 from seaweedfs_tpu.ops import gf256
 from seaweedfs_tpu.ops.rs_jax import RSJax, _apply_bits
@@ -117,7 +113,7 @@ def test_dryrun_multichip_entrypoint():
 
 
 def test_production_encoder_on_mesh_bit_exact(tmp_path):
-    """r3 verdict #10 done-criterion: the PRODUCTION encoder
+    """The PRODUCTION encoder
     (ec_encode_volume via JaxBackend) shards batch columns across the
     virtual 8-device mesh and produces a bit-identical .ecsum to the
     single-device CPU backend (shared impl with dryrun_multichip)."""
@@ -154,3 +150,22 @@ def test_parallel_pkg_mesh_helpers(mesh, rng):
     np.testing.assert_array_equal(parity, expected)
     cks = mrs.global_checksum(handle)
     assert cks == int(expected.astype(np.uint64).sum() % (1 << 32))
+
+
+@pytest.mark.parametrize("impl", ["pallas", "pallas_aligned"])
+def test_pallas_kernel_inside_the_column_mesh(impl, rng):
+    """What a multi-chip TPU host builds by itself: the Pallas kernel
+    wrapped in shard_map (here in interpret mode), encode and a 2-row
+    rebuild apply, through the backend's staged surface."""
+    from seaweedfs_tpu.ec.backend import CpuBackend, JaxBackend
+    from seaweedfs_tpu.ec.context import DEFAULT_EC_CONTEXT as ctx
+
+    be = JaxBackend(ctx, impl=impl, interpret=True, n_devices=4)
+    assert be._mesh_rs.n_devices == 4 and not be._mesh_rs.pod_sharded
+    cpu = CpuBackend(ctx)
+    data = rng.integers(0, 256, size=(10, 4 * 8192 + 77), dtype=np.uint8)
+    parity = be.to_host(be.encode_staged(be.to_device(data)))
+    np.testing.assert_array_equal(parity, cpu.encode(data))
+    coeffs = rng.integers(0, 256, size=(2, 10), dtype=np.uint8)
+    out = be.to_host(be.apply_staged(coeffs, be.to_device(data)))
+    np.testing.assert_array_equal(out, cpu.apply(coeffs, data))
