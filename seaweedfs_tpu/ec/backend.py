@@ -26,8 +26,37 @@ import numpy as np
 from .. import faults
 from ..ops import gf256
 from ..utils import metrics as _M
+from ..utils import trace
 from ..utils.glog import logger
 from .context import ECContext, ECError
+
+# XLA compilations as the program itself sees them: a request that meets
+# a new shape pays seconds for one, and without a count of its own a
+# server cannot say that it did, nor which request paid.
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_compiles_total = _M.REGISTRY.counter(
+    "sw_ec_compiles_total", "XLA compilations since the first device backend"
+)
+_compile_seconds_total = _M.REGISTRY.counter(
+    "sw_ec_compile_seconds_total", "seconds spent in XLA compilations"
+)
+
+
+def _on_jax_duration(event: str, seconds: float, **_kw) -> None:
+    if event == _COMPILE_EVENT:
+        _compiles_total.inc()
+        _compile_seconds_total.inc(seconds)
+        # jit compiles on the calling thread: its ambient span paid
+        trace.event(trace.current(), "compile", seconds=round(seconds, 4))
+
+
+@functools.lru_cache(maxsize=1)
+def _watch_compiles() -> None:
+    """Register the process's one compile listener (jax.monitoring keeps
+    listeners for the life of the process)."""
+    import jax
+
+    jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
 
 
 class RSBackend(Protocol):
@@ -176,6 +205,8 @@ class JaxBackend(_BackendBase):
     stage 2: pjit across chips for large volumes). Single-device
     behavior is unchanged."""
 
+    device = None  # where to_device puts: JAX's default; ChipBackend pins one
+
     def __init__(
         self,
         ctx: ECContext,
@@ -187,6 +218,7 @@ class JaxBackend(_BackendBase):
         from ..ops.rs_jax import RSJax
         from ..utils.devices import local_devices
 
+        _watch_compiles()
         info = local_devices()
         if impl == "auto":
             impl = "pallas" if info.platform == "tpu" else "xla"
@@ -212,24 +244,34 @@ class JaxBackend(_BackendBase):
     # N+1 while batch N computes (and N-1 drains to host) only requires
     # NOT forcing np.asarray between the stages. The encoder's bounded
     # queues provide the double-buffering window.
+    # The trace.lap / trace.count lines split the caller's h2d_dispatch
+    # and device_drain stages where the bytes cross (utils/trace.py);
+    # disarmed each is one module-bool check.
     def to_device(self, data: np.ndarray):
         import jax
 
+        trace.lap("stage")
         data = np.ascontiguousarray(data, dtype=np.uint8)
         if self._mesh_rs is not None:
             from ..parallel import pad_cols
 
-            padded, n = pad_cols(data, self._mesh_rs.n_devices)
-            return (self._mesh_rs.put(padded), n)
-        return jax.device_put(data)
+            data, n = pad_cols(data, self._mesh_rs.n_devices)
+        trace.count("h2d_bytes", data.nbytes)
+        trace.count("batches", 1)
+        trace.lap("put")
+        if self._mesh_rs is not None:
+            return (self._mesh_rs.put(data), n)
+        return jax.device_put(data, self.device)
 
     def encode_staged(self, staged):
+        trace.lap("launch")
         if self._mesh_rs is not None:
             arr, n = staged
             return (self._mesh_rs.encode(arr), n)
         return self._rs.encode(staged)
 
     def apply_staged(self, coeffs: np.ndarray, staged):
+        trace.lap("launch")
         coeffs = np.ascontiguousarray(coeffs, dtype=np.uint8)
         if self._mesh_rs is not None:
             arr, n = staged
@@ -247,18 +289,44 @@ class JaxBackend(_BackendBase):
         faults.fire(
             "ec.device.kernel_fetch", impl=getattr(self._rs, "impl", "")
         )
+        if trace.armed:
+            return self._to_host_split(result)
         if self._mesh_rs is not None:
             arr, n = result
             return np.asarray(arr, dtype=np.uint8)[:, :n]
         return np.asarray(result, dtype=np.uint8)
 
+    def _to_host_split(self, result) -> np.ndarray:
+        """`to_host` with the wait for the result (upload, kernel, the
+        device's queue) told apart from the copy back. Armed only: the
+        plain path is the one np.asarray, which waits and copies in one."""
+        arr, n = result if self._mesh_rs is not None else (result, None)
+        trace.lap("ready")
+        arr.block_until_ready()
+        trace.lap("d2h")
+        out = np.asarray(arr, dtype=np.uint8)
+        trace.count("d2h_bytes", out.nbytes)
+        return out if n is None else out[:, :n]
+
     def reconstruct(
         self, shards: dict[int, np.ndarray], want: list[int] | None = None
     ) -> dict[int, np.ndarray]:
+        # RSJax.reconstruct marks its own .stack and .launch
         out = self._rs.reconstruct(
             {i: np.asarray(s, np.uint8) for i, s in shards.items()}, want=want
         )
-        return {i: np.asarray(v) for i, v in out.items()}
+        if not trace.armed:
+            return {i: np.asarray(v) for i, v in out.items()}
+        import jax
+
+        trace.lap("ready")
+        jax.block_until_ready(out)
+        trace.lap("d2h")
+        host = {i: np.asarray(v) for i, v in out.items()}
+        trace.count("h2d_bytes", sum(len(s) for s in shards.values()))
+        trace.count("batches", 1)
+        trace.count("d2h_bytes", sum(v.nbytes for v in host.values()))
+        return host
 
     def apply(self, coeffs: np.ndarray, data: np.ndarray) -> np.ndarray:
         return np.asarray(self._rs.apply(coeffs, np.asarray(data, np.uint8)))
@@ -399,6 +467,7 @@ class FallbackBackend(_BackendBase):
     # bit-identical to what the device would have produced.
 
     def to_device(self, data: np.ndarray):
+        trace.lap("stage")  # the host copy, where there is one
         data = np.ascontiguousarray(data, dtype=np.uint8)
         if self.breaker.allows():
             try:

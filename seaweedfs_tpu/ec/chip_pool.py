@@ -86,8 +86,8 @@ _placement_decisions = _M.REGISTRY.counter(
 class ChipBackend(JaxBackend):
     """Single-device JaxBackend pinned to one local device.
 
-    Staged H2D goes through `jax.device_put(data, device)`; computation
-    follows the committed input, so encode_staged/apply_staged run on
+    Staged H2D (the inherited `to_device`) puts to `self.device`;
+    computation follows the committed input, so encode_staged/apply_staged run on
     exactly this chip. The synchronous surface (encode/apply without
     staging) is only used by CPU fallback replays and inherits the
     default-device behavior — streams always take the staged path.
@@ -114,13 +114,6 @@ class ChipBackend(JaxBackend):
         self._mesh_rs = None  # this backend IS one chip
         self.device = device
         self.chip_label = f"{device.platform}:{device.id}"
-
-    def to_device(self, data: np.ndarray):
-        import jax
-
-        return jax.device_put(
-            np.ascontiguousarray(data, dtype=np.uint8), self.device
-        )
 
 
 class _PodLedger:

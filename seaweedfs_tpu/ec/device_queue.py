@@ -126,6 +126,7 @@ from collections import deque
 
 from .. import faults
 from ..utils import metrics as _M
+from ..utils import trace
 from .context import ECError
 
 # Highest priority first; admission prefers earlier classes.
@@ -358,26 +359,20 @@ class DeviceStream:
         (device refused the dispatch; FallbackBackend turns that into a
         CPU handle instead, so this is the raw-backend path), the slot
         is released before the exception propagates."""
-        ticket = self.queue._admit(self.priority, cost)
         span = self.span
-        if span is not None:
-            span.add_stage(
-                "admission_wait", ticket.wait_s, self.queue.label
-            )
+        # charged the queue's own wait_s (its clock, residency wait
+        # included); the with-block gives the stage its interval
+        with trace.stage(span, "admission_wait", self.queue.label) as timer:
+            ticket = self.queue._admit(self.priority, cost)
+            timer.seconds = ticket.wait_s
         with self._lock:
             self._outstanding.add(ticket)
         ok = False
-        t0 = time.perf_counter() if span is not None else 0.0
         try:
-            handle = fn()
+            with trace.stage(span, "h2d_dispatch", self.queue.label):
+                handle = fn()
             ok = True
         finally:
-            if span is not None:
-                span.add_stage(
-                    "h2d_dispatch",
-                    time.perf_counter() - t0,
-                    self.queue.label,
-                )
             if not ok:
                 self.release(ticket)
         return ticket, handle
@@ -489,9 +484,9 @@ class DeviceQueue:
             raise ECError(
                 f"unknown priority class {priority!r} (want one of {PRIORITIES})"
             )
-        ticket = self._admit(priority, cost)
-        if span is not None:
-            span.add_stage("admission_wait", ticket.wait_s, self.label)
+        with trace.stage(span, "admission_wait", self.label) as timer:
+            ticket = self._admit(priority, cost)
+            timer.seconds = ticket.wait_s
         try:
             yield ticket
         finally:

@@ -21,7 +21,6 @@ from __future__ import annotations
 
 import queue as _queue
 import threading as _threading
-import time as _time
 from typing import Callable, Iterator, Sequence
 
 import numpy as np
@@ -42,12 +41,12 @@ def _traced_produce(span, stage: str, produce):
     def wrapped():
         it = produce()
         while True:
-            t0 = _time.perf_counter()
-            try:
-                item = next(it)
-            except StopIteration:
-                return
-            span.add_stage(stage, _time.perf_counter() - t0)
+            with span.stage(stage) as timer:
+                try:
+                    item = next(it)
+                except StopIteration:
+                    timer.drop()  # the producer's exit read nothing
+                    return
             yield item
 
     return wrapped
@@ -55,11 +54,8 @@ def _traced_produce(span, stage: str, produce):
 
 def _traced_call(span, stage: str, fn):
     def wrapped(item):
-        t0 = _time.perf_counter()
-        try:
+        with span.stage(stage):
             return fn(item)
-        finally:
-            span.add_stage(stage, _time.perf_counter() - t0)
 
     return wrapped
 
@@ -119,8 +115,7 @@ def run_pipeline(
             return True
         except _queue.Full:
             pass
-        t0 = _time.perf_counter() if span is not None else 0.0
-        try:
+        with trace.stage(span, "queue_wait"):
             while True:
                 try:
                     q.put(item, timeout=0.2)
@@ -128,11 +123,6 @@ def run_pipeline(
                 except _queue.Full:
                     if abort.is_set():
                         return False
-        finally:
-            if span is not None:
-                span.add_stage(
-                    "queue_wait", _time.perf_counter() - t0
-                )
 
     def reader():
         try:
@@ -160,8 +150,9 @@ def run_pipeline(
             while write_q.get() is not None:
                 pass
 
-    rt = _threading.Thread(target=reader, daemon=True)
-    wt = _threading.Thread(target=writer, daemon=True)
+    # named: the flight recorder's timeline has a row per thread
+    rt = _threading.Thread(target=reader, daemon=True, name="ec-pipe-reader")
+    wt = _threading.Thread(target=writer, daemon=True, name="ec-pipe-sink")
     rt.start()
     wt.start()
     try:
@@ -204,6 +195,14 @@ def run_pipeline(
             ) from (errors[0] if errors else None)
     if errors:
         raise errors[0]
+
+
+def _contiguous(out) -> np.ndarray:
+    """The drained batch as the sink needs it; `device_drain.host_copy`
+    says what that cost where it copies (a mesh result sliced back to
+    its width)."""
+    trace.lap("host_copy")
+    return np.ascontiguousarray(out, dtype=np.uint8)
 
 
 def run_staged_apply(
@@ -306,9 +305,7 @@ def run_staged_apply(
             # the calling thread keeps dispatching the batches queued
             # behind it.
             with trace.stage(span, "device_drain", chip):
-                out = np.ascontiguousarray(
-                    backend.to_host(handle), dtype=np.uint8
-                )
+                out = _contiguous(backend.to_host(handle))
             with trace.stage(span, write_stage):
                 consume(tag, out)
 
@@ -350,9 +347,7 @@ def run_staged_apply(
         tag, ticket, handle = item
         try:
             with trace.stage(span, "device_drain", device_queue.label):
-                out = np.ascontiguousarray(
-                    backend.to_host(handle), dtype=np.uint8
-                )
+                out = _contiguous(backend.to_host(handle))
         finally:
             # Success or failure, the window slot frees — a dying stream
             # must not wedge the chip for the other streams.

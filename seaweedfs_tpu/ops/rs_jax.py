@@ -34,7 +34,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..utils import trace
 from . import gf256
+
+# The one name of the Reed-Solomon apply on the device, whichever
+# implementation runs: the Pallas kernel carries it (a profiler trace
+# lists `sw_rs_apply[.N]` among the device operations), and the jitted
+# glue around it (pad, pack, slice; the XLA path whole) lies under a
+# named scope of the same name.
+KERNEL_NAME = "sw_rs_apply"
 
 # Accumulator dtype: int32 matmuls hit the MXU int8 path on v5e+; f32 is
 # the safe fallback everywhere (values <= 2048 are exact in f32).
@@ -63,6 +71,7 @@ def bit_matrix_bitmajor(coeffs: np.ndarray) -> np.ndarray:
 
 
 @functools.partial(jax.jit, static_argnames=())
+@jax.named_scope(KERNEL_NAME)
 def _apply_bits(b: jax.Array, data: jax.Array) -> jax.Array:
     """b: (8m, 8k) f32; data: (k, n) uint8 -> (m, n) uint8."""
     k = data.shape[0]
@@ -79,6 +88,7 @@ def _apply_bits(b: jax.Array, data: jax.Array) -> jax.Array:
 
 
 @functools.partial(jax.jit, donate_argnums=())
+@jax.named_scope(KERNEL_NAME)
 def _apply_bits_bitmajor(b: jax.Array, data: jax.Array) -> jax.Array:
     """Same contract as _apply_bits but with bit-major b (see above)."""
     k = data.shape[0]
@@ -235,8 +245,11 @@ class RSJax:
         if not missing:
             return {}
         src = present[: self.k]
-        bits = self._rows_bits(missing, src)
+        # the parts of the caller's `reconstruct` stage (utils/trace.py)
+        trace.lap("stack")
         data = jnp.stack([jnp.asarray(shards[i], dtype=jnp.uint8) for i in src])
+        trace.lap("launch")
+        bits = self._rows_bits(missing, src)
         out = self._apply(bits, data, len(missing))
         return {idx: out[i] for i, idx in enumerate(missing)}
 

@@ -42,6 +42,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu  # noqa: F401  (memory spaces)
 
 from . import gf256
+from .rs_jax import KERNEL_NAME
 
 # Default word-column tile. Measured sweet spot on v5e for the pw=1
 # int8 single-dot kernel (8192 beat 16384 by ~25%); VMEM use is
@@ -100,6 +101,7 @@ def _rs_kernel(k: int, m: int, pack_width: int, b_ref, d_ref, out_ref):
     out_ref[:] = out.astype(_WORD_DTYPES[pack_width])
 
 
+@jax.named_scope(KERNEL_NAME)
 def _pallas_apply(
     kernel,
     b,
@@ -149,6 +151,7 @@ def _pallas_apply(
         ],
         out_specs=pl.BlockSpec((out_rows, tile_n), lambda i: (0, i)),
         interpret=interpret,
+        name=KERNEL_NAME,
         cost_estimate=pl.CostEstimate(
             flops=2 * 8 * out_rows * 8 * k * words.shape[1],
             bytes_accessed=(k + out_rows) * n_padded + 64 * out_rows * k * 4,

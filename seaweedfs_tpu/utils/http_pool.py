@@ -40,6 +40,8 @@ import threading
 import time
 from http.server import HTTPServer
 
+from . import trace
+
 # How many back-to-back requests one dispatch may serve before the
 # connection is re-queued behind other ready work — bounds how long a
 # pipelining client can monopolize a worker.
@@ -60,12 +62,15 @@ class _Conn:
     instance (rfile/wfile survive across requests — keep-alive), and
     its idle bookkeeping."""
 
-    __slots__ = ("sock", "handler", "last_active")
+    __slots__ = ("sock", "handler", "last_active", "queued_ns")
 
     def __init__(self, sock, handler):
         self.sock = sock
         self.handler = handler
         self.last_active = time.monotonic()
+        # perf_counter_ns when the connection was put on the ready
+        # queue (tracer armed only, else 0): the `ready_wait` stage
+        self.queued_ns = 0
 
 
 def _deferred_handler(cls, request_timeout: float):
@@ -186,7 +191,7 @@ class PooledHTTPServer(HTTPServer):
                         sel.unregister(key.fileobj)
                         conn = key.data
                         conn.last_active = time.monotonic()
-                        self._ready.put(conn)
+                        self._enqueue_ready(conn)
                 now = time.monotonic()
                 if now - last_sweep >= _IDLE_SWEEP_INTERVAL:
                     last_sweep = now
@@ -293,6 +298,12 @@ class PooledHTTPServer(HTTPServer):
 
     # ----------------------------------------------------------- dispatch
 
+    def _enqueue_ready(self, conn: _Conn) -> None:
+        """The one queue in front of the workers."""
+        if trace.armed:
+            conn.queued_ns = time.perf_counter_ns()
+        self._ready.put(conn)
+
     def _worker(self) -> None:
         while True:
             conn = self._ready.get()
@@ -312,6 +323,15 @@ class PooledHTTPServer(HTTPServer):
         h = conn.handler
         for _ in range(_MAX_REQUESTS_PER_DISPATCH):
             metrics.gateway_inflight.inc(server=self.server_kind)
+            if trace.armed:
+                # the request's bytes are here (readiness dispatched
+                # us): its root span, made once the headers are parsed,
+                # starts now, after `queued_ns` of waiting for a worker
+                h._sw_begun = (
+                    conn.queued_ns, time.perf_counter_ns(),
+                    time.thread_time_ns(),
+                )
+                conn.queued_ns = 0
             try:
                 h.handle_one_request()
                 with self._conns_lock:  # += is not atomic across workers
@@ -331,7 +351,7 @@ class PooledHTTPServer(HTTPServer):
         # fairness: a pipelining client with more buffered requests goes
         # to the back of the ready queue instead of monopolizing this
         # worker
-        self._ready.put(conn)
+        self._enqueue_ready(conn)
 
     def _readable_now(self, conn: _Conn) -> bool:
         """True when the connection's NEXT request is already here —
@@ -509,6 +529,11 @@ def send_body(handler, *parts) -> int:
     total = sum(len(p) for p in parts)
     if handler.command == "HEAD" or total == 0:
         return 0
+    with trace.stage(getattr(handler, "_sw_span", None), "send"):
+        return _send_parts(handler, parts, total)
+
+
+def _send_parts(handler, parts: list, total: int) -> int:
     from . import metrics
 
     srv = getattr(handler, "server", None)

@@ -62,8 +62,11 @@ class RequestTracingMixin:
 
     Per-request tracing rides the same hooks: ``parse_request`` opens
     (or adopts, via the ``X-Sw-*`` headers) a root span and installs it
-    as the thread's ambient span; ``handle_one_request`` finishes it
-    after the response and records the request into the
+    as the thread's ambient span (under the pooled front end,
+    utils/http_pool.py, the span starts where the worker took the
+    request up and carries ``ready_wait`` and ``parse`` stages);
+    ``handle_one_request`` finishes it after the response and records
+    the request into the
     ``sw_request_seconds{server,op}`` SLO histogram. Subclasses set
     ``trace_server_kind`` ("s3", "filer", "volume", "master",
     "webdav") and may refine the op class per request by assigning
@@ -91,6 +94,19 @@ class RequestTracingMixin:
                 )
                 self._sw_span = sp
                 self._sw_token = trace.set_current(sp)
+                begun = self.__dict__.pop("_sw_begun", None)
+                if sp is not None and begun is not None:
+                    # the pooled front end read the clocks before the
+                    # request line was: the span starts there, after
+                    # whatever the connection waited for a worker
+                    queued_ns, t0_ns, cpu0_ns = begun
+                    sp.backdate(t0_ns, cpu0_ns)
+                    if queued_ns:
+                        sp.add_interval("ready_wait", queued_ns, t0_ns)
+                    sp.add_interval(
+                        "parse", t0_ns, time.perf_counter_ns(),
+                        time.thread_time_ns() - cpu0_ns,
+                    )
         return ok
 
     def send_response(self, code, message=None):  # type: ignore[override]

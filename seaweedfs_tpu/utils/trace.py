@@ -15,10 +15,29 @@ Model
   ``ec.rebuild``, ``ec.decode``, ``ec.degraded_read``,
   ``ec.peer_rebuild``, ``rpc.ec_shard_read`` …), children for sub-ops
   (per-peer fetches, the nested rebuild inside a decode). Spans carry
-  per-stage ACCUMULATORS (total seconds + count per stage name) rather
-  than one child span per pipeline batch — a 1 GiB encode is thousands
-  of batches, and the interesting question is "where did the op's time
-  go", not "what did batch #3817 do".
+  per-stage ACCUMULATORS (total seconds + count + CPU seconds per stage
+  name) rather than one child span per pipeline batch.
+- Beside the accumulators every stage entry leaves an INTERVAL
+  ``(stage, start_ns, end_ns, thread, cpu_ns)`` on the span's own
+  monotonic clock (``time.perf_counter_ns``), at most
+  :data:`MAX_INTERVALS` per span (past the cap only the accumulators
+  grow and ``stages_dropped`` counts). Intervals say which thread sets
+  the pace where stages overlap: the reader's ``disk_read`` beside the
+  sink's ``device_drain``. An after-the-fact ``add_stage(name, secs)``
+  leaves ``(now - secs, now)`` with no CPU reading.
+- Spans and ``with``-scoped stages read ``time.thread_time_ns`` at both
+  ends and export ``cpu_s``: wall less CPU is time the thread did not
+  run (blocked on a read, the device, a lock, or waiting for the GIL).
+- Spans and ``with``-scoped stages also open a
+  ``jax.profiler.TraceAnnotation`` (``sw:<op>`` / ``sw:<op>/<stage>``,
+  the trace id as an argument) where JAX is already loaded, so a
+  profiler trace taken by anyone holds the program's stages on the
+  device operations' clock. JAX is never imported for this.
+- A SUB-STAGE ``<stage>.<part>`` (:data:`SUB_STAGES`) splits its parent
+  from inside: :func:`lap` begins one under whatever stage the calling
+  thread has open and ends the one before it, so the backend marks its
+  parts without being handed a span, and the parts of one entry lie end
+  to end. Every total that sums stages counts parents only.
 - Completed LOCAL ROOTS (spans with no local parent — including spans
   whose parent lives on another server) land in a bounded ring,
   dumpable as Chrome ``trace_event`` JSON (``/debug/traces``,
@@ -49,7 +68,20 @@ Canonical stage names (the Prometheus ``stage`` label of
 ``reconstruct``    synchronous (non-staged) Reed-Solomon apply
 ``fsync_publish``  flush/fsync/rename publication windows
 ``stream``         server-side RPC response streaming
+``ready_wait``     HTTP: a readable connection queued for a pool worker
+                   (ends where its span starts)
+``parse``          HTTP: request line and headers
+``send``           HTTP: the response body leaving (``send_body``)
 =================  =====================================================
+
+Sub-stages: ``h2d_dispatch`` = ``.stage`` (contiguous host copy /
+column padding) + ``.put`` (``jax.device_put``) + ``.launch`` (the
+jitted apply: coefficient bits, cache look-up, enqueue);
+``device_drain`` = ``.ready`` (blocked until the result exists on the
+device: upload, kernel, device queue) + ``.d2h`` (the copy into a numpy
+array) + ``.host_copy`` (``np.ascontiguousarray`` where it copies);
+``reconstruct`` (single-shot degraded read) = ``.stack`` + ``.launch``
++ ``.ready`` + ``.d2h``.
 
 Overlap efficiency
 ------------------
@@ -73,14 +105,15 @@ staged pipeline actually overlaps.
 
 Disarm discipline (same as ``faults/``): the tracer is OFF by default
 and every production call site is a single module-bool (or is-None)
-check when disarmed — no allocation, no lock, no contextvar read. Hot
-per-batch helpers (:func:`stage`, :func:`add_stage`, :func:`current`)
-take only positional arguments so the disarmed path cannot even box a
-kwargs dict.
+check when disarmed — no allocation, no lock, no contextvar read, no
+clock read. Hot per-batch helpers (:func:`stage`, :func:`lap`,
+:func:`count`, :func:`add_stage`, :func:`current`) take only positional
+arguments so the disarmed path cannot even box a kwargs dict.
 """
 
 from __future__ import annotations
 
+import sys
 import threading
 import time
 import uuid
@@ -128,7 +161,27 @@ STAGES = frozenset({
     "parity_update",
     # gateway read path (PR 9): where a slow S3 GET burned its budget
     "s3.auth", "filer.lookup", "chunk.fetch", "volume.read",
+    # HTTP front end (utils/http_pool.py, utils/request_id.py)
+    "ready_wait", "parse", "send",
 })
+
+# parent stage -> its parts; a sub-stage lies inside its parent's
+# interval, so every sum over stages skips SUB_STAGES
+_SUB_PARTS = {
+    "h2d_dispatch": ("stage", "put", "launch"),
+    "device_drain": ("ready", "d2h", "host_copy"),
+    "reconstruct": ("stack", "launch", "ready", "d2h"),
+}
+_SUB_NAME = {
+    (parent, part): f"{parent}.{part}"
+    for parent, parts in _SUB_PARTS.items() for part in parts
+}
+SUB_STAGES = frozenset(_SUB_NAME.values())
+STAGES = STAGES | SUB_STAGES
+
+# Intervals kept per span; a (10, 16 MiB)-batch rebuild of 1 GiB leaves
+# about a hundred. 5 ints and a name each: some 100 KiB a span at most.
+MAX_INTERVALS = 1024
 
 # Stages that count as device time for the overlap-efficiency gauge.
 DEVICE_STAGES = frozenset({"h2d_dispatch", "device_drain"})
@@ -144,13 +197,25 @@ _overlap_eff = _M.REGISTRY.gauge(
     "(latest completed trace)",
     ("op",),
 )
-_traces_total = _M.REGISTRY.counter(
-    "sw_ec_traces_total", "completed root spans by op class", ("op",)
-)
-_slow_ops_total = _M.REGISTRY.counter(
-    "sw_ec_slow_ops_total", "root spans exceeding the slow-op threshold",
-    ("op",),
-)
+# what crossed the host/device seam, counted where it crosses
+# (ec/backend.py) under whatever stage is open: see count()
+_seam_counters = {
+    "h2d_bytes": _M.REGISTRY.counter(
+        "sw_ec_h2d_bytes_total",
+        "bytes put to the device by EC operations (tracer armed only)",
+        ("op",),
+    ),
+    "d2h_bytes": _M.REGISTRY.counter(
+        "sw_ec_d2h_bytes_total",
+        "bytes fetched from the device by EC operations (tracer armed only)",
+        ("op",),
+    ),
+    "batches": _M.REGISTRY.counter(
+        "sw_ec_device_batches_total",
+        "batches EC operations handed to the device (tracer armed only)",
+        ("op",),
+    ),
+}
 
 # Module-level fast-path flag, read unlocked by every instrumentation
 # site. configure() flips it under _lock AFTER the ring/threshold are in
@@ -174,6 +239,32 @@ _ewma_lock = threading.Lock()
 _stage_ewma: dict[tuple[str, str], float] = {}
 
 _current: ContextVar["Span | None"] = ContextVar("sw_trace_span", default=None)
+# the with-scoped stage this thread has open: the parent of lap()'s parts
+_open_stage: ContextVar["_StageTimer | None"] = ContextVar(
+    "sw_trace_stage", default=None
+)
+
+_trace_annotation = None  # jax.profiler.TraceAnnotation, once JAX is loaded
+
+
+def _annotate(op: str, stage: str, trace_id: str):
+    """An entered profiler annotation ``sw:<op>[/<stage>]``, or None
+    where JAX is not loaded: this module never imports it."""
+    global _trace_annotation
+    cls = _trace_annotation
+    if cls is None:
+        prof = getattr(sys.modules.get("jax"), "profiler", None)
+        cls = _trace_annotation = getattr(prof, "TraceAnnotation", None)
+        if cls is None:
+            return None
+    # A stable name, no ids in it: one name an op class and stage. The
+    # id rides as an argument behind a letter: the profile's readers
+    # take a bare "7144024763e54529" for a number, and show infinity.
+    ann = cls(
+        f"sw:{op}/{stage}" if stage else f"sw:{op}", trace_id=f"t{trace_id}"
+    )
+    ann.__enter__()
+    return ann
 
 
 class _Noop:
@@ -189,26 +280,76 @@ class _Noop:
     def __exit__(self, *exc):
         return False
 
+    # a stage timer's two knobs, accepted and ignored
+    seconds = property(lambda self: None, lambda self, value: None)
+
+    def drop(self) -> None:
+        pass
+
 
 _NOOP = _Noop()
 
 
 class _StageTimer:
-    __slots__ = ("span", "name", "chip", "t0")
+    """One with-scoped stage entry: wall and CPU clocks, a profiler
+    annotation, and the thread's open stage for :func:`lap`. `seconds`
+    overrides what the accumulator is charged (the interval stays the
+    with-block's): ``admission_wait`` charges the queue's own
+    ``ticket.wait_s``. :meth:`drop` records nothing."""
+
+    __slots__ = (
+        "span", "name", "chip", "seconds", "_dropped", "_t0", "_c0",
+        "_ann", "_token", "_part", "_part_t0", "_part_c0", "_part_ann",
+    )
 
     def __init__(self, span: "Span", name: str, chip: str):
         self.span = span
         self.name = name
         self.chip = chip
+        self.seconds = None
+        self._dropped = False
+        self._part = None
+
+    def drop(self) -> None:
+        self._dropped = True
 
     def __enter__(self):
-        self.t0 = time.perf_counter()
+        self._token = _open_stage.set(self)
+        self._ann = _annotate(self.span.op, self.name, self.span.trace_id)
+        self._c0 = time.thread_time_ns()
+        self._t0 = time.perf_counter_ns()
         return self
 
+    def _lap(self, part: "str | None", now_ns: int, cpu_ns: int) -> None:
+        """Close the part that is open, at these clock readings, and
+        open `part` (None: none) at the same ones: the parts of one
+        entry lie end to end."""
+        if self._part is not None:
+            if self._part_ann is not None:
+                self._part_ann.__exit__(None, None, None)
+            self.span._record(
+                self._part, self._part_t0, now_ns, cpu_ns - self._part_c0,
+                self.chip,
+            )
+        self._part = part
+        if part is not None:
+            self._part_t0 = now_ns
+            self._part_c0 = cpu_ns
+            self._part_ann = _annotate(self.span.op, part, self.span.trace_id)
+
     def __exit__(self, *exc):
-        self.span.add_stage(
-            self.name, time.perf_counter() - self.t0, self.chip
-        )
+        t1 = time.perf_counter_ns()
+        cpu1 = time.thread_time_ns()
+        if self._part is not None:
+            self._lap(None, t1, cpu1)
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        _open_stage.reset(self._token)
+        if not self._dropped:
+            self.span._record(
+                self.name, self._t0, t1, cpu1 - self._c0, self.chip,
+                self.seconds,
+            )
         return False
 
 
@@ -238,9 +379,10 @@ class Span:
 
     __slots__ = (
         "trace_id", "span_id", "parent_id", "op", "name", "server",
-        "request_id", "start_ts", "_t0", "duration_s", "attrs",
-        "stages", "events", "children", "_lock", "_local_root",
-        "_finished",
+        "request_id", "start_ts", "start_ns", "end_ns", "duration_s",
+        "cpu_s", "thread", "attrs", "stages", "intervals",
+        "stages_dropped", "events", "children", "_lock", "_local_root",
+        "_finished", "_c0", "_ident", "_ann",
     )
 
     def __init__(
@@ -261,17 +403,31 @@ class Span:
         self.server = server
         self.request_id = _rid.get()
         self.start_ts = time.time()
-        self._t0 = time.perf_counter()
+        # the span's own clock: every interval below is on it, and
+        # start_ts says where it lies in wall time
+        self.start_ns = time.perf_counter_ns()
+        self.end_ns = 0
         self.duration_s = 0.0
+        # CPU seconds of the owning thread between start and finish
+        # (None until then, and where another thread finished the span)
+        self.cpu_s: float | None = None
+        self.thread = threading.current_thread().name
+        self._ident = threading.get_ident()
+        self._c0 = time.thread_time_ns()
         self.attrs = dict(attrs) if attrs else {}
-        # stage -> [total_seconds, count, chip] (chip: last writer wins
-        # — one stream runs on one chip; a mesh stream reports "")
+        # stage -> [total_seconds, count, chip, cpu_ns] (chip: last
+        # writer wins — one stream runs on one chip; a mesh stream
+        # reports "")
         self.stages: dict[str, list] = {}
+        # (stage, start_ns, end_ns, thread, cpu_ns); cpu_ns -1 = not read
+        self.intervals: list[tuple] = []
+        self.stages_dropped = 0
         self.events: list[dict] = []
         self.children: list["Span"] = []
         self._lock = threading.Lock()
         self._local_root = local_root
         self._finished = False
+        self._ann = _annotate(op, "", self.trace_id)
 
     # -------------------------------------------------------- recording
 
@@ -289,18 +445,39 @@ class Span:
             self.children.append(c)
         return c
 
-    def add_stage(self, stage: str, seconds: float, chip: str = "") -> None:
+    def backdate(self, start_ns: int, cpu_ns: int) -> None:
+        """Set the start back to clock readings the owning thread took
+        before the span could be made (an HTTP root is made once the
+        trace headers are parsed; its request began before that)."""
+        self.start_ts -= (self.start_ns - start_ns) / 1e9
+        self.start_ns = start_ns
+        self._c0 = cpu_ns
+
+    def _record(
+        self, stage: str, t0_ns: int, t1_ns: int, cpu_ns: int,
+        chip: str = "", seconds: float | None = None,
+    ) -> None:
+        if seconds is None:
+            seconds = (t1_ns - t0_ns) / 1e9
         if seconds < 0.0:
             seconds = 0.0
+        thread = threading.current_thread().name
         with self._lock:
             acc = self.stages.get(stage)
             if acc is None:
-                self.stages[stage] = [seconds, 1, chip]
+                self.stages[stage] = [seconds, 1, chip, max(cpu_ns, 0)]
             else:
                 acc[0] += seconds
                 acc[1] += 1
                 if chip:
                     acc[2] = chip
+                acc[3] += max(cpu_ns, 0)
+            if len(self.intervals) < MAX_INTERVALS:
+                self.intervals.append((stage, t0_ns, t1_ns, thread, cpu_ns))
+            else:
+                self.stages_dropped += 1
+        if stage in SUB_STAGES:
+            return  # inside its parent: the histogram's sum must not double
         _stage_seconds.observe(seconds, op=self.op, stage=stage, chip=chip)
         with _ewma_lock:
             key = (self.op, stage)
@@ -311,6 +488,20 @@ class Span:
                 else prev + EWMA_ALPHA * (seconds - prev)
             )
 
+    def add_stage(self, stage: str, seconds: float, chip: str = "") -> None:
+        """A stage timed after the fact: it ended now."""
+        now = time.perf_counter_ns()
+        self._record(
+            stage, now - int(max(seconds, 0.0) * 1e9), now, -1, chip, seconds
+        )
+
+    def add_interval(
+        self, stage: str, t0_ns: int, t1_ns: int, cpu_ns: int = -1
+    ) -> None:
+        """A stage whose two ends were read (``time.perf_counter_ns``)
+        where no timer could be open: before the span was made."""
+        self._record(stage, t0_ns, t1_ns, cpu_ns)
+
     def stage(self, name: str, chip: str = "") -> _StageTimer:
         return _StageTimer(self, name, chip)
 
@@ -320,6 +511,10 @@ class Span:
                 {"ts": time.time(), "name": name, "attrs": attrs}
             )
 
+    def count(self, name: str, n: int) -> None:
+        with self._lock:
+            self.attrs[name] = self.attrs.get(name, 0) + n
+
     # --------------------------------------------------------- lifecycle
 
     def finish(self) -> None:
@@ -327,7 +522,12 @@ class Span:
             if self._finished:
                 return
             self._finished = True
-            self.duration_s = time.perf_counter() - self._t0
+            self.end_ns = time.perf_counter_ns()
+            self.duration_s = (self.end_ns - self.start_ns) / 1e9
+            if threading.get_ident() == self._ident:
+                self.cpu_s = (time.thread_time_ns() - self._c0) / 1e9
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
         if self._local_root:
             _complete_root(self)
 
@@ -335,11 +535,7 @@ class Span:
 
     def to_dict(self) -> dict:
         with self._lock:
-            dur = (
-                self.duration_s
-                if self._finished
-                else time.perf_counter() - self._t0
-            )
+            end_ns = self.end_ns if self._finished else time.perf_counter_ns()
             return {
                 "trace_id": self.trace_id,
                 "span_id": self.span_id,
@@ -349,12 +545,21 @@ class Span:
                 "server": self.server,
                 "request_id": self.request_id,
                 "start_ts": self.start_ts,
-                "duration_s": dur,
+                "start_ns": self.start_ns,
+                "end_ns": end_ns,
+                "duration_s": (end_ns - self.start_ns) / 1e9,
+                "cpu_s": self.cpu_s,
+                "thread": self.thread,
                 "attrs": dict(self.attrs),
                 "stages": {
-                    s: {"seconds": a[0], "count": a[1], "chip": a[2]}
+                    s: {
+                        "seconds": a[0], "count": a[1], "chip": a[2],
+                        "cpu_s": a[3] / 1e9,
+                    }
                     for s, a in self.stages.items()
                 },
+                "intervals": [list(iv) for iv in self.intervals],
+                "stages_dropped": self.stages_dropped,
                 "events": [dict(e) for e in self.events],
                 "children": [c.to_dict() for c in self.children],
             }
@@ -371,7 +576,8 @@ def _tree_stage_totals(doc: dict) -> dict[str, float]:
     while stack:
         d = stack.pop()
         for s, a in d["stages"].items():
-            totals[s] = totals.get(s, 0.0) + a["seconds"]
+            if s not in SUB_STAGES:  # already inside its parent's seconds
+                totals[s] = totals.get(s, 0.0) + a["seconds"]
         stack.extend(d["children"])
     return totals
 
@@ -413,7 +619,6 @@ def _doc_span_count(doc: dict) -> int:
 def _complete_root(span: Span) -> None:
     global _ring_spans
     doc = span.to_dict()
-    _traces_total.inc(op=span.op)
     eff = overlap_efficiency(doc)
     if eff is not None:
         doc["overlap_efficiency"] = round(eff, 4)
@@ -443,7 +648,6 @@ def _complete_root(span: Span) -> None:
             _ring_spans -= _ring.popleft().get("span_count", 1)
         slow = _slow_op_s
     if 0.0 < slow <= doc["duration_s"]:
-        _slow_ops_total.inc(op=span.op)
         _log.warning(
             "slow op %s (%.3fs > %.3fs) request_id=%s trace=%s\n%s",
             span.op, doc["duration_s"], slow,
@@ -466,6 +670,8 @@ def format_tree(doc: dict, indent: int = 0) -> str:
         f"{' [' + doc['name'] + ']' if doc['name'] != doc['op'] else ''}"
         f" {doc['duration_s'] * 1000:.1f}ms"
     )
+    if doc.get("cpu_s") is not None:
+        line += f" cpu={doc['cpu_s'] * 1000:.1f}ms"
     if indent == 0:
         line += (
             f" root={doc['op']}"
@@ -650,6 +856,39 @@ def stage(span, name: str, chip: str = ""):
     return _StageTimer(span, name, chip)
 
 
+def lap(part: str) -> None:
+    """Begin sub-stage ``<stage>.<part>`` of the stage the calling
+    thread has open, ending the part before it: ``trace.lap("put")``
+    inside the backend splits the pipeline's ``h2d_dispatch`` without
+    the backend being handed a span. Parts lie end to end (the last
+    ends with its stage), so they add up to their parent but for what
+    ran before the first. Nothing when disarmed (one module-bool
+    check), when no stage is open, and where the open stage has no
+    such part (:data:`SUB_STAGES` is the whole list)."""
+    if not armed:
+        return
+    parent = _open_stage.get()
+    if parent is None:
+        return
+    name = _SUB_NAME.get((parent.name, part))
+    if name is not None:
+        parent._lap(name, time.perf_counter_ns(), time.thread_time_ns())
+
+
+def count(name: str, n: int) -> None:
+    """Add `n` to the seam counter `name` (``h2d_bytes``, ``d2h_bytes``,
+    ``batches``): an attribute of the span whose stage the calling
+    thread has open, and ``sw_ec_*_total{op}`` on /metrics. Nothing
+    when disarmed (one module-bool check) or with no stage open."""
+    if not armed:
+        return
+    parent = _open_stage.get()
+    if parent is None:
+        return
+    parent.span.count(name, n)
+    _seam_counters[name].inc(n, op=parent.span.op)
+
+
 def add_stage(span, name: str, seconds: float, chip: str = "") -> None:
     if span is not None:
         span.add_stage(name, seconds, chip)
@@ -718,15 +957,18 @@ def traces(
 def chrome_trace(trace_id: str = "", docs: list[dict] | None = None) -> dict:
     """Chrome ``trace_event`` JSON (the dict; ``json.dump`` it) for the
     recorded traces — loadable in Perfetto / chrome://tracing. Each
-    server becomes a process row, each root span a thread row; stages
-    and attrs ride in ``args``."""
+    server becomes a process, each thread that worked for a root span a
+    row of it: spans lie on their owning thread's row and every stage
+    interval is an ``X`` event on the row of the thread that ran it, so
+    a pipeline's reader, dispatcher and sink show side by side. Stage
+    totals and attrs ride in the span events' ``args``."""
     if docs is None:
         docs = traces(trace_id)
     events: list[dict] = []
     pids: dict[str, int] = {}
     tid_next: dict[int, int] = {}
 
-    def emit(doc: dict, pid: int, tid: int) -> None:
+    def emit(doc: dict, pid: int, row) -> None:
         args = {
             "trace_id": doc["trace_id"],
             "span_id": doc["span_id"],
@@ -736,9 +978,14 @@ def chrome_trace(trace_id: str = "", docs: list[dict] | None = None) -> dict:
                 for s, a in doc["stages"].items()
             },
         }
+        if doc.get("cpu_s") is not None:
+            args["cpu_ms"] = round(doc["cpu_s"] * 1000.0, 3)
+        if doc.get("stages_dropped"):
+            args["stages_dropped"] = doc["stages_dropped"]
         if doc.get("overlap_efficiency") is not None:
             args["overlap_efficiency"] = doc["overlap_efficiency"]
         args.update(doc["attrs"])
+        tid = row(doc.get("thread", ""))
         events.append(
             {
                 "name": doc["name"],
@@ -751,6 +998,21 @@ def chrome_trace(trace_id: str = "", docs: list[dict] | None = None) -> dict:
                 "args": args,
             }
         )
+        for stage, t0, t1, thread, cpu_ns in doc.get("intervals", ()):
+            ev = {
+                "name": stage,
+                "cat": doc["op"],
+                "ph": "X",
+                # the span's monotonic clock, laid where start_ts says
+                "ts": doc["start_ts"] * 1e6 + (t0 - doc["start_ns"]) / 1e3,
+                "dur": max(t1 - t0, 1) / 1e3,
+                "pid": pid,
+                "tid": row(thread),
+                "args": {"trace_id": doc["trace_id"], "span_id": doc["span_id"]},
+            }
+            if cpu_ns >= 0:
+                ev["args"]["cpu_ms"] = round(cpu_ns / 1e6, 3)
+            events.append(ev)
         for ev in doc["events"]:
             events.append(
                 {
@@ -765,7 +1027,7 @@ def chrome_trace(trace_id: str = "", docs: list[dict] | None = None) -> dict:
                 }
             )
         for c in doc["children"]:
-            emit(c, pid, tid)
+            emit(c, pid, row)
 
     for doc in docs:
         server = doc.get("server") or "proc"
@@ -781,18 +1043,25 @@ def chrome_trace(trace_id: str = "", docs: list[dict] | None = None) -> dict:
                     "args": {"name": server},
                 }
             )
-        tid = tid_next.get(pid, 0) + 1
-        tid_next[pid] = tid
-        events.append(
-            {
-                "name": "thread_name",
-                "ph": "M",
-                "pid": pid,
-                "tid": tid,
-                "args": {
-                    "name": f"{doc['op']} {doc['trace_id'][:8]}"
-                },
-            }
-        )
-        emit(doc, pid, tid)
+        rows: dict[str, int] = {}
+
+        def row(thread: str, doc=doc, pid=pid, rows=rows) -> int:
+            tid = rows.get(thread)
+            if tid is None:
+                tid = rows[thread] = tid_next[pid] = tid_next.get(pid, 0) + 1
+                label = f"{doc['op']} {doc['trace_id'][:8]}"
+                events.append(
+                    {
+                        "name": "thread_name",
+                        "ph": "M",
+                        "pid": pid,
+                        "tid": tid,
+                        "args": {
+                            "name": f"{label} {thread}" if thread else label
+                        },
+                    }
+                )
+            return tid
+
+        emit(doc, pid, row)
     return {"traceEvents": events, "displayTimeUnit": "ms"}

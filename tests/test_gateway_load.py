@@ -83,9 +83,13 @@ def test_pooled_server_keepalive_park_resume():
         assert sess.get(f"http://127.0.0.1:{port}/a").content == b"echo:/a"
         time.sleep(1.0)  # parked well past any dispatch loop
         assert sess.get(f"http://127.0.0.1:{port}/b").content == b"echo:/b"
-        st = srv.pool_status()
-        assert st["requests_served"] >= 2
-        assert st["open_connections"] >= 1  # the parked keep-alive conn
+        # the worker counts a request after handle_one_request returns,
+        # which is after the client has its reply: wait, don't race it
+        _wait(
+            lambda: srv.pool_status()["requests_served"] >= 2,
+            msg="requests_served to reach 2",
+        )
+        assert srv.pool_status()["open_connections"] >= 1  # the parked conn
     finally:
         srv.shutdown()
         srv.server_close()
