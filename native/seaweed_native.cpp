@@ -429,6 +429,10 @@ int sn_shard_append(const int* fds, const uint8_t* const* rows, int nrows,
 
 #include <fcntl.h>
 
+// sn_batch_pread starts a thread a row only for rows this long; the
+// encoder's and the rebuild's 16 MiB rows are, a needle's extent is not.
+static const size_t kThreadMinWidth = (size_t)4 << 20;
+
 // Read `width` bytes from fds[i] at offsets[i] into dst + i*stride.
 // pad_eof!=0 zero-fills past EOF (the encoder's ragged tail); pad_eof==0
 // treats a short read as that row's failure (the rebuild contract).
@@ -482,10 +486,15 @@ int sn_batch_pread(const int* fds, const uint64_t* offsets, int nrows,
     };
     // Page-cache-warm rows are memcpy-bound: more workers than cores
     // just thrash. Cold rows are I/O-bound and still overlap fine at
-    // core count (each worker drains rows in a strided loop).
+    // core count (each worker drains rows in a strided loop). Rows
+    // under kThreadMinWidth are read in a loop on the caller's thread:
+    // a degraded read's matrix has rows of 64 KiB to 1 MiB, which one
+    // thread copies faster than ten can be started, and sixteen such
+    // reads at once would start 160 (PERF.md, PR 27, has the readings).
     unsigned hw = std::thread::hardware_concurrency();
     int nworkers = (int)(hw ? hw : 1);
     if (nworkers > nrows) nworkers = nrows;
+    if (width < kThreadMinWidth) nworkers = 1;
     if (nworkers > 1) {
         std::vector<std::thread> ts;
         ts.reserve((size_t)nworkers);
@@ -500,6 +509,26 @@ int sn_batch_pread(const int* fds, const uint64_t* offsets, int nrows,
     for (int i = 0; i < nrows; i++)
         if (status[i] != 0) return -(i + 1);
     return 0;
+}
+
+// CRC32C of every `granule`-byte piece of each row of a (nrows, width)
+// matrix whose rows lie `stride` bytes apart; a row's last piece may be
+// short. out[i * per_row + g] with per_row = ceil(width / granule). One
+// GIL-releasing call checks the whole sibling matrix of a degraded read
+// (or its one output row) against the sidecar's granule CRCs. Rows are
+// walked in a loop: a reconstruction's few MiB are a millisecond of
+// hardware CRC32C, less than starting a thread per row would cost.
+void sn_crc32c_granules(const uint8_t* rows, int nrows, size_t width,
+                        size_t stride, uint32_t granule, uint32_t* out) {
+    size_t per_row = (width + granule - 1) / granule;
+    for (int i = 0; i < nrows; i++) {
+        const uint8_t* p = rows + (size_t)i * stride;
+        for (size_t g = 0; g < per_row; g++) {
+            size_t at = g * (size_t)granule;
+            size_t len = width - at < granule ? width - at : granule;
+            out[(size_t)i * per_row + g] = sn_crc32c(0, p + at, len);
+        }
+    }
 }
 
 // Best-effort readahead hint for the NEXT batch's extent; the producer

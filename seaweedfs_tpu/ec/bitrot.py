@@ -40,7 +40,7 @@ import struct
 import uuid as uuid_mod
 from dataclasses import dataclass, field
 
-from ..utils.crc import crc32c, crc32c_combine
+from ..utils.crc import crc32c, crc32c_combine, crc32c_granules
 from .context import BITROT_BLOCK_SIZE, BITROT_LEAF_SIZE, ECContext, ECError
 
 MAGIC = 0x53575453  # "SWTS" — distinct from the reference's "ECSU"
@@ -300,6 +300,21 @@ class BitrotProtection:
             if gi >= len(crcs) or crc32c(blk) != crcs[gi]:
                 return False
         return True
+
+    def verify_rows(self, shard_ids, lo: int, rows) -> list[bool]:
+        """`verify_range` for several shards at once: row r of the 2-D
+        uint8 matrix `rows` holds shard `shard_ids[r]`'s bytes at [lo,
+        lo+width), `lo` granule-aligned as there. Every granule CRC of
+        every row comes from ONE native call (a degraded read checks
+        its whole sibling matrix, then its one output row, without a
+        Python loop over granules); -> one verdict per row."""
+        gsize, _ = self.verify_granularity(shard_ids[0])
+        got = crc32c_granules(rows, gsize)
+        first, n = lo // gsize, got.shape[1]
+        return [
+            got[r].tolist() == self.verify_granularity(sid)[1][first : first + n]
+            for r, sid in enumerate(shard_ids)
+        ]
 
     # ---- file io ----
 

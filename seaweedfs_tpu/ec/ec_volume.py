@@ -10,6 +10,7 @@ shards are on local disk.
 
 from __future__ import annotations
 
+import contextlib
 import json
 import os
 import struct
@@ -377,27 +378,14 @@ class EcVolume:
             f"{self._shard_gen.get(shard_id, 0)}:{lo}:{hi}"
         )
 
-        def range_ok(sid: int, data: bytes) -> bool:
-            """Verify a shard's [lo, hi) bytes against its own granule
-            CRCs (granules align across shards: equal sizes, one
-            layout)."""
-            with trace.stage(sp, "crc_verify"):
-                return prot.verify_range(sid, lo, data)
-
         def build() -> bytes:
             # Sources are sidecar-verified BEFORE being fed to
-            # Reed-Solomon: a silently-rotten sibling is excluded
-            # instead of poisoning the reconstruction (which would
-            # force a refusal even though k clean shards exist).
-            data = self._reconstruct_range(
-                shard_id, lo, hi - lo, source_ok=range_ok
-            )
-            if not range_ok(shard_id, data):
-                raise ECError(
-                    f"reconstructed shard {shard_id} [{lo}:{hi}) fails "
-                    f".ecsum verification; refusing to serve"
-                )
-            return data
+            # Reed-Solomon (a silently-rotten sibling is excluded
+            # instead of poisoning the reconstruction, which would
+            # force a refusal even though k clean shards exist), and
+            # the output before it is served: _reconstruct_range does
+            # both against `prot`, granules aligning across shards.
+            return self._reconstruct_range(shard_id, lo, hi - lo, prot)
 
         if cache is None:
             return build()[offset - lo : offset - lo + size]
@@ -419,52 +407,83 @@ class EcVolume:
             trace.event(sp, "singleflight_wait", lo=lo, hi=hi)
         return data[offset - lo : offset - lo + size]
 
-    def _reconstruct_range(
-        self, shard_id: int, offset: int, size: int, source_ok=None
-    ) -> bytes:
-        """On-the-fly RS decode of one interval from >=k sibling shards
-        (reference store_ec.go:656-747; like the reference, sibling
-        reads fan out in parallel — remote fetches dominate latency)."""
-        k = self.ctx.data_shards
-        sp = trace.current()  # the ec.degraded_read root, when armed
-        sources: dict[int, np.ndarray] = {}
-        # Local sibling reads ride the native zero-copy plane when it's
-        # up (and no fault registry is armed — the chaos seams want
-        # bytes): each sibling's extent lands in a numpy buffer via one
-        # positioned native read instead of an os.pread bytes copy. The
-        # downstream stack/verify path takes either representation.
+    def _sibling_matrix(
+        self, shard_id: int, offset: int, size: int, prot, sp
+    ) -> tuple[np.ndarray, tuple[int, ...]]:
+        """[offset, offset+size) of k sibling shards as the rows of ONE
+        contiguous (k, size) matrix, and the shard id in each row
+        (reference store_ec.go:656-747). The matrix is what every later
+        step takes whole: one native read fills it, one native call
+        checks all its rows against the sidecar (`prot`; None = no
+        ground truth, rows are taken as read), one put carries it to
+        the device. A row that is short, unreadable or rotten is
+        refilled from the next mounted shard, then from peers."""
         from . import native_io
 
+        k = self.ctx.data_shards
+        matrix = np.empty((k, size), dtype=np.uint8)
+        ids: list[int | None] = [None] * k  # shard in each row; None = to fill
+
+        def admit(row: int, shard_ids: list[int], filled_how: str) -> None:
+            """Rows from `row` on were just filled with these shards'
+            bytes: count them, and keep the ones that pass the sidecar."""
+            self.bytes_read += len(shard_ids) * size
+            if sp is not None:
+                sp.count(filled_how, len(shard_ids))
+            ok = [True] * len(shard_ids)
+            if prot is not None:
+                with trace.stage(sp, "crc_verify"):
+                    ok = prot.verify_rows(
+                        shard_ids, offset, matrix[row : row + len(shard_ids)]
+                    )
+            for r, (sid, good) in enumerate(zip(shard_ids, ok), row):
+                if good:
+                    ids[r] = sid
+
+        # Local sibling reads ride the native zero-copy plane when it's
+        # up (and no fault registry is armed — the chaos seams want
+        # bytes): the first k mounted siblings land in their rows in one
+        # lock-free call. Whatever that leaves open, and everything
+        # without the plane, is filled a row at a time.
         use_native = native_io.enabled() and not faults.active()
-        local = [(i, fd) for i, fd in self.shard_fds.items() if i != shard_id]
-        for i, fd in local:
+        rest = [(i, fd) for i, fd in self.shard_fds.items() if i != shard_id]
+        if use_native and rest:
+            head = rest[:k]
+            try:
+                with trace.stage(sp, "sibling_read"):
+                    native_io.read_batch(
+                        [fd for _i, fd in head], [offset] * len(head),
+                        matrix[: len(head)], pad_eof=False,
+                    )
+            except OSError:
+                pass  # a short or closed shard; which rows landed is not known
+            else:
+                rest = rest[k:]
+                admit(0, [i for i, _fd in head], "sibling_rows_batched")
+        for i, fd in rest:
+            if None not in ids:
+                break
+            row = ids.index(None)
             try:
                 with trace.stage(sp, "sibling_read"):
                     if use_native:
-                        arr = np.empty(size, dtype=np.uint8)
-                        native_io.read_exact_into(fd, arr, offset)
-                        got = arr
+                        native_io.read_exact_into(fd, matrix[row], offset)
                     else:
                         got = os.pread(fd, size, offset)
+                        if len(got) != size:
+                            continue  # truncated shard
+                        matrix[row] = np.frombuffer(got, dtype=np.uint8)
             except OSError:
                 continue
-            self.bytes_read += len(got)
-            if len(got) == size and (
-                source_ok is None or source_ok(i, got)
-            ):
-                sources[i] = (
-                    got if use_native else np.frombuffer(got, dtype=np.uint8)
-                )
-                if len(sources) == k:
-                    break
-        if len(sources) < k and self.remote_reader is not None:
+            admit(row, [i], "sibling_rows_single")
+        if None in ids and self.remote_reader is not None:
             import contextvars
             from concurrent.futures import FIRST_COMPLETED, ThreadPoolExecutor, wait
 
             missing = [
                 i
                 for i in range(self.ctx.total)
-                if i != shard_id and i not in sources
+                if i != shard_id and i not in ids
             ]
 
             def fetch(i):
@@ -476,75 +495,81 @@ class EcVolume:
                 # shard-read RPC hop carries both in its metadata.
                 return ex.submit(contextvars.copy_context().run, fetch, i)
 
-            # stop as soon as k sources exist: one hung peer must not
+            # stop as soon as k rows are filled: one hung peer must not
             # stall the read for the full RPC timeout
             ex = ThreadPoolExecutor(max_workers=min(len(missing), 8))
             try:
                 # "sibling_read" covers only the blocked wait on peer
-                # fetches; the source_ok callbacks below run range_ok,
-                # which tags its own time "crc_verify" — wrapping them
-                # here too would double-count verify seconds into the
-                # wire stage.
+                # fetches; admit() tags its own time "crc_verify".
                 with trace.stage(sp, "sibling_read"):
                     futures = {submit(ex, i) for i in missing}
-                while futures and len(sources) < k:
+                while futures and None in ids:
                     with trace.stage(sp, "sibling_read"):
                         done, futures = wait(
                             futures, return_when=FIRST_COMPLETED
                         )
                     for f in done:
                         i, got = f.result()
-                        if got is not None:
-                            self.bytes_read += len(got)
-                        if (
-                            got is not None
-                            and len(got) == size
-                            and (source_ok is None or source_ok(i, got))
-                        ):
-                            sources[i] = np.frombuffer(got, dtype=np.uint8)
+                        if got is None or len(got) != size or None not in ids:
+                            continue
+                        row = ids.index(None)
+                        matrix[row] = np.frombuffer(got, dtype=np.uint8)
+                        admit(row, [i], "sibling_rows_single")
             finally:
                 ex.shutdown(wait=False, cancel_futures=True)
-        if len(sources) < k:
+        if None in ids:
             raise ECError(
-                f"shard {shard_id} unavailable and only {len(sources)} "
-                f"sibling shards readable (need {k})"
+                f"shard {shard_id} unavailable and only "
+                f"{k - ids.count(None)} sibling shards readable (need {k})"
             )
-        if len(sources) > k:
-            sources = {i: sources[i] for i in sorted(sources)[:k]}
+        return matrix, tuple(ids)
+
+    def _decode_row(self, shard_id: int, src_ids: tuple[int, ...]) -> np.ndarray:
+        """(1, k) coefficients taking shards `src_ids`, in that order,
+        to shard `shard_id`: tiny, but their GF inversion isn't free on
+        a hot read path, so memoized per (target, source rows)."""
+        coeffs = self._coeff_cache.get((shard_id, src_ids))
+        if coeffs is None:
+            # the backend already built this matrix (Protocol doesn't
+            # promise the attribute, so fall back to constructing)
+            k = self.ctx.data_shards
+            matrix = getattr(self.backend, "matrix", None)
+            if matrix is None:
+                matrix = gf256.ReedSolomon(k, self.ctx.parity_shards).matrix
+            coeffs = _decode_coeffs(matrix, k, (shard_id,), src_ids)
+            if len(self._coeff_cache) >= 64:  # flapping remote sources
+                self._coeff_cache.clear()
+            self._coeff_cache[(shard_id, src_ids)] = coeffs
+        return coeffs
+
+    def _reconstruct_range(
+        self, shard_id: int, offset: int, size: int, prot=None
+    ) -> bytes:
+        """On-the-fly RS decode of one interval from k sibling shards.
+        With `prot` (the sidecar; `offset` granule-aligned) every source
+        row is verified before it reaches Reed-Solomon and the output
+        before it is returned: a mismatch there raises, fail-closed."""
+        sp = trace.current()  # the ec.degraded_read root, when armed
+        matrix, src_ids = self._sibling_matrix(shard_id, offset, size, prot, sp)
+        coeffs = self._decode_row(shard_id, src_ids)
         if size >= 2 * STAGED_RECOVERY_BATCH:
             # Wide extent (multi-leaf verified reconstruction, v1 16 MiB
             # blocks, scrub-driven repair reads): batch the GF(256)
             # apply through the backend's staged hooks so H2D upload,
             # device compute, and D2H drain overlap across column
             # batches — the same shape rebuild uses, one code path
-            # (ec/pipeline.py run_staged_apply).
-            src_ids = tuple(sorted(sources))
-            coeffs = self._coeff_cache.get((shard_id, src_ids))
-            if coeffs is None:
-                # the backend already built this matrix (Protocol doesn't
-                # promise the attribute, so fall back to constructing)
-                matrix = getattr(self.backend, "matrix", None)
-                if matrix is None:
-                    matrix = gf256.ReedSolomon(k, self.ctx.parity_shards).matrix
-                coeffs = _decode_coeffs(matrix, k, (shard_id,), src_ids)
-                if len(self._coeff_cache) >= 64:  # flapping remote sources
-                    self._coeff_cache.clear()
-                self._coeff_cache[(shard_id, src_ids)] = coeffs
-            # Stacked PER BATCH, not whole-extent: a (k, size) upfront
-            # stack would transiently double the sibling-byte footprint
-            # for exactly the wide extents this path targets; one
-            # (k, batch) copy at a time is the to_device copy anyway.
-            srcs = [sources[i] for i in src_ids]
-            out = np.empty(size, dtype=np.uint8)
+            # (ec/pipeline.py run_staged_apply). One (k, batch) copy at
+            # a time is the to_device copy anyway.
+            out = np.empty((1, size), dtype=np.uint8)
 
             def produce():
                 for off in range(0, size, STAGED_RECOVERY_BATCH):
-                    yield off, np.stack(
-                        [s[off : off + STAGED_RECOVERY_BATCH] for s in srcs]
+                    yield off, np.ascontiguousarray(
+                        matrix[:, off : off + STAGED_RECOVERY_BATCH]
                     )
 
             def consume(off, rec):
-                out[off : off + rec.shape[1]] = rec[0]
+                out[0, off : off + rec.shape[1]] = rec[0]
 
             run_staged_apply(
                 self.backend, coeffs, produce, consume,
@@ -562,28 +587,35 @@ class EcVolume:
                 read_stage="stage_batch",
                 write_stage="write_sink",
             )
-            self.bytes_reconstructed += size
-            return out.tobytes()
-        # Single-shot path (the latency-sensitive needle-read shape):
-        # still a CLIENT of the shared per-chip scheduler — serving
-        # traffic takes a FOREGROUND window slot with a cost hint, so a
-        # gateway read preempts colocated recovery/scrub admission
-        # instead of racing it unscheduled (ISSUE 11). The wait lands on
-        # the span as "admission_wait", like the staged path's.
-        from .device_queue import batch_cost, resolve_scope
-
-        queue = resolve_scope(self.scheduler).for_backend(self.backend)
-        if queue is not None:
-            with queue.admission(
-                "foreground", batch_cost(1, size), span=sp
-            ):
-                with trace.stage(sp, "reconstruct"):
-                    rec = self.backend.reconstruct(sources, want=[shard_id])
         else:
-            with trace.stage(sp, "reconstruct"):
-                rec = self.backend.reconstruct(sources, want=[shard_id])
+            # Single-shot path (the latency-sensitive needle-read
+            # shape): the matrix goes up in one put and one apply. Still
+            # a CLIENT of the shared per-chip scheduler — serving traffic
+            # takes a FOREGROUND window slot with a cost hint, so a
+            # gateway read preempts colocated recovery/scrub admission
+            # instead of racing it unscheduled (ISSUE 11). The wait
+            # lands on the span as "admission_wait", like the staged
+            # path's.
+            from .device_queue import batch_cost, resolve_scope
+
+            queue = resolve_scope(self.scheduler).for_backend(self.backend)
+            slot = (
+                queue.admission("foreground", batch_cost(1, size), span=sp)
+                if queue is not None
+                else contextlib.nullcontext()
+            )
+            with slot, trace.stage(sp, "reconstruct"):
+                out = self.backend.apply(coeffs, matrix)
         self.bytes_reconstructed += size
-        return np.asarray(rec[shard_id], dtype=np.uint8).tobytes()
+        if prot is not None:
+            with trace.stage(sp, "crc_verify"):
+                (ok,) = prot.verify_rows([shard_id], offset, out)
+            if not ok:
+                raise ECError(
+                    f"reconstructed shard {shard_id} [{offset}:{offset + size}) "
+                    f"fails .ecsum verification; refusing to serve"
+                )
+        return out[0].tobytes()
 
     # ------------------------------------------------------------- delete
 

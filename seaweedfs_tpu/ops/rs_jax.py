@@ -246,7 +246,7 @@ class RSJax:
             return {}
         src = present[: self.k]
         # the parts of the caller's `reconstruct` stage (utils/trace.py)
-        trace.lap("stack")
+        trace.lap("put")
         data = jnp.stack([jnp.asarray(shards[i], dtype=jnp.uint8) for i in src])
         trace.lap("launch")
         bits = self._rows_bits(missing, src)

@@ -90,6 +90,22 @@ def crc32c(data, crc: int = 0) -> int:
     return _crc32c_py(data, crc)
 
 
+def crc32c_granules(rows: np.ndarray, granule: int) -> np.ndarray:
+    """CRC32C of every `granule`-byte piece of each row of a 2-D uint8
+    matrix (a row's last piece may be short) -> u32[rows, pieces]: one
+    native call for the lot, a loop over `crc32c` without the native
+    core."""
+    if _load_native():
+        from . import native
+
+        return native.crc32c_granules(rows, granule)
+    out = np.empty((len(rows), -(-rows.shape[1] // granule)), dtype=np.uint32)
+    for r, row in enumerate(rows):
+        for g in range(out.shape[1]):
+            out[r, g] = _crc32c_py(row[g * granule : (g + 1) * granule])
+    return out
+
+
 # ---------------------------------------------------------------- combine
 #
 # crc32c(A || B) from crc32c(A), crc32c(B), len(B) without touching the

@@ -121,6 +121,15 @@ _lib.sn_batch_pread.argtypes = [
     ctypes.c_void_p,                  # out_counts
     ctypes.c_int32,                   # max_out
 ]
+_lib.sn_crc32c_granules.restype = None
+_lib.sn_crc32c_granules.argtypes = [
+    ctypes.c_void_p,   # rows
+    ctypes.c_int,      # nrows
+    ctypes.c_size_t,   # width
+    ctypes.c_size_t,   # stride
+    ctypes.c_uint32,   # granule
+    ctypes.c_void_p,   # out (u32[nrows * ceil(width / granule)])
+]
 _lib.sn_fadvise_willneed.restype = ctypes.c_int
 _lib.sn_fadvise_willneed.argtypes = [
     ctypes.c_int, ctypes.c_uint64, ctypes.c_uint64,
@@ -237,6 +246,25 @@ def crc32c(data, crc: int = 0) -> int:
     return _lib.sn_crc32c(
         crc, ctypes.c_void_p(data.ctypes.data), data.nbytes
     )
+
+
+def crc32c_granules(rows: np.ndarray, granule: int) -> np.ndarray:
+    """CRC32C of every `granule`-byte piece of each row of a 2-D uint8
+    matrix (rows contiguous, any row stride; the last piece of a row may
+    be short) -> u32[nrows, ceil(width / granule)]. One GIL-releasing
+    call, however many rows and pieces."""
+    if rows.dtype != np.uint8 or rows.ndim != 2 or rows.strides[1] != 1:
+        raise ValueError("crc32c_granules wants a 2-D uint8 matrix of contiguous rows")
+    if granule <= 0:
+        raise ValueError(f"granule {granule} must be positive")
+    n, width = rows.shape
+    out = np.empty((n, -(-width // granule)), dtype=np.uint32)
+    if out.size:
+        _lib.sn_crc32c_granules(
+            ctypes.c_void_p(rows.ctypes.data), n, width, rows.strides[0],
+            granule, ctypes.c_void_p(out.ctypes.data),
+        )
+    return out
 
 
 def rs_apply(coeffs: np.ndarray, data: np.ndarray) -> np.ndarray:

@@ -80,8 +80,8 @@ jitted apply: coefficient bits, cache look-up, enqueue);
 ``device_drain`` = ``.ready`` (blocked until the result exists on the
 device: upload, kernel, device queue) + ``.d2h`` (the copy into a numpy
 array) + ``.host_copy`` (``np.ascontiguousarray`` where it copies);
-``reconstruct`` (single-shot degraded read) = ``.stack`` + ``.launch``
-+ ``.ready`` + ``.d2h``.
+``reconstruct`` (single-shot degraded read) = ``.put`` (the sibling
+matrix's one ``device_put``) + ``.launch`` + ``.ready`` + ``.d2h``.
 
 Overlap efficiency
 ------------------
@@ -170,7 +170,7 @@ STAGES = frozenset({
 _SUB_PARTS = {
     "h2d_dispatch": ("stage", "put", "launch"),
     "device_drain": ("ready", "d2h", "host_copy"),
-    "reconstruct": ("stack", "launch", "ready", "d2h"),
+    "reconstruct": ("put", "launch", "ready", "d2h"),
 }
 _SUB_NAME = {
     (parent, part): f"{parent}.{part}"

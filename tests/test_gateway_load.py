@@ -255,15 +255,15 @@ def test_concurrent_degraded_reads_collapse_to_one_reconstruction(tmp_path):
     nid = _needle_on_shard0(vol)
 
     recon_calls = []
-    orig = vol.backend.reconstruct
+    orig = vol.backend.apply  # a reconstruction is ONE apply of its matrix
     gate = threading.Event()
 
-    def counting_reconstruct(sources, want):
-        recon_calls.append(want)
+    def counting_apply(coeffs, matrix):
+        recon_calls.append(matrix.shape)
         gate.wait(5)  # hold the leader so every reader joins the flight
-        return orig(sources, want=want)
+        return orig(coeffs, matrix)
 
-    vol.backend.reconstruct = counting_reconstruct
+    vol.backend.apply = counting_apply
     results, errors = [], []
     lock = threading.Lock()
 
@@ -306,13 +306,13 @@ def test_invalidation_never_serves_stale_generation(tmp_path):
     nid = _needle_on_shard0(vol)
 
     recon_calls = []
-    orig = vol.backend.reconstruct
+    orig = vol.backend.apply
 
-    def counting_reconstruct(sources, want):
-        recon_calls.append(want)
-        return orig(sources, want=want)
+    def counting_apply(coeffs, matrix):
+        recon_calls.append(matrix.shape)
+        return orig(coeffs, matrix)
 
-    vol.backend.reconstruct = counting_reconstruct
+    vol.backend.apply = counting_apply
     assert vol.read_needle(nid).data == payloads[nid]
     assert len(recon_calls) == 1
     assert vol.read_needle(nid).data == payloads[nid]
