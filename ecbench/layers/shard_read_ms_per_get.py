@@ -1,0 +1,15 @@
+"""Milliseconds a GET of the window spent reading the healthy intervals
+of its needle from mounted shards or a peer (`volume.read.shard`, a
+part of the handler's `volume.read` stage), per GET. A program that
+does not split `volume.read` gives nothing to read."""
+
+from ecbench.layerlib import get_roots
+from ecbench.spanlib import has_stage, stage_ms_per_get
+
+
+def read(obs, cell):
+    # a needle that lies wholly on lost shards has no `.shard` part, but
+    # every needle read ends in `.parse`: that marks a program with parts
+    if not has_stage(get_roots(obs), ("volume.read.parse",)):
+        return None
+    return stage_ms_per_get(obs, "volume.read.shard")

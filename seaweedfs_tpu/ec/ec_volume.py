@@ -229,6 +229,12 @@ class EcVolume:
     # --------------------------------------------------------------- read
 
     def read_needle(self, needle_id: int, cookie: Optional[int] = None) -> Needle:
+        # The laps here, in _read_shard_interval, _recover_interval and
+        # _read_extent split the HTTP handler's `volume.read` stage from
+        # inside (`.index`, `.shard`, `.recover`, `.parse`): one
+        # module-bool check each when disarmed, nothing where the caller
+        # has no such stage open.
+        trace.lap("index")
         with self._lock:
             nv = self.find_needle(needle_id)
         if nv is None or nv.is_deleted:
@@ -273,9 +279,12 @@ class EcVolume:
                 parts.append(self._recover_interval(shard_id, shard_off, iv.size))
             else:
                 parts.append(self._read_shard_interval(shard_id, shard_off, iv.size))
+        # the join, and the caller's Needle.from_bytes with the body's CRC
+        trace.lap("parse")
         return b"".join(parts)
 
     def _read_shard_interval(self, shard_id: int, offset: int, size: int) -> bytes:
+        trace.lap("shard")
         fd = self.shard_fds.get(shard_id)
         if fd is not None:
             try:
@@ -337,6 +346,7 @@ class EcVolume:
         lands in the interval cache so a hot needle on a lost shard
         pays reconstruction once.
         """
+        trace.lap("recover")
         # Flight-recorder root per degraded-read op (a child when a
         # server RPC/scrub span is active in this thread).
         sp = trace.start(

@@ -81,7 +81,12 @@ jitted apply: coefficient bits, cache look-up, enqueue);
 device: upload, kernel, device queue) + ``.d2h`` (the copy into a numpy
 array) + ``.host_copy`` (``np.ascontiguousarray`` where it copies);
 ``reconstruct`` (single-shot degraded read) = ``.put`` (the sibling
-matrix's one ``device_put``) + ``.launch`` + ``.ready`` + ``.d2h``.
+matrix's one ``device_put``) + ``.launch`` + ``.ready`` + ``.d2h``;
+``volume.read`` (a needle GET of an EC volume) = ``.index`` (the
+``.ecx`` look-up under the volume's lock) + ``.shard`` (intervals read
+from mounted shards or a peer) + ``.recover`` (intervals recovered: the
+``ec.degraded_read`` child span lies inside) + ``.parse`` (the join and
+``Needle.from_bytes``, the body's CRC).
 
 Overlap efficiency
 ------------------
@@ -171,6 +176,8 @@ _SUB_PARTS = {
     "h2d_dispatch": ("stage", "put", "launch"),
     "device_drain": ("ready", "d2h", "host_copy"),
     "reconstruct": ("put", "launch", "ready", "d2h"),
+    # an EC needle read (ec/ec_volume.py), under the HTTP handler's stage
+    "volume.read": ("index", "shard", "recover", "parse"),
 }
 _SUB_NAME = {
     (parent, part): f"{parent}.{part}"

@@ -43,6 +43,22 @@ def _fault_registry_hygiene():
         faults.clear()
 
 
+@pytest.fixture
+def disarmed():
+    """The tracer is process-wide, and with `--dist loadfile` another
+    file's tests ran on this worker before these: a recorder they left
+    armed, or root spans left in the ring, must not decide a test that
+    asserts on the disarmed state. Put the tracer back before, and
+    leave it so."""
+    from seaweedfs_tpu.utils import trace
+
+    trace.configure(enabled=False, slow_op_s=0.0)
+    trace.reset()
+    yield trace
+    trace.configure(enabled=False, slow_op_s=0.0)
+    trace.reset()
+
+
 @pytest.fixture(scope="session")
 def rng():
     import numpy as np

@@ -78,7 +78,10 @@ def read_traced(ev, offset, size):
         got = ev._recover_interval(LOST, offset, size)
         (doc,) = [d for d in trace.traces() if d["op"] == "ec.degraded_read"]
     finally:
+        # the tracer is process-wide: leave it off and empty for whatever
+        # file this worker runs next (tests/test_trace.py asserts on both)
         trace.configure(enabled=False)
+        trace.reset()
     return got, doc
 
 

@@ -22,8 +22,15 @@ from seaweedfs_tpu.shell.commands import COMMANDS, ShellEnv, run_command
 
 
 def wait_for(cond, timeout=15.0, msg="condition"):
+    """Poll `cond` until it holds. A master look-up RAISES LookupError
+    until the volume's heartbeat has landed: that is "not yet" too."""
     deadline = time.time() + timeout
-    while not cond():
+    while True:
+        try:
+            if cond():
+                return
+        except LookupError:
+            pass
         if time.time() > deadline:
             raise TimeoutError(msg)
         time.sleep(0.05)

@@ -48,7 +48,7 @@ def walk(doc):
 # ---------------------------------------------------------------- disarmed
 
 
-def test_disarmed_fast_path_is_noop_singleton():
+def test_disarmed_fast_path_is_noop_singleton(disarmed):
     """Span-enter/exit when disarmed must be one flag/is-None check and
     ZERO allocations: every helper returns the same singleton or None."""
     assert not trace.armed
@@ -637,7 +637,7 @@ class _NoClock:
         raise AssertionError(f"time.{name} read while the tracer is disarmed")
 
 
-def test_disarmed_no_new_site_reads_a_clock_or_allocates(monkeypatch, tmp_path):
+def test_disarmed_no_new_site_reads_a_clock_or_allocates(disarmed, monkeypatch, tmp_path):
     """The sites this PR added, driven with the tracer off: each is one
     module-bool or is-None check that hands back the no-op singleton."""
     import numpy as np
