@@ -237,13 +237,69 @@ class JaxBackend(_BackendBase):
 
             self._mesh_rs = MeshRS(self._rs, make_mesh(want))
 
+    # -- the host link: a batch crosses it as 32-bit words, both ways.
+    # A (rows, n) uint8 array lies on the chip four ROWS to a word, so a
+    # two-row result is half holes and a one-row result three quarters,
+    # the holes cross with the bytes, and even a dense one is picked
+    # apart on the way (ops/rs_pallas.py "Words in, words out"; PERF.md
+    # section 6, PR 30). The same bytes as int32 words of four
+    # consecutive bytes are a free view on the host at either end, and
+    # the kernels take and return them. A staged handle is
+    # (device words, n): n is the batch's width in bytes, the words may
+    # end in a pad.
+
+    @staticmethod
+    def _words(data: np.ndarray, n_devices: int = 1):
+        """Host (k, n) uint8 -> ((k, n/4) int32 view of it, n). Only a
+        width that does not fill its last word (or the devices' equal
+        shares of words) is copied, into a zero pad: parity of a zero
+        column is zero."""
+        from ..parallel import pad_cols
+
+        data = np.ascontiguousarray(data, dtype=np.uint8)
+        data, n = pad_cols(data, 4 * n_devices)
+        return data.view(np.int32), n
+
+    @staticmethod
+    def _fetch(words, n: int) -> np.ndarray:
+        """Device words -> host (rows, n) uint8: the fetched buffer
+        viewed as bytes, the pad cut by a slice; no second copy (the
+        runtime hands a result of a few words back column-major: only
+        that is copied)."""
+        host = np.ascontiguousarray(np.asarray(words))
+        trace.count("d2h_bytes", host.nbytes)
+        trace.count("d2h_dense_bytes", host.nbytes)
+        return host.view(np.uint8)[:, :n]
+
     def encode(self, data: np.ndarray) -> np.ndarray:
-        return np.asarray(self._rs.encode(data))
+        words, n = self._words(data)
+        return self._fetch(self._rs.encode(words), n)
+
+    def apply(self, coeffs: np.ndarray, data: np.ndarray) -> np.ndarray:
+        # put, call and fetch at once; armed, the parts of the caller's
+        # `reconstruct` stage (a degraded read's one matrix)
+        import jax
+
+        trace.lap("put")
+        words, n = self._words(data)
+        trace.count("h2d_bytes", words.nbytes)
+        trace.count("batches", 1)
+        staged = jax.device_put(words)
+        trace.lap("launch")
+        out = self._rs.apply(coeffs, staged)
+        if trace.armed:
+            trace.lap("ready")
+            out.block_until_ready()
+            trace.lap("d2h")
+        return self._fetch(out, n)
 
     # -- async pipeline: JAX dispatch is non-blocking, so staging batch
-    # N+1 while batch N computes (and N-1 drains to host) only requires
-    # NOT forcing np.asarray between the stages. The encoder's bounded
-    # queues provide the double-buffering window.
+    # N+1 while batch N computes (and N-1 comes home) only requires NOT
+    # forcing np.asarray between the stages. The encoder's bounded
+    # queues provide the double-buffering window. The copy home is
+    # asked for where the apply is launched (`copy_to_host_async`), so
+    # it runs behind the sink's writes and the batch's wait in the
+    # queue, and `to_host` finds the bytes there or waits for the rest.
     # The trace.lap / trace.count lines split the caller's h2d_dispatch
     # and device_drain stages where the bytes cross (utils/trace.py);
     # disarmed each is one module-bool check.
@@ -251,103 +307,55 @@ class JaxBackend(_BackendBase):
         import jax
 
         trace.lap("stage")
-        data = np.ascontiguousarray(data, dtype=np.uint8)
-        if self._mesh_rs is not None:
-            from ..parallel import pad_cols
-
-            data, n = pad_cols(data, self._mesh_rs.n_devices)
-        trace.count("h2d_bytes", data.nbytes)
+        mesh = self._mesh_rs
+        words, n = self._words(data, 1 if mesh is None else mesh.n_devices)
+        trace.count("h2d_bytes", words.nbytes)
         trace.count("batches", 1)
         trace.lap("put")
-        if self._mesh_rs is not None:
-            return (self._mesh_rs.put(data), n)
-        return jax.device_put(data, self.device)
+        if mesh is not None:
+            return mesh.put(words), n
+        return jax.device_put(words, self.device), n
 
     def encode_staged(self, staged):
         trace.lap("launch")
-        if self._mesh_rs is not None:
-            arr, n = staged
-            return (self._mesh_rs.encode(arr), n)
-        return self._rs.encode(staged)
+        words, n = staged
+        rs = self._rs if self._mesh_rs is None else self._mesh_rs
+        out = rs.encode(words)
+        out.copy_to_host_async()
+        return out, n
 
     def apply_staged(self, coeffs: np.ndarray, staged):
         trace.lap("launch")
+        words, n = staged
         coeffs = np.ascontiguousarray(coeffs, dtype=np.uint8)
-        if self._mesh_rs is not None:
-            arr, n = staged
+        if self._mesh_rs is None:
+            out = self._rs.apply(coeffs, words)
+        else:
             bits = self._rs.coeff_bits(coeffs)
-            return (self._mesh_rs.apply(bits, arr, coeffs.shape[0]), n)
-        return self._rs.apply(coeffs, staged)
+            out = self._mesh_rs.apply(bits, words, coeffs.shape[0])
+        out.copy_to_host_async()
+        return out, n
 
     def to_host(self, result) -> np.ndarray:
         # TPU-side chaos hook: the kernel was LAUNCHED (encode_staged/
-        # apply_staged dispatched it non-blocking) and this fetch is
-        # where a reset/hung device actually surfaces. A raised IOError
-        # here models a mid-kernel device reset, so FallbackBackend's
-        # to_host failover (CPU replay of the carried host batch) is
-        # exercisable — not just pre-dispatch death.
+        # apply_staged dispatched it non-blocking, and asked for the
+        # copy home) and this fetch is where a reset/hung device
+        # actually surfaces. A raised IOError here models a mid-kernel
+        # device reset, so FallbackBackend's to_host failover (CPU
+        # replay of the carried host batch) is exercisable — not just
+        # pre-dispatch death.
         faults.fire(
             "ec.device.kernel_fetch", impl=getattr(self._rs, "impl", "")
         )
+        words, n = result
         if trace.armed:
-            return self._to_host_split(result)
-        if self._mesh_rs is not None:
-            arr, n = result
-            return np.asarray(arr, dtype=np.uint8)[:, :n]
-        return np.asarray(result, dtype=np.uint8)
-
-    def _to_host_split(self, result) -> np.ndarray:
-        """`to_host` with the wait for the result (upload, kernel, the
-        device's queue) told apart from the copy back. Armed only: the
-        plain path is the one np.asarray, which waits and copies in one."""
-        arr, n = result if self._mesh_rs is not None else (result, None)
-        trace.lap("ready")
-        arr.block_until_ready()
-        trace.lap("d2h")
-        out = np.asarray(arr, dtype=np.uint8)
-        trace.count("d2h_bytes", out.nbytes)
-        return out if n is None else out[:, :n]
-
-    def reconstruct(
-        self, shards: dict[int, np.ndarray], want: list[int] | None = None
-    ) -> dict[int, np.ndarray]:
-        # RSJax.reconstruct marks its own .stack and .launch
-        out = self._rs.reconstruct(
-            {i: np.asarray(s, np.uint8) for i, s in shards.items()}, want=want
-        )
-        if not trace.armed:
-            return {i: np.asarray(v) for i, v in out.items()}
-        import jax
-
-        trace.lap("ready")
-        jax.block_until_ready(out)
-        trace.lap("d2h")
-        host = {i: np.asarray(v) for i, v in out.items()}
-        trace.count("h2d_bytes", sum(len(s) for s in shards.values()))
-        trace.count("batches", 1)
-        trace.count("d2h_bytes", sum(v.nbytes for v in host.values()))
-        return host
-
-    def apply(self, coeffs: np.ndarray, data: np.ndarray) -> np.ndarray:
-        data = np.asarray(data, np.uint8)
-        if not trace.armed:
-            return np.asarray(self._rs.apply(coeffs, data))
-        # the same put, call and fetch, told apart: the parts of the
-        # caller's `reconstruct` stage (a degraded read's one matrix)
-        import jax
-
-        trace.lap("put")
-        staged = jax.device_put(data)
-        trace.lap("launch")
-        out = self._rs.apply(coeffs, staged)
-        trace.lap("ready")
-        out.block_until_ready()
-        trace.lap("d2h")
-        host = np.asarray(out)
-        trace.count("h2d_bytes", data.nbytes)
-        trace.count("batches", 1)
-        trace.count("d2h_bytes", host.nbytes)
-        return host
+            # the same fetch, with the wait for the result (upload,
+            # kernel, the device's queue) told apart from what is left
+            # of the copy home
+            trace.lap("ready")
+            words.block_until_ready()
+            trace.lap("d2h")
+        return self._fetch(words, n)
 
 
 # Live FallbackBackend registry for the breaker-health gauge: sampled

@@ -199,8 +199,8 @@ def run_pipeline(
 
 def _contiguous(out) -> np.ndarray:
     """The drained batch as the sink needs it; `device_drain.host_copy`
-    says what that cost where it copies (a mesh result sliced back to
-    its width)."""
+    says what that cost where it copies (a result whose width ended in
+    a pad: a tail that does not fill a word, a mesh's equal shares)."""
     trace.lap("host_copy")
     return np.ascontiguousarray(out, dtype=np.uint8)
 
@@ -226,11 +226,15 @@ def run_staged_apply(
     """The staged device `apply` driver shared by rebuild, decode, and
     degraded reconstruction: run_pipeline where the transform stage is
     `backend.apply_staged(coeffs, backend.to_device(batch))` — a
-    NON-BLOCKING H2D upload + device dispatch — and the writer stage
-    forces the result with `backend.to_host` before handing the host
-    uint8 matrix to `consume`. Batch N computes on the device while
-    batch N+1 uploads and batch N-1 drains, the same double-buffered
-    window `encode_staged` gave the encoder.
+    NON-BLOCKING H2D upload + device dispatch that also asks for the
+    result's copy home (a device backend sends the batch up and brings
+    the result back as dense 32-bit words: ec/backend.py) — and the
+    writer stage takes the result with `backend.to_host`, which finds
+    the bytes on the host or waits for the rest of the copy, before
+    handing the host uint8 matrix (a view of what was fetched) to
+    `consume`. Batch N computes on the device while batch N+1 uploads
+    and batch N-1 comes home behind the sink's writes, the same
+    double-buffered window `encode_staged` gave the encoder.
 
     `produce()` yields `(tag, batch)` pairs; `consume(tag, out)` gets
     the tag back untouched (offset bookkeeping stays with the caller).

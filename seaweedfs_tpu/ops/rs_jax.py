@@ -34,7 +34,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..utils import trace
 from . import gf256
 
 # The one name of the Reed-Solomon apply on the device, whichever
@@ -70,20 +69,42 @@ def bit_matrix_bitmajor(coeffs: np.ndarray) -> np.ndarray:
     )
 
 
+def _lanes(data) -> jax.Array:
+    """A batch as the device applies take it: (k, n) uint8 bytes or, the
+    form in which ec/backend.py sends it over the host link, (k, n/4)
+    int32 words of four consecutive bytes each (rs_pallas.py, "Words
+    in, words out"). The result of an apply has the form of its input.
+    Anything but int32 is taken as bytes."""
+    if getattr(data, "dtype", None) == jnp.int32:
+        return jnp.asarray(data)
+    return jnp.asarray(data, dtype=jnp.uint8)
+
+
 @functools.partial(jax.jit, static_argnames=())
 @jax.named_scope(KERNEL_NAME)
 def _apply_bits(b: jax.Array, data: jax.Array) -> jax.Array:
-    """b: (8m, 8k) f32; data: (k, n) uint8 -> (m, n) uint8."""
+    """b: (8m, 8k) f32; data: (k, n) uint8 -> (m, n) uint8, or int32
+    words -> words (`_lanes`): the four bytes of a word go through the
+    one matmul on an axis of their own, so the columns stay columns (a
+    column-sharded batch needs no collective)."""
+    words = data.dtype == jnp.int32
+    if words:
+        data = jnp.stack(
+            [((data >> (8 * i)) & 0xFF).astype(jnp.uint8) for i in range(4)],
+            axis=1,
+        )  # (k, 4, n/4)
     k = data.shape[0]
     m = b.shape[0] // 8
-    bits = (data[:, None, :] >> jnp.arange(8, dtype=jnp.uint8)[None, :, None]) & 1
-    bits = bits.reshape(8 * k, -1).astype(_ACC_DTYPE)
-    acc = jnp.matmul(b, bits, preferred_element_type=_ACC_DTYPE)
-    pbits = acc.astype(jnp.int32) & 1
-    pbits = pbits.reshape(m, 8, -1)
-    out = (pbits << jnp.arange(8, dtype=jnp.int32)[None, :, None]).sum(
-        axis=1, dtype=jnp.int32
-    )
+    rest = data.shape[1:]
+    bit = jnp.arange(8, dtype=jnp.uint8).reshape((1, 8) + (1,) * len(rest))
+    bits = ((data[:, None] >> bit) & 1).reshape((8 * k,) + rest).astype(_ACC_DTYPE)
+    acc = jnp.tensordot(b, bits, axes=1, preferred_element_type=_ACC_DTYPE)
+    pbits = (acc.astype(jnp.int32) & 1).reshape((m, 8) + rest)
+    out = (pbits << bit.astype(jnp.int32)).sum(axis=1, dtype=jnp.int32)
+    if words:
+        return functools.reduce(
+            jnp.bitwise_or, [out[:, i] << (8 * i) for i in range(4)]
+        )
     return out.astype(jnp.uint8)
 
 
@@ -198,8 +219,9 @@ class RSJax:
         return _apply_bits(bits, data)
 
     def encode(self, data) -> jax.Array:
-        """(k, n) uint8 data shards -> (m, n) uint8 parity shards."""
-        data = jnp.asarray(data, dtype=jnp.uint8)
+        """(k, n) uint8 data shards -> (m, n) uint8 parity shards (or
+        words -> words: `_lanes`)."""
+        data = _lanes(data)
         if data.shape[0] != self.k:
             raise ValueError(f"expected {self.k} data rows, got {data.shape[0]}")
         return self._apply(self._parity_bits, data, self.m)
@@ -245,10 +267,7 @@ class RSJax:
         if not missing:
             return {}
         src = present[: self.k]
-        # the parts of the caller's `reconstruct` stage (utils/trace.py)
-        trace.lap("put")
         data = jnp.stack([jnp.asarray(shards[i], dtype=jnp.uint8) for i in src])
-        trace.lap("launch")
         bits = self._rows_bits(missing, src)
         out = self._apply(bits, data, len(missing))
         return {idx: out[i] for i, idx in enumerate(missing)}
@@ -283,7 +302,7 @@ class RSJax:
                 f"coeffs {coeffs.shape} do not match {len(data)} data rows"
             )
         bits = jnp.asarray(self.coeff_bits(coeffs))
-        return self._apply(bits, jnp.asarray(data, dtype=jnp.uint8), coeffs.shape[0])
+        return self._apply(bits, _lanes(data), coeffs.shape[0])
 
     def verify(self, shards) -> bool:
         shards = jnp.asarray(shards, dtype=jnp.uint8)

@@ -27,6 +27,19 @@ while interpret mode (true f32) passes. Consequences baked in here:
 - pack_width=2 f32 dots force precision=HIGHEST (exact, slower);
 - pack_width=4 would need >24-bit exact accumulation — rejected.
 
+Words in, words out. A (rows, n) uint8 array lives on the chip four
+ROWS to a 32-bit word (`T(4,128)(4,1)`): a two-row result is half
+holes, a one-row result three quarters, and the holes cross the host
+link with the bytes; a dense one still pays a pass that picks the four
+rows of each word apart (PERF.md section 6, PR 30: 90 ms for a
+(2, 16 MiB) uint8 result, 169 for (1, 32 MiB), 49 for (4, 8 MiB), 11
+for the same bytes as (2, 4 Mi) int32). So both kernels also take the
+batch as int32 words of four consecutive bytes (the host's free
+`.view(np.int32)` of the same rows) and return words: the kernel body
+runs once per byte of the word (`_each_byte`), the MXU work per byte is
+the same, and no lane is shuffled. ec/backend.py stages every batch
+that way; a uint8 array handed in directly is computed as before.
+
 Reference hot loop being replaced:
 weed/storage/erasure_coding/ec_encoder.go:427 (encodeDataOneBatch).
 """
@@ -53,8 +66,25 @@ _WORD_DTYPES = {1: jnp.uint8, 2: jnp.uint16, 4: jnp.uint32}
 _MASKS = {1: 0x01, 2: 0x0101, 4: 0x01010101}
 
 
+def _each_byte(d_ref, out_ref, body) -> None:
+    """`out_ref[:] = body(tile)`, where `body` maps an int32 tile whose
+    lanes hold bytes (or uintW words of packed bytes) to the same.
+    Lanes that are int32 words of four bytes take one pass per byte,
+    low to high: `body` reads bit j as `(x >> j) & mask`, so the bytes
+    above the one it is handed do not reach its result."""
+    d = d_ref[:].astype(jnp.int32)
+    if d_ref.dtype != jnp.int32:
+        out_ref[:] = body(d).astype(out_ref.dtype)
+        return
+    out = body(d)
+    for byte in range(1, 4):
+        out = out | (body(d >> (8 * byte)) << (8 * byte))
+    out_ref[:] = out
+
+
 def _rs_kernel(k: int, m: int, pack_width: int, b_ref, d_ref, out_ref):
-    """b_ref: (8m, 8k) bit-major; d_ref: (k, TN) uintW words.
+    """b_ref: (8m, 8k) bit-major; d_ref: (k, TN) uintW words, or int32
+    words of four bytes (`_each_byte`).
 
     One contraction-(8k) dot per tile, not 8 contraction-k dots: the MXU
     is weight-stationary, so contraction length is utilization (80/128
@@ -71,34 +101,37 @@ def _rs_kernel(k: int, m: int, pack_width: int, b_ref, d_ref, out_ref):
             "the TPU MXU does not provide (int32 dots unsupported, f32 "
             "dots are inexact past 2^24)"
         )
-    d = d_ref[:].astype(jnp.int32)
-    planes = jnp.concatenate([(d >> j) & mask for j in range(8)], axis=0)
-    if pack_width == 1:
-        # 0/1 planes fit int8: exact integer MXU path, ~2x f32 rate.
-        acc = jax.lax.dot_general(
-            b_ref[:].astype(jnp.int8),
-            planes.astype(jnp.int8),
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.int32,
-        )
-        acci = acc
-    else:
-        # Packed sums reach 8k * 0x0101 (~20k): exact only if the MXU
-        # really accumulates f32 — HIGHEST forces the multi-pass f32
-        # path (default precision runs bf16 passes and corrupts the low
-        # byte of every word; caught by the bit-exactness suite).
-        acc = jax.lax.dot_general(
-            b_ref[:].astype(jnp.float32),
-            planes.astype(jnp.float32),
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-            precision=jax.lax.Precision.HIGHEST,
-        )
-        acci = acc.astype(jnp.int32)
-    out = jnp.zeros((m, d.shape[1]), dtype=jnp.int32)
-    for i in range(8):
-        out = out | ((acci[i * m : (i + 1) * m] & mask) << i)
-    out_ref[:] = out.astype(_WORD_DTYPES[pack_width])
+
+    # 0/1 planes fit int8: exact integer MXU path, ~2x f32 rate. Packed
+    # sums reach 8k * 0x0101 (~20k): exact only if the MXU really
+    # accumulates f32 — HIGHEST forces the multi-pass f32 path (default
+    # precision runs bf16 passes and corrupts the low byte of every
+    # word; caught by the bit-exactness suite).
+    b = b_ref[:].astype(jnp.int8 if pack_width == 1 else jnp.float32)
+
+    def body(d):
+        planes = jnp.concatenate([(d >> j) & mask for j in range(8)], axis=0)
+        if pack_width == 1:
+            acci = jax.lax.dot_general(
+                b,
+                planes.astype(jnp.int8),
+                dimension_numbers=(((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.int32,
+            )
+        else:
+            acci = jax.lax.dot_general(
+                b,
+                planes.astype(jnp.float32),
+                dimension_numbers=(((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+                precision=jax.lax.Precision.HIGHEST,
+            ).astype(jnp.int32)
+        out = jnp.zeros((m, d.shape[1]), dtype=jnp.int32)
+        for i in range(8):
+            out = out | ((acci[i * m : (i + 1) * m] & mask) << i)
+        return out
+
+    _each_byte(d_ref, out_ref, body)
 
 
 @jax.named_scope(KERNEL_NAME)
@@ -120,13 +153,17 @@ def _pallas_apply(
     `out_rows` is the kernel's output block height (possibly padded);
     `keep_rows` is how many real parity rows the caller gets back.
     n is padded to a tile multiple internally (RS of zero bytes is zero,
-    so padding never corrupts real columns).
+    so padding never corrupts real columns). int32 `data` is the batch
+    as words of four bytes and comes back as words; `pack_width` packs
+    uint8 `data` only.
     """
     if pack_width not in _WORD_DTYPES:
         raise ValueError(f"pack_width must be 1, 2 or 4, got {pack_width}")
+    words_in = data.dtype == jnp.int32
+    if words_in:
+        pack_width = 1  # a lane is a word already; the kernel walks its bytes
     n = data.shape[1]
-    bytes_per_tile = tile_n * pack_width
-    pad = (-n) % bytes_per_tile
+    pad = (-n) % (tile_n * pack_width)
     if pad:
         data = jnp.pad(data, ((0, 0), (0, pad)))
     n_padded = data.shape[1]
@@ -137,13 +174,13 @@ def _pallas_apply(
         )
     else:
         words = data
+    lane_dtype = jnp.int32 if words_in else _WORD_DTYPES[pack_width]
+    n_bytes = n_padded * (4 if words_in else 1)
     grid = (words.shape[1] // tile_n,)
     zeros = (0,) * len(b_block)
     out_words = pl.pallas_call(
         kernel,
-        out_shape=jax.ShapeDtypeStruct(
-            (out_rows, words.shape[1]), _WORD_DTYPES[pack_width]
-        ),
+        out_shape=jax.ShapeDtypeStruct((out_rows, words.shape[1]), lane_dtype),
         grid=grid,
         in_specs=[
             pl.BlockSpec(b_block, lambda i: zeros),
@@ -153,8 +190,8 @@ def _pallas_apply(
         interpret=interpret,
         name=KERNEL_NAME,
         cost_estimate=pl.CostEstimate(
-            flops=2 * 8 * out_rows * 8 * k * words.shape[1],
-            bytes_accessed=(k + out_rows) * n_padded + 64 * out_rows * k * 4,
+            flops=2 * 8 * out_rows * 8 * k * n_bytes,
+            bytes_accessed=(k + out_rows) * n_bytes + 64 * out_rows * k * 4,
             transcendentals=0,
         ),
     )(b.astype(jnp.float32), words)
@@ -268,32 +305,37 @@ def _rs_kernel_aligned(k: int, m_pad: int, pack_width: int, b_ref, d_ref, out_re
         raise NotImplementedError(
             "pack_width=4 needs >24-bit exact matmul accumulation"
         )
-    d = d_ref[:].astype(jnp.int32)
-    planes = jnp.concatenate([(d >> j) & mask for j in range(8)], axis=0)
-    b2 = b_ref[:].reshape(8 * k, 8 * m_pad)  # rows j*k+c match plane order
-    if pack_width == 1:
-        acc = jax.lax.dot_general(
-            b2.astype(jnp.int8),
-            planes.astype(jnp.int8),
-            dimension_numbers=(((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.int32,
-        )
-        acci = acc
-    else:
-        # Packed sums exceed 8 bits: the MXU's default bf16 passes would
-        # corrupt them — force the exact multi-pass f32 path.
-        acc = jax.lax.dot_general(
-            b2.astype(jnp.float32),
-            planes.astype(jnp.float32),
-            dimension_numbers=(((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-            precision=jax.lax.Precision.HIGHEST,
-        )
-        acci = acc.astype(jnp.int32)
-    out = jnp.zeros((m_pad, d.shape[1]), dtype=jnp.int32)
-    for i in range(8):
-        out = out | ((acci[i * m_pad : (i + 1) * m_pad] & mask) << i)
-    out_ref[:] = out.astype(_WORD_DTYPES[pack_width])
+
+    # rows j*k+c match plane order. Packed sums exceed 8 bits: the MXU's
+    # default bf16 passes would corrupt them — HIGHEST forces the exact
+    # multi-pass f32 path.
+    b2 = b_ref[:].reshape(8 * k, 8 * m_pad).astype(
+        jnp.int8 if pack_width == 1 else jnp.float32
+    )
+
+    def body(d):
+        planes = jnp.concatenate([(d >> j) & mask for j in range(8)], axis=0)
+        if pack_width == 1:
+            acci = jax.lax.dot_general(
+                b2,
+                planes.astype(jnp.int8),
+                dimension_numbers=(((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.int32,
+            )
+        else:
+            acci = jax.lax.dot_general(
+                b2,
+                planes.astype(jnp.float32),
+                dimension_numbers=(((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+                precision=jax.lax.Precision.HIGHEST,
+            ).astype(jnp.int32)
+        out = jnp.zeros((m_pad, d.shape[1]), dtype=jnp.int32)
+        for i in range(8):
+            out = out | ((acci[i * m_pad : (i + 1) * m_pad] & mask) << i)
+        return out
+
+    _each_byte(d_ref, out_ref, body)
 
 
 @functools.partial(

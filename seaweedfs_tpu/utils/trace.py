@@ -78,8 +78,9 @@ Sub-stages: ``h2d_dispatch`` = ``.stage`` (contiguous host copy /
 column padding) + ``.put`` (``jax.device_put``) + ``.launch`` (the
 jitted apply: coefficient bits, cache look-up, enqueue);
 ``device_drain`` = ``.ready`` (blocked until the result exists on the
-device: upload, kernel, device queue) + ``.d2h`` (the copy into a numpy
-array) + ``.host_copy`` (``np.ascontiguousarray`` where it copies);
+device: upload, kernel, device queue) + ``.d2h`` (what is left of the
+copy home, which the launch asked for, into a numpy array)
++ ``.host_copy`` (``np.ascontiguousarray`` where it copies);
 ``reconstruct`` (single-shot degraded read) = ``.put`` (the sibling
 matrix's one ``device_put``) + ``.launch`` + ``.ready`` + ``.d2h``;
 ``volume.read`` (a needle GET of an EC volume) = ``.index`` (the
@@ -215,6 +216,12 @@ _seam_counters = {
     "d2h_bytes": _M.REGISTRY.counter(
         "sw_ec_d2h_bytes_total",
         "bytes fetched from the device by EC operations (tracer armed only)",
+        ("op",),
+    ),
+    "d2h_dense_bytes": _M.REGISTRY.counter(
+        "sw_ec_d2h_dense_bytes_total",
+        "of sw_ec_d2h_bytes_total, the bytes that came home as dense 32-bit "
+        "words (a uint8 result crosses with its sublane holes)",
         ("op",),
     ),
     "batches": _M.REGISTRY.counter(
@@ -884,9 +891,10 @@ def lap(part: str) -> None:
 
 def count(name: str, n: int) -> None:
     """Add `n` to the seam counter `name` (``h2d_bytes``, ``d2h_bytes``,
-    ``batches``): an attribute of the span whose stage the calling
-    thread has open, and ``sw_ec_*_total{op}`` on /metrics. Nothing
-    when disarmed (one module-bool check) or with no stage open."""
+    ``d2h_dense_bytes``, ``batches``): an attribute of the span whose
+    stage the calling thread has open, and ``sw_ec_*_total{op}`` on
+    /metrics. Nothing when disarmed (one module-bool check) or with no
+    stage open."""
     if not armed:
         return
     parent = _open_stage.get()

@@ -93,6 +93,27 @@ def test_pallas_compact_compiles(one_chip, k, m_out, width):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+# a degraded GET's one-row result beside them: (k, rows out, width)
+WORD_SHAPES = SHAPES + [pytest.param(10, 1, 448 << 10, id="get1@448KiB")]
+
+
+@pytest.mark.parametrize("k,m_out,width", WORD_SHAPES)
+def test_pallas_compact_compiles_on_words(one_chip, k, m_out, width):
+    """The form in which `JaxBackend` stages every batch: int32 words
+    of four bytes in, words out, and the result dense on the chip (a
+    uint8 result is laid out four rows to a word, `(4,1)`)."""
+    _, bits, _ = _shapes(one_chip, k, m_out, width)
+    words = jax.ShapeDtypeStruct((k, width // 4), jnp.int32, sharding=one_chip)
+    compiled = rs_pallas.apply_bitmajor_pallas.lower(
+        bits, words, k=k, m=m_out
+    ).compile()
+    root = next(
+        ln for ln in compiled.as_text().splitlines()
+        if "tpu_custom_call" in ln and "sw_rs_apply" in ln
+    )
+    assert f"s32[{m_out},{width // 4}]" in root and "(4,1)" not in root.split("custom-call")[0]
+
+
 @pytest.mark.parametrize("k,m_out,width", SHAPES)
 def test_pallas_aligned_compiles(one_chip, k, m_out, width):
     data, _, planes = _shapes(one_chip, k, m_out, width)
@@ -116,7 +137,8 @@ def test_mesh_encode_and_apply_compile_over_four_chips(mesh4):
     mrs = MeshRS(rs, mesh4)
     assert not mrs.pod_sharded
     cols = NamedSharding(mesh4, P(None, BLOCK_AXIS))
-    data = jax.ShapeDtypeStruct((10, 16 * MIB), jnp.uint8, sharding=cols)
+    # as `JaxBackend.to_device` puts a batch: int32 words, column-sharded
+    data = jax.ShapeDtypeStruct((10, 4 * MIB), jnp.int32, sharding=cols)
     enc = mrs._encode.lower(data).compile()
     assert "tpu_custom_call" in enc.as_text()
     # every chip holds a quarter of the columns, nothing is gathered
