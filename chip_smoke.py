@@ -47,7 +47,7 @@ STAGED_SHAPES = [
     ("10+4 rebuild2 @16MiB", 10, 4, 16 * MIB, "rebuild"),
     ("4+2 encode @256KiB", 4, 2, 256 << 10, "encode"),
 ]
-IMPLS = ("pallas", "pallas_aligned", "xla")
+IMPLS = ("pallas", "xla")
 
 
 def emit(doc: dict) -> None:

@@ -137,7 +137,7 @@ class ChipPool:
 
     `devices` is any sequence of placement targets and `make_chip(dev)`
     builds the backend for one of them — the routing/load core is
-    plain Python (bench --self-check exercises it without jax).
+    plain Python (tests/test_chip_placement.py exercises it without jax).
 
     Load accounting is per placed STREAM: `acquire(cost_hint)` charges
     the stream's estimated total cost (rows x bytes it will dispatch)

@@ -111,7 +111,7 @@ lives:
 telemetry + /status + /cluster/status) and per-tenant shed counters
 surface the whole state. ``SEAWEED_EC_RESIDENCY_WINDOW=0`` disables
 the global ledger (each scope back to its private window only);
-tests/bench inject private ledgers via ``QueueScope(residency=...)``.
+tests inject private ledgers via ``QueueScope(residency=...)``.
 """
 
 from __future__ import annotations
@@ -134,9 +134,10 @@ PRIORITIES = ("foreground", "recovery", "scrub")
 
 # Minimum admitted-cost share per background class under saturation.
 # Small on purpose: this is a SERVING store — repair proceeds, but
-# foreground keeps ~90% of the chip when it wants it (the bench
-# acceptance bar is foreground >= 85% of isolated throughput with a
-# concurrent rebuild stream still making progress).
+# foreground keeps ~90% of the chip when it wants it, with a
+# concurrent rebuild stream still making progress
+# (tests/test_device_queue.py; no cell has made the queue wait yet:
+# ROADMAP Design 7).
 DEFAULT_SHARES = {"recovery": 0.10, "scrub": 0.02}
 
 # Default bound on in-flight device batches across ALL streams of one
@@ -649,7 +650,7 @@ class DeviceQueue:
 
 # --------------------------------------------------------------------------
 # Residency: the physical admission layer under the scopes. ONE ledger
-# per process (or one injected per test/bench), ONE lock for all chips
+# per process (or one injected per test), ONE lock for all chips
 # — a mesh-wide stream acquires every chip it spans atomically, with no
 # per-chip lock ordering to deadlock on.
 # --------------------------------------------------------------------------
@@ -730,7 +731,7 @@ class ResidencyLedger:
     shed policy. Every DeviceQueue charges it in a second admission
     phase (after its own scope window), so the per-scope windows become
     sub-budgets of the chip's physical bound. See the module docstring
-    for the policy; `budget`/`clock` are injectable for tests/bench."""
+    for the policy; `budget`/`clock` are injectable for tests."""
 
     def __init__(
         self,
@@ -1226,7 +1227,7 @@ class QueueScope:
     selects the physical ledger the scope's queues charge: None = the
     process-wide default (env-gated), False = no physical ledger (the
     pre-PR 16 logical-window-only behavior), or an injected
-    ResidencyLedger (tests/bench)."""
+    ResidencyLedger (tests)."""
 
     def __init__(
         self,

@@ -184,7 +184,8 @@ class EcVolume:
         self._coeff_cache: dict[tuple, np.ndarray] = {}
         # Observability: total bytes pread/fetched to serve reads
         # (sibling reads during recovery dominate under degraded
-        # serving — the bench derives read amplification from this).
+        # serving); rides the heartbeat's heat blob and survives a
+        # restart in the `.heat` sidecar.
         self.bytes_read = 0
         # Bytes of shard content produced by RS reconstruction (the
         # degraded-read work). Rides the heartbeat telemetry blob as

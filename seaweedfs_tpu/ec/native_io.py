@@ -58,7 +58,7 @@ def _native_mod():
 def enabled() -> bool:
     """True when the native data plane should carry reads/writes:
     the .so loaded and SEAWEED_EC_NATIVE != 0 (checked live so tests
-    and benches can flip the env per call)."""
+    can flip the env per call)."""
     if os.environ.get("SEAWEED_EC_NATIVE", "1") == "0":
         return False
     return _native_mod() is not None

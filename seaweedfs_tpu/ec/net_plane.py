@@ -1163,8 +1163,10 @@ class NetPlaneClient:
     ) -> bytes:
         """Python-plane fetch over the same wire: materializes the
         payload as `bytes` (counted against the python plane's
-        copied/received totals). Used by granule re-reads and by the
-        bench's same-transport Python-plane comparison."""
+        copied/received totals). Nothing in the package calls it: only
+        tests/test_native_net_plane.py does, for its same-wire
+        Python-plane comparison and the generation fence (ROADMAP
+        Design 4)."""
         with self._addr_lock(addr):
             s, _n = self._request(addr, vid, sid, gen, off, size)
             try:
@@ -1186,7 +1188,7 @@ class NetPlaneClient:
         buffer in `chunk`-sized pieces. Returns bytes written. The wire
         bytes are attributed to the native plane
         (`sw_net_bytes_received_total{plane=native}` — or python when
-        the .so is absent), which is the bench's migration evidence.
+        the .so is absent).
         Raises :class:`NetPlaneUnavailable` (memoized) for peers
         without the sidecar and :class:`NetPlaneError` for refusals
         (stale generation, shard not local) — callers fall back to the

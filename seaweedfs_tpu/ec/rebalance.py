@@ -27,7 +27,7 @@ gravity layer for EXISTING bytes:
 
 The planner is pure (NodeViews + heat dicts in, Migrations out) so it
 is testable against synthetic skew the way ``plan_ec_balance`` is; the
-driver takes gRPC stubs through a resolver so tests/bench drive real
+driver takes gRPC stubs through a resolver so tests drive real
 in-process servers.
 """
 
@@ -252,8 +252,8 @@ def plan_hot_migrations(
 
 
 # ---------------------------------------------------------------------------
-# Driver — the ec_migrate worker task body (also driven by the bench
-# and the crash-rerun tests).
+# Driver — the ec_migrate worker task body (also driven by the
+# crash-rerun tests).
 # ---------------------------------------------------------------------------
 
 

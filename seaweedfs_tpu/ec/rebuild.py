@@ -102,7 +102,6 @@ def rebuild_ec_files(
     unsafe_ignore_sidecar: bool = False,
     batch_size: int = DEFAULT_BATCH,
     only_shards: list[int] | None = None,
-    staged: bool = True,
     priority: str = "recovery",
     scheduler=None,
 ) -> list[int]:
@@ -112,12 +111,9 @@ def rebuild_ec_files(
     subset-holding server must not mint local copies of shards placed on
     peers); present-but-corrupt shards are always replaced regardless.
 
-    `staged` (default) dispatches each batch through the backend's
-    staged apply (async H2D + device compute, D2H forced in the writer
-    thread) so a device rebuild overlaps transfer with compute like
-    `encode_staged`; False keeps the synchronous per-batch `apply` —
-    bit-identical by construction, kept for the bench's staged-vs-sync
-    comparison.
+    Each batch goes through the backend's staged apply (async H2D +
+    device compute, D2H forced in the writer thread), so a device
+    rebuild overlaps transfer with compute like `encode_staged`.
 
     `priority` tags the staged stream's class on the shared per-chip
     scheduler (ec/device_queue.py): "recovery" by default (rebuild and
@@ -184,7 +180,7 @@ def rebuild_ec_files(
     try:
         return _rebuild_ec_files_traced(
             base, ctx, backend, unsafe_ignore_sidecar, batch_size,
-            prot, present, missing, staged, priority, scheduler, sp,
+            prot, present, missing, priority, scheduler, sp,
         )
     finally:
         trace.finish(sp)
@@ -192,7 +188,7 @@ def rebuild_ec_files(
 
 def _rebuild_ec_files_traced(
     base, ctx, backend, unsafe_ignore_sidecar, batch_size,
-    prot, present, missing, staged, priority, scheduler, sp,
+    prot, present, missing, priority, scheduler, sp,
 ) -> list[int]:
     total, k = ctx.total, ctx.data_shards
 
@@ -319,7 +315,6 @@ def _rebuild_ec_files_traced(
                 prot is not None and not chaos and not unsafe_ignore_sidecar
             ),
             verified_ok=verified_ok,
-            staged=staged,
             priority=priority,
             scheduler=scheduler,
             span=sp,
@@ -344,7 +339,6 @@ def _attempt_rebuild(
     chaos: bool,
     inline_verify: bool,
     verified_ok: set[int] | None = None,
-    staged: bool = True,
     priority: str = "recovery",
     scheduler=None,
     span=None,
@@ -435,10 +429,10 @@ def _attempt_rebuild(
         # Fused path: read all k sources into one (k, width) matrix
         # (inline CRC rolled while cache-hot), then a single
         # precomputed-coefficient GF(256) apply per batch — no per-batch
-        # matrix inversion, no stack copy, no dict plumbing. The staged
-        # variant dispatches that apply through the backend's async
-        # hooks (run_staged_apply), so on a device batch N computes
-        # while N+1 uploads and N-1 drains to disk.
+        # matrix inversion, no stack copy, no dict plumbing. The apply
+        # is dispatched through the backend's async hooks
+        # (run_staged_apply), so on a device batch N computes while
+        # N+1 uploads and N-1 drains to disk.
         rs = gf256.ReedSolomon(ctx.data_shards, ctx.parity_shards)
         coeffs = _decode_coeffs(rs.matrix, k, tuple(targets), tuple(src))
 
@@ -486,10 +480,6 @@ def _attempt_rebuild(
                             rollers[i].update(buf[row])
                 yield off, buf
 
-        def transform(item):
-            off, buf = item
-            return off, backend.apply(coeffs, buf)
-
         def consume(_off, out):
             out = np.ascontiguousarray(out, dtype=np.uint8)
             sink.append_rows([out[p] for p in range(len(targets))])
@@ -534,11 +524,11 @@ def _attempt_rebuild(
         # the reconstruct stage (device dispatch in the calling thread,
         # result forced in the writer thread).
         join_timeout = 60.0 + 4.0 * batch_size / (16 << 20)
-        if chaos or not staged:
+        if chaos:
             run_pipeline(
                 produce,
                 transform,
-                consume if chaos else (lambda item: consume(*item)),
+                consume,
                 join_timeout=join_timeout,
                 describe="ec rebuild pipeline",
                 span=span,

@@ -31,8 +31,7 @@ stripes of ``k x block_size`` (row ``i`` of stripe ``s`` lands in shard
 small-chunk plan. N appends through the stream therefore produce
 byte-identical shard files and sidecar CRCs to ONE `write_ec_files`
 over the concatenation with the same block parameters (asserted
-cross-backend in tests/test_ec_stream_encode.py and in the
-`streaming_encode` bench line).
+cross-backend in tests/test_ec_stream_encode.py).
 
 Durability protocol (the stripe-cursor journal)
 -----------------------------------------------

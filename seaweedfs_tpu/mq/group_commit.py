@@ -24,8 +24,7 @@ failure to a per-partition ``KAFKA_STORAGE_ERROR``.
 
 ``SEAWEED_MQ_GROUP_COMMIT_MS`` is read live per produce (0 disables
 group commit: acks rely on the parity sweeper's lag bound instead of a
-synchronous flush), so bench phases flip it without restarting the
-broker.
+synchronous flush), so it changes without restarting the broker.
 """
 
 from __future__ import annotations

@@ -28,8 +28,9 @@ generalized to the Kafka wire format (i32 length prefix | frame):
   falling back to plain socket writes emitting the SAME wire bytes.
 
 ``workers=0`` opts out to :class:`NaiveFrameServer`, the original
-thread-per-connection shape (kept as the bench baseline — the thing
-``mq_sustained`` measures the pool against).
+thread-per-connection shape. Nothing measures the pool against it any
+more and no test selects it: a twin whose excuse is gone (ROADMAP
+Design 4).
 """
 
 from __future__ import annotations
@@ -562,7 +563,7 @@ class PooledFrameServer:
 
 class NaiveFrameServer:
     """The original thread-per-connection accept loop, kept behind
-    ``SEAWEED_MQ_KAFKA_WORKERS=0`` as the measured baseline. Frame
+    ``SEAWEED_MQ_KAFKA_WORKERS=0`` (ROADMAP Design 4). Frame
     reads still go through the capped/timed `read_frame` (hygiene is
     not optional), but there is no admission budget, no parking, no
     backpressure — every connection owns a thread for life."""

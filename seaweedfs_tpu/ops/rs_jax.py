@@ -144,11 +144,10 @@ class RSJax:
         interpret: bool = False,
         tile_n: int | None = None,
     ):
-        """impl: "xla" (portable), "pallas" (fused TPU kernel, compact
-        layout, 1x HBM traffic), or "pallas_aligned" (lane-aligned
-        Mosaic-conservative layout — see rs_pallas.py); `interpret=True`
-        runs the pallas kernels off-TPU for tests."""
-        if impl not in ("xla", "pallas", "pallas_aligned"):
+        """impl: "xla" (portable) or "pallas" (fused TPU kernel, 1x HBM
+        traffic — see rs_pallas.py); `interpret=True` runs the pallas
+        kernel off-TPU for tests."""
+        if impl not in ("xla", "pallas"):
             raise ValueError(f"unknown impl {impl!r}")
         self.k = data_shards
         self.m = parity_shards
@@ -158,22 +157,14 @@ class RSJax:
         self.tile_n = tile_n
         self._ref = gf256.ReedSolomon(data_shards, parity_shards)
         self.matrix = self._ref.matrix
-        if impl == "pallas":
-            expand = bit_matrix_bitmajor
-        elif impl == "pallas_aligned":
-            from . import rs_pallas
-
-            expand = rs_pallas.bit_matrix_planes
-        else:
-            expand = bit_matrix
-        self._expand = expand
+        self._expand = bit_matrix_bitmajor if impl == "pallas" else bit_matrix
         # numpy, not a device array: the chips of a pool share one codec
         # (ec/chip_pool.py), and an array committed to one device would
         # pin every dispatch there. jit converts at call time; the
         # matrix is tiny (8m x 8k floats), so the per-call transfer is
         # noise.
         self._parity_bits = np.asarray(
-            expand(self._ref.parity), dtype=_ACC_DTYPE
+            self._expand(self._ref.parity), dtype=_ACC_DTYPE
         )
         # Bounded: shard-loss patterns are diverse in a long-lived volume
         # server; each entry pins an (8m x 8k) bit-matrix.
@@ -197,18 +188,13 @@ class RSJax:
     # -- encode ------------------------------------------------------------
 
     def _apply(self, bits: np.ndarray, data: jax.Array, m_out: int) -> jax.Array:
-        if self.impl in ("pallas", "pallas_aligned"):
+        if self.impl == "pallas":
             from . import rs_pallas
 
             kwargs = {}
             if self.tile_n is not None:
                 kwargs["tile_n"] = self.tile_n
-            fn = (
-                rs_pallas.apply_planes_pallas
-                if self.impl == "pallas_aligned"
-                else rs_pallas.apply_bitmajor_pallas
-            )
-            return fn(
+            return rs_pallas.apply_bitmajor_pallas(
                 bits,
                 data,
                 k=int(data.shape[0]),

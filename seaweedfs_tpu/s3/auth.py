@@ -148,8 +148,8 @@ def _memo_count(result: str) -> None:
 
 
 def auth_cache_stats() -> dict:
-    """Signing-key / verdict-memo occupancy for status surfaces and the
-    bench's counter evidence."""
+    """Signing-key / verdict-memo occupancy. No status surface reads it:
+    only tests/test_warm_path.py does."""
     with _skey_lock:
         skeys = len(_skey_cache)
     with _memo_lock:
@@ -177,9 +177,9 @@ def signing_key(secret: str, date: str, region: str, service: str = "s3") -> byt
     """Derived SigV4 signing key, cached per (secret, date, region,
     service) — a pure function, so the cache can never go stale; a
     rotated secret is simply a different key.
-    ``SEAWEED_S3_AUTH_MEMO=0`` disables this cache too (it is the
-    master off-switch for the whole SigV4 fast path, giving benches a
-    true per-request-derivation baseline)."""
+    ``SEAWEED_S3_AUTH_MEMO=0`` disables this cache too: it is the
+    master off-switch for the whole SigV4 fast path (a user-set
+    switch with no measurement behind it: ROADMAP Design 4)."""
     if _memo_capacity() <= 0:
         return _derive_signing_key(secret, date, region, service)
     ck = (secret, date, region, service)
@@ -214,9 +214,9 @@ def sign_v4(
     a canonical-request change lands in one place for both directions.
     Signs `headers` (plus x-amz-date / x-amz-content-sha256, which are
     always added and signed) and returns a new dict with the
-    Authorization header merged in. Used by the bench's warm-GET
-    phases and the warm-path tests; tests/test_s3.py keeps its own
-    independent signer as the cross-implementation check."""
+    Authorization header merged in. Used by the warm-path tests;
+    tests/test_s3.py keeps its own independent signer as the
+    cross-implementation check."""
     h = {k.lower(): v for k, v in (headers or {}).items()}
     if amz_date is None:
         amz_date = datetime.now(timezone.utc).strftime("%Y%m%dT%H%M%SZ")

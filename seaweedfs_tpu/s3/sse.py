@@ -23,7 +23,7 @@ import hashlib
 import os
 
 # `cryptography` is an optional dependency: the S3 gateway itself (and
-# the read-path bench/tests) must import without it — only the SSE
+# the read-path tests) must import without it — only the SSE
 # features need the cipher, and they raise NotImplemented when it is
 # absent instead of poisoning the whole gateway import.
 try:

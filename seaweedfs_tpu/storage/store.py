@@ -31,8 +31,8 @@ def durable_writes_default() -> bool:
     durable before it is acked (fsync — per needle, or amortized over a
     group-commit window when SEAWEED_VOLUME_GROUP_COMMIT_MS > 0).
     Default 0 keeps the historical contract: an acked write survives
-    SIGKILL (kernel flush) but not power loss. Read live per write so
-    the bench's phases flip it without restarting servers."""
+    SIGKILL (kernel flush) but not power loss. Read live per write, so
+    it changes without restarting servers."""
     return os.environ.get("SEAWEED_VOLUME_FSYNC", "0") == "1"
 
 

@@ -44,8 +44,8 @@ from .types import (
 
 def _group_commit_window_s() -> float:
     """SEAWEED_VOLUME_GROUP_COMMIT_MS as seconds (0 = fsync-per-needle,
-    the default). Read live per write so the bench's on/off phases flip
-    it without reopening volumes."""
+    the default). Read live per write, so an operator (and
+    tests/test_group_commit.py) changes it without reopening volumes."""
     try:
         ms = float(os.environ.get("SEAWEED_VOLUME_GROUP_COMMIT_MS", "0"))
     except ValueError:
@@ -326,8 +326,7 @@ class Volume:
     def _group_committer(self) -> "_GroupCommitter | None":
         """The active group committer, (re)built lazily from the live
         SEAWEED_VOLUME_GROUP_COMMIT_MS value — a window change mid-life
-        (the bench's on/off phases) swaps the committer instead of
-        freezing the open-time value. None when the window is 0
+        swaps the committer instead of freezing the open-time value. None when the window is 0
         (fsync-per-needle)."""
         w = _group_commit_window_s()
         c = self._committer

@@ -5,8 +5,8 @@ use the chip is the one that opens it: `local_devices()` asks JAX
 in-process, once, and every "is there a TPU?" decision in the package
 reads its cached answer. On a TPU the persistent compile cache is
 placed here too, before the first compile, so the servers, the worker,
-the smoke and the bench's device stages all share one cache directory
-by going through this module.
+the smoke and the benchmark's cells all share one cache directory by
+going through this module.
 """
 
 from __future__ import annotations

@@ -382,8 +382,8 @@ gateway_rejected_total = REGISTRY.counter(
 # the bit-identical fallback through Python buffers). The copied
 # counter tracks payload bytes MATERIALIZED into Python-level buffers
 # at the instrumented seams (gRPC chunk joins, wfile writes, pread
-# bytes) — bytes_copied_per_byte_served in bench.py is
-# copied(plane) / served(plane), ~0 for the native plane.
+# bytes): copied(plane) / served(plane) is the copies per byte served,
+# ~0 for the native plane.
 # `direction` (ISSUE 18) splits the read-serving path from the write
 # path (needle/blob WRITE opcode, replica fan-out, stream-shard push)
 # so the copies-per-byte derivation covers PUTs too.

@@ -1,12 +1,13 @@
 """Pipeline flight recorder: stage-attributed spans for the EC data path.
 
-The bench verdict (ROADMAP "bench reality check") is that e2e encode is
-I/O-bound while the device kernel is effectively free — but the only
-evidence is aggregate counters after the fact. This module attributes
-wall time to every STAGE of an EC operation (admission wait, queue
-wait, disk read, H2D dispatch, device drain, fused write+CRC sink,
-verify, publish/rename) and stitches the stages into one span tree per
-operation, across threads and — via gRPC metadata — across servers.
+An EC operation is bound by its host side while the device kernel is
+close to free (PERF.md section 5: the kernel is under 1 % of a
+rebuild), and aggregate counters after the fact do not say where. This
+module attributes wall time to every STAGE of an EC operation
+(admission wait, queue wait, disk read, H2D dispatch, device drain,
+fused write+CRC sink, verify, publish/rename) and stitches the stages
+into one span tree per operation, across threads and — via gRPC
+metadata — across servers.
 
 Model
 -----
@@ -40,8 +41,8 @@ Model
   to end. Every total that sums stages counts parents only.
 - Completed LOCAL ROOTS (spans with no local parent — including spans
   whose parent lives on another server) land in a bounded ring,
-  dumpable as Chrome ``trace_event`` JSON (``/debug/traces``,
-  ``bench.py --trace-out``; load the file in Perfetto / chrome://tracing).
+  dumpable as Chrome ``trace_event`` JSON (``/debug/traces``; load
+  the file in Perfetto / chrome://tracing).
 - Trace identity crosses RPC hops in gRPC metadata
   (:data:`TRACE_ID_KEY` / :data:`PARENT_SPAN_KEY`) alongside
   ``X-Request-ID``, so a fleet-dispatched peer-fetch rebuild yields ONE

@@ -140,6 +140,31 @@ def test_least_loaded_routing_under_skewed_costs():
     assert made == [0, 1, 2, 3]
 
 
+@pytest.mark.parametrize("seed", [0xA11, 0xA12, 0xA13])
+def test_routing_replays_the_documented_policy_for_seeded_arrivals(seed):
+    """Least outstanding cost, ties to the lowest index, replayed by
+    hand for a seeded arrival order with releases in between: the pool
+    must match it placement for placement, and drain to idle."""
+    rng = np.random.default_rng(seed)
+    pool = ChipPool(range(8), lambda d: f"chip{d}")
+    loads = [0] * 8
+    held = []
+    for cost in (int(c) for c in rng.integers(1, 1000, 48)):
+        want = min(range(8), key=lambda j: (loads[j], j))
+        got, be, release = pool.acquire(cost)
+        assert (got, be) == (want, f"chip{want}")
+        loads[want] += cost
+        held.append((want, cost, release))
+        if rng.random() < 0.3:  # a stream ends: its cost leaves its chip
+            j, c, rel = held.pop(int(rng.integers(len(held))))
+            rel()
+            loads[j] -= c
+        assert pool.loads() == loads
+    for _, _, rel in held:
+        rel()
+    assert pool.idle() and pool.loads() == [0] * 8
+
+
 def test_wide_lone_stream_keeps_mesh_competing_streams_get_chips():
     be = JaxBackend(CTX)
     pool = pool_for(be)

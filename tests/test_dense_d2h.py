@@ -39,12 +39,17 @@ def make_backend(kind):
         return JaxBackend(CTX, impl="xla", n_devices=1)
     if kind == "mesh":
         return JaxBackend(CTX, impl="xla")  # conftest forces 8 virtual devices
-    be = JaxBackend(CTX, impl=kind, interpret=True, n_devices=1)
+    # "pallas_mesh": the kernel inside shard_map over those devices, what
+    # a four-chip host builds by itself
+    be = JaxBackend(
+        CTX, impl="pallas", interpret=True,
+        n_devices=None if kind == "pallas_mesh" else 1,
+    )
     be._rs.tile_n = TILE
     return be
 
 
-@pytest.fixture(scope="module", params=["xla", "pallas", "pallas_aligned", "mesh"])
+@pytest.fixture(scope="module", params=["xla", "pallas", "pallas_mesh", "mesh"])
 def backend(request):
     return make_backend(request.param)
 

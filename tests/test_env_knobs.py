@@ -3,11 +3,7 @@ environment variable referenced in code must be documented in the
 README's "Env knob registry" — the `trace.STAGES` registry pattern
 applied to configuration, so a knob can't ship invisible.
 
-Scans quoted string literals in the package + bench.py (composed
-f-string prefixes like f"SEAWEED_BENCH_{name}_ATTEMPTS" are covered by
-the documented `SEAWEED_BENCH_<STAGE>_ATTEMPTS` wildcard and excluded
-from the literal scan by construction — a prefix ending in `_` never
-matches)."""
+Scans quoted string literals in the package."""
 
 import os
 import re
@@ -20,7 +16,7 @@ _KNOB = re.compile(r'["\'](SEAWEED_[A-Z0-9_]*[A-Z0-9])["\']')
 def _scan_sources() -> dict[str, set[str]]:
     pkg_root = seaweedfs_tpu.__path__[0]
     repo_root = os.path.dirname(pkg_root)
-    files = [os.path.join(repo_root, "bench.py")]
+    files: list[str] = []
     for dirpath, _dirnames, filenames in os.walk(pkg_root):
         if "__pycache__" in dirpath:
             continue
@@ -62,7 +58,7 @@ def test_every_env_knob_is_documented_in_readme():
         "SEAWEED_S3_AUTH_MEMO",
         "SEAWEED_EC_STREAM_BLOCK_KB",
         "SEAWEED_EC_STREAM_MAX_LAG_MS",
-        "SEAWEED_BENCH_VOLUME_MB",
+        "SEAWEED_EC_STREAM_FLUSH_KB",
     ):
         assert required in found, required
 

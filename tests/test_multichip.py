@@ -152,15 +152,14 @@ def test_parallel_pkg_mesh_helpers(mesh, rng):
     assert cks == int(expected.astype(np.uint64).sum() % (1 << 32))
 
 
-@pytest.mark.parametrize("impl", ["pallas", "pallas_aligned"])
-def test_pallas_kernel_inside_the_column_mesh(impl, rng):
+def test_pallas_kernel_inside_the_column_mesh(rng):
     """What a multi-chip TPU host builds by itself: the Pallas kernel
     wrapped in shard_map (here in interpret mode), encode and a 2-row
     rebuild apply, through the backend's staged surface."""
     from seaweedfs_tpu.ec.backend import CpuBackend, JaxBackend
     from seaweedfs_tpu.ec.context import DEFAULT_EC_CONTEXT as ctx
 
-    be = JaxBackend(ctx, impl=impl, interpret=True, n_devices=4)
+    be = JaxBackend(ctx, impl="pallas", interpret=True, n_devices=4)
     assert be._mesh_rs.n_devices == 4 and not be._mesh_rs.pod_sharded
     cpu = CpuBackend(ctx)
     data = rng.integers(0, 256, size=(10, 4 * 8192 + 77), dtype=np.uint8)

@@ -232,6 +232,14 @@ def test_peer_rebuild_native_vs_python_bit_identical(
     v1 and v2 sidecars, ragged tails, multi-chunk streams."""
     from seaweedfs_tpu.ec import peer_rebuild as pr
 
+    from seaweedfs_tpu.utils import metrics as M
+
+    def total(counter, plane=None):
+        return sum(
+            v for key, v in counter.snapshot().items()
+            if plane is None or (key and key[0] == plane)
+        )
+
     monkeypatch.setattr(pr, "FETCH_CHUNK", 8192)  # force multi-chunk
     make, client = planes_env
     results = {}
@@ -242,6 +250,8 @@ def test_peer_rebuild_native_vs_python_bit_identical(
         fp = make(pdir)
         c = client()
         fetch, fetch_into = wire_transports(c, {"p": fp.addr})
+        copied0 = total(M.net_bytes_copied_total)
+        received0 = total(M.net_bytes_received_total, tag)
         rep = rebuild_from_peers(
             base,
             {1: ["p"], 2: ["p"], 3: ["p"], 4: ["p"]},
@@ -255,6 +265,13 @@ def test_peer_rebuild_native_vs_python_bit_identical(
         assert rep.rebuilt == [5]
         want_plane = tag
         assert set(rep.fetched_plane.values()) == {want_plane}
+        # copies per byte served: the native plane lands every payload
+        # byte without materializing it in a Python buffer, the Python
+        # plane copies each at least once
+        copied = total(M.net_bytes_copied_total) - copied0
+        received = total(M.net_bytes_received_total, tag) - received0
+        assert received >= len(rep.fetched) * SHARD_SIZE
+        assert (copied == 0) if tag == "native" else (copied >= received)
         results[tag] = (
             open(base + CTX.to_ext(5), "rb").read(), blobs[5]
         )

@@ -61,16 +61,12 @@ def mesh4(topo):
 
 
 def _shapes(sharding, k, m_out, width):
-    """(data, bit-major/byte-major matrix, plane stack) shape structs."""
+    """(data, bit-major/byte-major matrix) shape structs."""
     data = jax.ShapeDtypeStruct((k, width), jnp.uint8, sharding=sharding)
     bits = jax.ShapeDtypeStruct(
         (8 * m_out, 8 * k), jnp.float32, sharding=sharding
     )
-    m_pad = rs_pallas._aligned_m_pad(m_out, 1)
-    planes = jax.ShapeDtypeStruct(
-        (8, k, 8 * m_pad), jnp.float32, sharding=sharding
-    )
-    return data, bits, planes
+    return data, bits
 
 
 # (k, rows out, width): what the main path dispatches.
@@ -86,15 +82,25 @@ SHAPES = [
 
 @pytest.mark.parametrize("k,m_out,width", SHAPES)
 def test_pallas_compact_compiles(one_chip, k, m_out, width):
-    data, bits, _ = _shapes(one_chip, k, m_out, width)
+    data, bits = _shapes(one_chip, k, m_out, width)
     compiled = rs_pallas.apply_bitmajor_pallas.lower(
         bits, data, k=k, m=m_out
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
 
-# a degraded GET's one-row result beside them: (k, rows out, width)
-WORD_SHAPES = SHAPES + [pytest.param(10, 1, 448 << 10, id="get1@448KiB")]
+# a degraded GET's one-row result beside them, and the geometries that
+# share the kernel (contraction 8k = 32 / 80 / 128 of the MXU's 128;
+# ROADMAP Reach B10): (k, rows out, width)
+WORD_SHAPES = SHAPES + [
+    pytest.param(10, 1, 448 << 10, id="get1@448KiB"),
+    pytest.param(16, 4, 1 * MIB, id="16+4@1MiB"),
+    pytest.param(16, 4, 16 * MIB, id="16+4@16MiB"),
+    pytest.param(16, 2, 16 * MIB, id="16+4-rebuild2@16MiB"),
+    pytest.param(4, 2, 1 * MIB, id="4+2@1MiB"),
+    pytest.param(4, 2, 16 * MIB, id="4+2@16MiB"),  # = its two-row rebuild
+    pytest.param(4, 1, 16 * MIB, id="4+2-rebuild1@16MiB"),
+]
 
 
 @pytest.mark.parametrize("k,m_out,width", WORD_SHAPES)
@@ -102,7 +108,7 @@ def test_pallas_compact_compiles_on_words(one_chip, k, m_out, width):
     """The form in which `JaxBackend` stages every batch: int32 words
     of four bytes in, words out, and the result dense on the chip (a
     uint8 result is laid out four rows to a word, `(4,1)`)."""
-    _, bits, _ = _shapes(one_chip, k, m_out, width)
+    _, bits = _shapes(one_chip, k, m_out, width)
     words = jax.ShapeDtypeStruct((k, width // 4), jnp.int32, sharding=one_chip)
     compiled = rs_pallas.apply_bitmajor_pallas.lower(
         bits, words, k=k, m=m_out
@@ -115,17 +121,8 @@ def test_pallas_compact_compiles_on_words(one_chip, k, m_out, width):
 
 
 @pytest.mark.parametrize("k,m_out,width", SHAPES)
-def test_pallas_aligned_compiles(one_chip, k, m_out, width):
-    data, _, planes = _shapes(one_chip, k, m_out, width)
-    compiled = rs_pallas.apply_planes_pallas.lower(
-        planes, data, k=k, m=m_out
-    ).compile()
-    assert "tpu_custom_call" in compiled.as_text()
-
-
-@pytest.mark.parametrize("k,m_out,width", SHAPES)
 def test_xla_compiles(one_chip, k, m_out, width):
-    data, bits, _ = _shapes(one_chip, k, m_out, width)
+    data, bits = _shapes(one_chip, k, m_out, width)
     compiled = rs_jax._apply_bits.lower(bits, data).compile()
     assert "tpu_custom_call" not in compiled.as_text()
 
