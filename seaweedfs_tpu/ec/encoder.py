@@ -138,15 +138,16 @@ def write_ec_files(
                     )
 
         # Native read source (ec/native_io.py): one GIL-releasing
-        # batched pread per batch straight into a pooled aligned matrix
-        # that flows read -> device -> sink untouched (the zero-copy
-        # plane), with the NEXT batch's extents readahead-hinted before
-        # this one reads. An armed fault registry or SEAWEED_EC_NATIVE=0
-        # keeps the bit-identical Python preadv loop.
+        # batched pread per batch straight into an aligned matrix of
+        # the process-wide pool, which flows read -> device -> sink
+        # untouched (the zero-copy plane), with the NEXT batch's extents
+        # readahead-hinted before this one reads. An armed fault
+        # registry or SEAWEED_EC_NATIVE=0 keeps the bit-identical
+        # Python preadv loop.
         from . import native_io
 
         use_native = native_io.enabled() and not faults.active()
-        pool = native_io.BufferPool(k) if use_native else None
+        pool = native_io.batch_pool() if use_native else None
 
         def produce():
             plan = list(batch_plan())
@@ -158,6 +159,7 @@ def write_ec_files(
                         row_offset + i * block_size + chunk_off
                         for i in range(k)
                     ]
+                    trace.count("read_bytes", k * width)
                     if use_native:
                         if n_batch + 1 < len(plan):
                             nro, nbs, nco, nw = plan[n_batch + 1]
@@ -165,7 +167,9 @@ def write_ec_files(
                                 native_io.prefetch(
                                     dat_fd, nro + i * nbs + nco, nw
                                 )
-                        data = pool.get(width)
+                        data, held = pool.get(k, width)
+                        if held:
+                            trace.count("read_reused_bytes", k * width)
                         native_io.read_batch(
                             [dat_fd] * k, offsets, data, pad_eof=True
                         )
@@ -242,8 +246,8 @@ def write_ec_files(
             with trace.stage(sp, "write_sink"):
                 sink.append_rows([*data, *parity])
             if pool is not None:
-                # the batch's bytes are on disk (or in the sink's write
-                # path) — its pooled matrix is free to carry batch N+2
+                # to_host has returned and the batch's bytes are with
+                # the sink: its matrix is free to carry a later batch
                 pool.put(data)
 
         try:

@@ -4,21 +4,25 @@ The Python layer ORCHESTRATES buffers here instead of copying them:
 batches land via one GIL-releasing `sn_batch_pread` call per batch into
 caller-owned aligned numpy matrices that flow produce -> transform ->
 consume untouched (numpy views over one allocation — no `bytes`
-objects, no per-batch malloc/page-fault churn), then return to a small
-pool. The write half is the stateful native sink (utils/native.py
-NativeSink, used by pipeline.FusedShardSink). The NETWORK half lives
-in ec/net_plane.py (ISSUE 12): the same BufferPool class backs the
-peer-fetch ingress landings and the fastread client, and `enabled()`
-below is the single gate every plane (local, wire, HTTP egress)
-checks.
+objects, no per-batch malloc/page-fault churn), then return to one
+process-wide bounded pool (`batch_pool()`). The write half is the
+stateful native sink (utils/native.py NativeSink, used by
+pipeline.FusedShardSink). The NETWORK half lives in ec/net_plane.py
+(ISSUE 12): `landing_pool()` backs the peer-fetch ingress landings and
+the fastread client, and `enabled()` below is the single gate every
+plane (local, wire, HTTP egress) checks.
 
 Buffer-ownership rules (README "Native data plane" has the long form):
 
 - A pooled matrix belongs to exactly one in-flight batch from the
-  moment `BufferPool.get` returns it until its release callback runs in
-  the consume stage. The pipeline's bounded queues cap in-flight
-  batches, and the pool is sized to that cap, so `get` never blocks on
-  the happy path.
+  moment a pool's `get` returns it until the consume stage hands it
+  back with `put`: for a staged batch (`batch_pool()`, encode and
+  rebuild) that is after `backend.to_host` has returned for the batch
+  and its rows are with the sink. Until then the runtime may still be
+  reading the matrix for the upload, and `FallbackBackend` carries it
+  as the host copy a mid-batch failover replays on the CPU. `get`
+  never blocks: it allocates when the pool holds no matrix of the
+  shape.
 - Rows handed to the native sink must stay alive until the append call
   returns (the C side pwrite(2)s straight from them; it stores no
   pointers).
@@ -39,6 +43,7 @@ read/write seams (see ec/rebuild.py).
 from __future__ import annotations
 
 import os
+import threading
 from typing import Sequence
 
 import numpy as np
@@ -73,8 +78,9 @@ def aligned_matrix(rows: int, width: int, align: int = _ALIGN) -> np.ndarray:
     return raw[off : off + rows * width].reshape(rows, width)
 
 
+_pool_lock = threading.Lock()
 _landing_pool_singleton = None
-_landing_pool_lock = None
+_batch_pool_singleton = None
 
 
 def landing_pool() -> "BufferPool":
@@ -82,35 +88,40 @@ def landing_pool() -> "BufferPool":
     shared by every single-stream ingress (peer-fetch net-plane
     landings, the fastread client) so steady state allocates once per
     width and reuses forever."""
-    global _landing_pool_singleton, _landing_pool_lock
-    if _landing_pool_lock is None:
-        import threading as _t
-
-        _landing_pool_lock = _t.Lock()
-    with _landing_pool_lock:
+    global _landing_pool_singleton
+    with _pool_lock:
         if _landing_pool_singleton is None:
             _landing_pool_singleton = BufferPool(rows=1)
         return _landing_pool_singleton
 
 
+def batch_pool() -> "BatchPool":
+    """Process-wide pool of the (rows, width) matrices that a
+    pipeline's reader fills (ec/encoder.py, ec/rebuild.py): from the
+    second batch in flight on, and in every later operation of the
+    process, a batch lands in pages that are already committed and
+    mapped, and no 160 MiB mapping is made or torn down on a pipeline
+    thread. It keeps what one pipeline can have alive."""
+    global _batch_pool_singleton
+    with _pool_lock:
+        if _batch_pool_singleton is None:
+            from .pipeline import BATCHES_ALIVE
+
+            _batch_pool_singleton = BatchPool(keep=BATCHES_ALIVE)
+        return _batch_pool_singleton
+
+
 class BufferPool:
-    """Reusable aligned (rows, width) matrices cycling through the
-    pipeline, free-listed by exact width (the encode plan yields at
-    most a few width classes: full batches, the small-block phase, and
-    ragged tails). Allocation happens on demand; the population is
-    naturally bounded by the pipeline's in-flight batch cap
-    (~2*queue_size + one per stage), so steady state is allocate-once,
-    reuse-forever — no per-batch malloc or page-fault churn. Release is
-    cooperative: the consume stage calls `put` when the batch's bytes
-    have been written; a batch dropped by an aborting pipeline simply
-    strands its matrix for the GC (the pool holds no global list)."""
+    """Reusable aligned (rows, width) matrices free-listed by exact
+    width, for the one-row landings of `landing_pool()`. Allocation
+    happens on demand; release is cooperative (`put` when the bytes
+    have been consumed), and a buffer that is never put back is the
+    collector's: the pool holds no list of what it handed out."""
 
     def __init__(self, rows: int):
-        import threading as _t
-
         self.rows = rows
         self._free: dict[int, list[np.ndarray]] = {}
-        self._lock = _t.Lock()
+        self._lock = threading.Lock()
 
     def get(self, width: int) -> np.ndarray:
         with self._lock:
@@ -122,6 +133,39 @@ class BufferPool:
     def put(self, buf: np.ndarray) -> None:
         with self._lock:
             self._free.setdefault(buf.shape[1], []).append(buf)
+
+
+class BatchPool:
+    """At most `keep` free staged-batch matrices of any shape, handed
+    out by exact (rows, width): an encode plan's full batches, its
+    small-block phase and a ragged tail are classes of their own, as
+    are 10+4, 4+2 and 16+4 (a column slice of a wider matrix would be
+    copied on its way to the device). `get` says whether the matrix
+    had been held, and allocates when none of the shape is; a `put`
+    over the bound drops the matrix that has lain longest, so the
+    shapes of operations long past make room for those of the running
+    one and the resident memory stays `keep` matrices of the widest
+    class. A batch that an aborting pipeline drops strands its matrix
+    for the collector: the pool holds no list of what it handed out."""
+
+    def __init__(self, keep: int):
+        self.keep = keep
+        self._free: list[np.ndarray] = []  # longest-lying first
+        self._lock = threading.Lock()
+
+    def get(self, rows: int, width: int) -> tuple[np.ndarray, bool]:
+        with self._lock:
+            for i in range(len(self._free) - 1, -1, -1):
+                if self._free[i].shape == (rows, width):
+                    return self._free.pop(i), True
+        return aligned_matrix(rows, width), False
+
+    def put(self, buf: np.ndarray) -> None:
+        with self._lock:
+            self._free.append(buf)
+            # held past the lock: a dropped matrix is unmapped outside it
+            dropped = self._free.pop(0) if len(self._free) > self.keep else None
+        del dropped
 
 
 def read_batch(

@@ -60,12 +60,20 @@ def _traced_call(span, stage: str, fn):
     return wrapped
 
 
+QUEUE_SIZE = 2
+# What one pipeline can have alive at once: a batch with the reader,
+# one with the dispatcher, one with the sink, and the two queues full
+# between them. The staged-batch pool (native_io.batch_pool) keeps as
+# many matrices and no more.
+BATCHES_ALIVE = 2 * QUEUE_SIZE + 3
+
+
 def run_pipeline(
     produce: Callable[[], Iterator],
     transform: Callable,
     consume: Callable,
     *,
-    queue_size: int = 2,
+    queue_size: int = QUEUE_SIZE,
     join_timeout: float = 120.0,
     describe: str = "ec pipeline",
     span=None,
@@ -83,9 +91,10 @@ def run_pipeline(
       BLOCK on device results (to_host) and disk writes, while the
       calling thread keeps dispatching the batches queued behind it.
 
-    Queue residency bound: up to `2*queue_size` items are alive at once
-    (one per stage plus the queues); callers sizing device memory must
-    budget accordingly.
+    Queue residency bound: up to `2*queue_size + 3` items are alive at
+    once (one per stage plus the two queues: `BATCHES_ALIVE` at the
+    default depth); callers sizing device memory must budget
+    accordingly.
 
     `span` + `stage_names` attribute wall time to the flight recorder
     (utils/trace.py): stage_names is (produce, transform, consume) —
@@ -211,7 +220,7 @@ def run_staged_apply(
     produce: Callable[[], Iterator],
     consume: Callable,
     *,
-    queue_size: int = 2,
+    queue_size: int = QUEUE_SIZE,
     join_timeout: float = 120.0,
     describe: str = "ec staged apply",
     priority: str = "recovery",
@@ -237,7 +246,12 @@ def run_staged_apply(
     double-buffered window `encode_staged` gave the encoder.
 
     `produce()` yields `(tag, batch)` pairs; `consume(tag, out)` gets
-    the tag back untouched (offset bookkeeping stays with the caller).
+    the tag back untouched, after `to_host` has returned for the batch
+    (offset bookkeeping stays with the caller, and a caller whose
+    batches come from `native_io.batch_pool()` carries the matrix in
+    the tag and puts it back there: not before, because the runtime
+    may read it for the upload until then and `FallbackBackend` replays
+    a failed batch from it).
     `coeffs=None` is the pass-through configuration: no device
     round-trip, the batch flows to `consume` unchanged (decode's
     de-stripe, where reads must overlap writes but there is nothing to

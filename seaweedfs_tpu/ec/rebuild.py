@@ -436,6 +436,8 @@ def _attempt_rebuild(
         rs = gf256.ReedSolomon(ctx.data_shards, ctx.parity_shards)
         coeffs = _decode_coeffs(rs.matrix, k, tuple(targets), tuple(src))
 
+        pool = native_io.batch_pool() if use_native else None
+
         def produce():
             src_fds = [fds[i] for i in src]
             out_crcs = out_counts = None
@@ -446,8 +448,13 @@ def _attempt_rebuild(
                 out_counts = np.empty(k, np.int32)
             for off in range(0, shard_size, batch_size):
                 width = min(batch_size, shard_size - off)
-                buf = np.empty((k, width), dtype=np.uint8)
+                trace.count("read_bytes", k * width)
                 if use_native:
+                    # a matrix that an earlier batch or operation of the
+                    # process filled: its pages are mapped already
+                    buf, held = pool.get(k, width)
+                    if held:
+                        trace.count("read_reused_bytes", k * width)
                     nxt = off + width
                     if nxt < shard_size:
                         nw = min(batch_size, shard_size - nxt)
@@ -471,6 +478,7 @@ def _attempt_rebuild(
                                 int(x) for x in out_crcs[row, :c]
                             )
                 else:
+                    buf = np.empty((k, width), dtype=np.uint8)
                     for row, i in enumerate(src):
                         try:
                             _pread_exact(fds[i], buf[row], off)
@@ -478,11 +486,16 @@ def _attempt_rebuild(
                             raise _SourceReadError([i]) from e
                         if rollers is not None:
                             rollers[i].update(buf[row])
-                yield off, buf
+                yield buf, buf
 
-        def consume(_off, out):
+        def consume(buf, out):
             out = np.ascontiguousarray(out, dtype=np.uint8)
             sink.append_rows([out[p] for p in range(len(targets))])
+            if pool is not None:
+                # to_host has returned for this batch: nothing reads
+                # its matrix any more, neither the upload nor a
+                # failover's replay on the CPU
+                pool.put(buf)
 
     def _cleanup_temps() -> None:
         for f in outs.values():

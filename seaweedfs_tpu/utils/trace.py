@@ -225,6 +225,18 @@ _seam_counters = {
         "words (a uint8 result crosses with its sublane holes)",
         ("op",),
     ),
+    "read_bytes": _M.REGISTRY.counter(
+        "sw_ec_read_bytes_total",
+        "bytes the encode and rebuild pipelines' readers landed in staged "
+        "batches (tracer armed only)",
+        ("op",),
+    ),
+    "read_reused_bytes": _M.REGISTRY.counter(
+        "sw_ec_read_reused_bytes_total",
+        "of sw_ec_read_bytes_total, the bytes that landed in a matrix the "
+        "process-wide batch pool had held (pages mapped by an earlier batch)",
+        ("op",),
+    ),
     "batches": _M.REGISTRY.counter(
         "sw_ec_device_batches_total",
         "batches EC operations handed to the device (tracer armed only)",
@@ -892,10 +904,10 @@ def lap(part: str) -> None:
 
 def count(name: str, n: int) -> None:
     """Add `n` to the seam counter `name` (``h2d_bytes``, ``d2h_bytes``,
-    ``d2h_dense_bytes``, ``batches``): an attribute of the span whose
-    stage the calling thread has open, and ``sw_ec_*_total{op}`` on
-    /metrics. Nothing when disarmed (one module-bool check) or with no
-    stage open."""
+    ``d2h_dense_bytes``, ``read_bytes``, ``read_reused_bytes``,
+    ``batches``): an attribute of the span whose stage the calling
+    thread has open, and ``sw_ec_*_total{op}`` on /metrics. Nothing
+    when disarmed (one module-bool check) or with no stage open."""
     if not armed:
         return
     parent = _open_stage.get()

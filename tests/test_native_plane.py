@@ -133,10 +133,16 @@ def _make_dat(tmp_path, name, nbytes, seed=7):
     return base
 
 
+@pytest.mark.parametrize("batch_size", [
+    pytest.param(16 << 20, id="a_batch_a_block"),
+    # blocks of 1 MiB in batches of 300,001 bytes: three full and a
+    # ragged tail a block, two width classes of the batch pool
+    pytest.param(300_001, id="ragged_batches"),
+])
 @pytest.mark.parametrize("leaf_size", [0, 64 * 1024])
 @pytest.mark.parametrize("tail", [0, 12345])
 def test_encode_native_vs_python_bit_identical(
-    tmp_path, monkeypatch, leaf_size, tail
+    tmp_path, monkeypatch, leaf_size, tail, batch_size
 ):
     """Same .dat, native plane vs SEAWEED_EC_NATIVE=0: shard bytes,
     sizes, block CRCs and (v2) leaf CRCs must match bit for bit —
@@ -149,9 +155,13 @@ def test_encode_native_vs_python_bit_identical(
     be = CpuBackend(CTX64)
 
     monkeypatch.setenv("SEAWEED_EC_NATIVE", "1")
-    prot_n = write_ec_files(base_n, CTX64, be, leaf_size=leaf_size)
+    prot_n = write_ec_files(
+        base_n, CTX64, be, batch_size=batch_size, leaf_size=leaf_size
+    )
     monkeypatch.setenv("SEAWEED_EC_NATIVE", "0")
-    prot_p = write_ec_files(base_p, CTX64, be, leaf_size=leaf_size)
+    prot_p = write_ec_files(
+        base_p, CTX64, be, batch_size=batch_size, leaf_size=leaf_size
+    )
 
     assert prot_n.shard_sizes == prot_p.shard_sizes
     assert prot_n.shard_crcs == prot_p.shard_crcs
@@ -163,7 +173,17 @@ def test_encode_native_vs_python_bit_identical(
         assert a == b, f"shard {i} differs"
 
 
-def test_rebuild_native_vs_python_bit_identical(tmp_path, monkeypatch):
+@pytest.mark.parametrize("batch_size", [
+    pytest.param(16 << 20, id="one_batch"),
+    # a 1 MiB shard in batches that do and do not divide it: the pool's
+    # full-width class, and a tail that is none, 3 bytes short or ragged
+    pytest.param(256 << 10, id="no_tail"),
+    pytest.param((256 << 10) + 1, id="tail_3_short"),
+    pytest.param(300_001, id="ragged_tail"),
+])
+def test_rebuild_native_vs_python_bit_identical(tmp_path, monkeypatch, batch_size):
+    """Both planes twice over, the native one from the process-wide
+    batch pool (its second pass lands in matrices the first filled)."""
     if not native_io.enabled():
         pytest.skip("native core unavailable")
     base = _make_dat(tmp_path, "v", (3 << 20) + 999)
@@ -173,11 +193,11 @@ def test_rebuild_native_vs_python_bit_identical(tmp_path, monkeypatch):
     originals = {
         i: open(base + CTX64.to_ext(i), "rb").read() for i in (1, 5)
     }
-    for env in ("1", "0"):
+    for env in ("1", "0", "1"):
         monkeypatch.setenv("SEAWEED_EC_NATIVE", env)
         for i in originals:
             os.unlink(base + CTX64.to_ext(i))
-        got = rebuild_ec_files(base, CTX64, backend=be)
+        got = rebuild_ec_files(base, CTX64, backend=be, batch_size=batch_size)
         assert sorted(got) == sorted(originals)
         for i, want in originals.items():
             assert open(base + CTX64.to_ext(i), "rb").read() == want
