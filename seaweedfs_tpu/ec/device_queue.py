@@ -188,6 +188,22 @@ _queue_wait_seconds = _M.REGISTRY.counter(
     "sw_ec_queue_wait_seconds_total",
     "EC device-queue admission wait", ("cls", "chip"),
 )
+_queue_blocked = _M.REGISTRY.counter(
+    "sw_ec_queue_blocked_total",
+    "EC device-queue admissions that found every window slot taken "
+    "when they arrived, by the class that held the most of them",
+    ("cls", "by", "chip"),
+)
+_queue_blocked_seconds = _M.REGISTRY.counter(
+    "sw_ec_queue_blocked_seconds_total",
+    "EC device-queue admission wait of the admissions that found the "
+    "window full", ("cls", "by", "chip"),
+)
+_queue_slot_seconds = _M.REGISTRY.counter(
+    "sw_ec_queue_slot_seconds_total",
+    "EC device-queue seconds of window slots held, added at release",
+    ("cls", "chip"),
+)
 
 # ---- residency defaults (env-tunable; see README env-knob registry) ----
 
@@ -271,6 +287,19 @@ def batch_cost(out_rows: int, width: int) -> int:
     return max(int(out_rows), 1) * max(int(width), 1)
 
 
+# Who a waiter is laid to when it finds every slot taken: the class that
+# holds the most slots, recovery before foreground on a tie (the
+# question an operator asks is "did repair hold serving back").
+_BLAME_ORDER = ("recovery", "foreground", "scrub")
+
+
+def _full_window(held: dict[str, int]) -> tuple[str, dict[str, int]]:
+    """(the class that holds most of a full window, slots by class)."""
+    held = {c: n for c, n in held.items() if n > 0}
+    by = max(_BLAME_ORDER, key=lambda c: held.get(c, 0))
+    return by, held
+
+
 class _Waiter:
     __slots__ = ("priority", "cost", "t_submit")
 
@@ -286,13 +315,25 @@ class Ticket:
     thread's finally. `wait_s` is the admission wait this batch paid
     (the flight recorder's "admission_wait" stage)."""
 
-    __slots__ = ("priority", "cost", "released", "wait_s", "res")
+    __slots__ = (
+        "priority", "cost", "released", "wait_s", "res", "t_admit",
+        "blocked",
+    )
 
-    def __init__(self, priority: str, cost: int, wait_s: float = 0.0):
+    def __init__(
+        self, priority: str, cost: int, wait_s: float = 0.0,
+        t_admit: float = 0.0, blocked=None,
+    ):
         self.priority = priority
         self.cost = cost
         self.released = False
         self.wait_s = wait_s
+        # the queue's clock when the slot was taken: slot-seconds are
+        # counted from here at release
+        self.t_admit = t_admit
+        # (class that held most of the window, slots by class) where
+        # every slot was taken when this batch arrived; else None
+        self.blocked = blocked
         # (ledger, _ResTicket) once the residency phase charged the
         # physical chip; None for ledger-less queues
         self.res = None
@@ -302,6 +343,7 @@ class ClassStats:
     __slots__ = (
         "submitted", "admitted", "admitted_cost", "drained",
         "drained_cost", "wait_s_total", "wait_s_max", "inflight",
+        "blocked", "blocked_s", "slot_s",
     )
 
     def __init__(self):
@@ -313,6 +355,11 @@ class ClassStats:
         self.wait_s_total = 0.0
         self.wait_s_max = 0.0
         self.inflight = 0
+        # admissions of this class that found the window full, and the
+        # seconds they then waited, by the class that held most of it
+        self.blocked: dict[str, int] = {}
+        self.blocked_s: dict[str, float] = {}
+        self.slot_s = 0.0  # seconds of window slots held (at release)
 
     def as_dict(self, depth: int) -> dict:
         return {
@@ -325,7 +372,20 @@ class ClassStats:
             "drained_cost": self.drained_cost,
             "wait_s_total": round(self.wait_s_total, 6),
             "wait_s_max": round(self.wait_s_max, 6),
+            "blocked": dict(self.blocked),
+            "blocked_s": {
+                by: round(s, 6) for by, s in self.blocked_s.items()
+            },
+            "slot_s": round(self.slot_s, 6),
         }
+
+
+def _note_window_full(span, ticket: Ticket) -> None:
+    """The `window_full` event on the span whose `admission_wait` stage
+    waited (armed only: a disarmed caller hands no span)."""
+    if span is not None and ticket.blocked is not None:
+        by, held = ticket.blocked
+        trace.event(span, "window_full", by=by, held=held)
 
 
 class DeviceStream:
@@ -366,6 +426,7 @@ class DeviceStream:
         with trace.stage(span, "admission_wait", self.queue.label) as timer:
             ticket = self.queue._admit(self.priority, cost)
             timer.seconds = ticket.wait_s
+        _note_window_full(span, ticket)
         with self._lock:
             self._outstanding.add(ticket)
         ok = False
@@ -488,6 +549,7 @@ class DeviceQueue:
         with trace.stage(span, "admission_wait", self.label) as timer:
             ticket = self._admit(priority, cost)
             timer.seconds = ticket.wait_s
+        _note_window_full(span, ticket)
         try:
             yield ticket
         finally:
@@ -538,6 +600,11 @@ class DeviceQueue:
             st = self._stats[priority]
             st.submitted += 1
             _queue_depth.inc(cls=priority, chip=self.label)
+            blocked = None
+            if self._inflight >= self.window:
+                blocked = _full_window(
+                    {c: s.inflight for c, s in self._stats.items()}
+                )
             while self._pick() is not w:
                 deadline = (
                     max(w.t_submit, self._last_progress) + self.admit_timeout
@@ -583,7 +650,10 @@ class DeviceQueue:
             self._credit[priority] = max(self._credit[priority] - cost, 0.0)
             self._inflight += 1
             self._last_progress = self._clock()
-            wait_s = max(self._clock() - w.t_submit, 0.0)
+            t_admit = self._clock()
+            wait_s = max(t_admit - w.t_submit, 0.0)
+            if blocked is not None:
+                self._count_blocked(priority, blocked[0], wait_s, first=True)
             st.admitted += 1
             st.admitted_cost += cost
             st.inflight += 1
@@ -596,7 +666,7 @@ class DeviceQueue:
             self._inflight_cost += cost
             # Another slot may still be free for the next waiter.
             self._cond.notify_all()
-        ticket = Ticket(priority, cost, wait_s)
+        ticket = Ticket(priority, cost, wait_s, t_admit, blocked)
         # Phase 2, OUTSIDE self._cond (the ledger has its own lock —
         # never nested): charge the physical chip(s). The local slot is
         # held while we wait here, which is exactly the sub-budget
@@ -615,7 +685,13 @@ class DeviceQueue:
                 raise
             ticket.res = (self.residency, res)
             rwait = max(self._clock() - t0, 0.0)
-            if rwait > 0.0:
+            # a chip full on the ledger (other scopes' batches count
+            # there) blocks like a full window: one count a batch, laid
+            # to the phase that was full first
+            first = ticket.blocked is None and res.blocked is not None
+            if first:
+                ticket.blocked = res.blocked
+            if rwait > 0.0 or first:
                 # the residency wait is part of this batch's admission
                 # wait: fold it into the ticket (span stage) and stats
                 ticket.wait_s += rwait
@@ -623,8 +699,27 @@ class DeviceQueue:
                     st = self._stats[priority]
                     st.wait_s_total += rwait
                     st.wait_s_max = max(st.wait_s_max, ticket.wait_s)
+                    if ticket.blocked is not None:
+                        self._count_blocked(
+                            priority, ticket.blocked[0], rwait, first
+                        )
                 _queue_wait_seconds.inc(rwait, cls=priority, chip=self.label)
         return ticket
+
+    def _count_blocked(
+        self, priority: str, by: str, seconds: float, first: bool
+    ) -> None:
+        """Under self._cond: a batch of `priority` found every slot
+        taken, most by class `by`, and waited `seconds` (`first`: the
+        batch has not been counted yet, in either phase)."""
+        st = self._stats[priority]
+        if first:
+            st.blocked[by] = st.blocked.get(by, 0) + 1
+            _queue_blocked.inc(cls=priority, by=by, chip=self.label)
+        st.blocked_s[by] = st.blocked_s.get(by, 0.0) + seconds
+        _queue_blocked_seconds.inc(
+            seconds, cls=priority, by=by, chip=self.label
+        )
 
     def _release(self, ticket: Ticket) -> None:
         res = None
@@ -636,8 +731,14 @@ class DeviceQueue:
             self._inflight -= 1
             self._pending_cost -= ticket.cost
             self._inflight_cost -= ticket.cost
-            self._last_progress = self._clock()
+            now = self._clock()
+            self._last_progress = now
+            held_s = max(now - ticket.t_admit, 0.0)
             st = self._stats[ticket.priority]
+            st.slot_s += held_s
+            _queue_slot_seconds.inc(
+                held_s, cls=ticket.priority, chip=self.label
+            )
             st.inflight -= 1
             st.drained += 1
             st.drained_cost += ticket.cost
@@ -660,15 +761,21 @@ class _ResTicket:
     """One granted residency charge: `keys` are the physical chips
     holding a slot each until release. Idempotent release."""
 
-    __slots__ = ("keys", "tenant", "priority", "cost", "released", "wait_s")
+    __slots__ = (
+        "keys", "tenant", "priority", "cost", "released", "wait_s",
+        "blocked",
+    )
 
-    def __init__(self, keys, tenant, priority, cost, wait_s):
+    def __init__(self, keys, tenant, priority, cost, wait_s, blocked=None):
         self.keys = keys
         self.tenant = tenant
         self.priority = priority
         self.cost = cost
         self.released = False
         self.wait_s = wait_s
+        # as Ticket.blocked: a chip of `keys` had no free slot when
+        # the charge arrived
+        self.blocked = blocked
 
 
 class _ResWaiter:
@@ -687,7 +794,7 @@ class _ChipState:
     __slots__ = (
         "key", "budget", "inflight", "inflight_cost", "max_inflight",
         "max_inflight_cost", "admitted", "admitted_cost", "over_since",
-        "breakers",
+        "breakers", "held",
     )
 
     def __init__(self, key: str, budget: int):
@@ -695,6 +802,7 @@ class _ChipState:
         self.budget = budget
         self.inflight = 0
         self.inflight_cost = 0
+        self.held: dict[str, int] = {}  # in-flight slots by class
         # Watermarks are the chaos tests' GROUND TRUTH for the
         # invariant "N scopes on one chip never exceed the budget":
         # they record the worst concurrency the ledger ever granted,
@@ -904,6 +1012,15 @@ class ResidencyLedger:
             self._rotate_buckets(now)
             w = _ResWaiter(keys, tenant, priority, cost, now, next(self._seq))
             self._waiters.append(w)
+            blocked = None
+            if not self._fits(w):
+                held: dict[str, int] = {}
+                for k in keys:
+                    ch = self._chip(k)
+                    if ch.inflight >= ch.budget:
+                        for c, n in ch.held.items():
+                            held[c] = held.get(c, 0) + n
+                blocked = _full_window(held)
             try:
                 self._update_pressure(now)
                 while not self._grantable(w, self._clock()):
@@ -941,6 +1058,7 @@ class ResidencyLedger:
                 ch = self._chip(k)
                 ch.inflight += 1
                 ch.inflight_cost += cost
+                ch.held[priority] = ch.held.get(priority, 0) + 1
                 ch.max_inflight = max(ch.max_inflight, ch.inflight)
                 ch.max_inflight_cost = max(
                     ch.max_inflight_cost, ch.inflight_cost
@@ -959,7 +1077,7 @@ class ResidencyLedger:
             self._update_pressure(now)
             wait_s = max(now - w.t_submit, 0.0)
             _res_wait_seconds.inc(wait_s, tenant=tenant, chip=keys[0])
-        return _ResTicket(keys, tenant, priority, cost, wait_s)
+        return _ResTicket(keys, tenant, priority, cost, wait_s, blocked)
 
     def release(self, ticket: _ResTicket) -> None:
         with self._cond:
@@ -970,6 +1088,7 @@ class ResidencyLedger:
                 ch = self._chip(k)
                 ch.inflight -= 1
                 ch.inflight_cost -= ticket.cost
+                ch.held[ticket.priority] -= 1
                 _res_inflight_g.set(ch.inflight, chip=k)
             now = self._clock()
             self._last_progress = now

@@ -534,3 +534,288 @@ def test_standalone_ec_volume_keeps_private_cache(tmp_path):
         assert all(k.startswith("1:") for k in ev.interval_cache._data)
     finally:
         ev.close()
+
+
+# --------------------------------------------------------------------------
+# PR 33: what the queue says when two classes meet. Every queue below
+# runs on a manual clock: a waiter's seconds are the seconds the test
+# moved it by, whatever the machine did meanwhile.
+# --------------------------------------------------------------------------
+
+
+class ManualClock:
+    def __init__(self):
+        self.now = 100.0
+
+    def __call__(self) -> float:
+        return self.now
+
+
+def _until(cond, what: str) -> None:
+    """Hand the interpreter to the other threads until `cond()`: order,
+    not time (the bound only ends a test that would hang)."""
+    tick = threading.Event()
+    for _ in range(20_000):
+        if cond():
+            return
+        tick.wait(0.0005)
+    raise AssertionError(f"never saw {what}")
+
+
+def _waiter(q, cls, order, span=None):
+    """A thread that asks for one slot of `cls` and holds it until told."""
+    go = threading.Event()
+
+    def run():
+        with q.admission(cls, 1000, span=span):
+            order.append(cls)
+            go.wait(30)
+
+    t = threading.Thread(target=run, daemon=True)
+    t.start()
+    return t, go
+
+
+def _full_of_recovery(clock, window=4):
+    q = DeviceQueue(window=window, clock=clock, label="chip0")
+    hog = q.stream("recovery")
+    tickets = [hog.dispatch(lambda: None, 10_000)[0] for _ in range(window)]
+    return q, hog, tickets
+
+
+def test_foreground_that_finds_the_window_full_of_recovery_is_counted_and_goes_first():
+    clock = ManualClock()
+    q, hog, tickets = _full_of_recovery(clock)
+    order: list = []
+    rec_t, rec_go = _waiter(q, "recovery", order)
+    _until(lambda: q.stats()["recovery"]["depth"] == 1, "the recovery waiter queued")
+    fg_t, fg_go = _waiter(q, "foreground", order)
+    _until(lambda: q.stats()["foreground"]["depth"] == 1, "the foreground waiter queued")
+    clock.now += 2.5
+    hog.release(tickets[0])  # one slot frees: the later, higher class takes it
+    _until(lambda: order == ["foreground"], "the foreground waiter admitted")
+    st = q.stats()
+    assert st["foreground"]["blocked"] == {"recovery": 1}
+    assert st["foreground"]["blocked_s"] == {"recovery": 2.5}
+    assert st["foreground"]["wait_s_total"] == 2.5
+    assert st["recovery"]["blocked"] == {} and st["recovery"]["depth"] == 1
+    clock.now += 1.0
+    fg_go.set()
+    fg_t.join(10)
+    _until(lambda: order == ["foreground", "recovery"], "the recovery waiter admitted")
+    st = q.stats()
+    assert st["recovery"]["blocked"] == {"recovery": 1}  # four of its own held it out
+    assert st["recovery"]["blocked_s"] == {"recovery": 3.5}
+    rec_go.set()
+    rec_t.join(10)
+    hog.close()
+    assert q.inflight == 0
+
+
+def test_a_waiter_that_finds_a_free_slot_counts_nothing():
+    clock = ManualClock()
+    q = DeviceQueue(window=4, clock=clock, label="chip0")
+    hog = q.stream("recovery")
+    held = [hog.dispatch(lambda: None, 10_000)[0] for _ in range(3)]
+    clock.now += 5.0
+    with q.admission("foreground", 1000) as ticket:
+        assert ticket.blocked is None and ticket.wait_s == 0.0
+    st = q.stats()
+    assert st["foreground"]["blocked"] == {} and st["foreground"]["blocked_s"] == {}
+    assert st["recovery"]["blocked"] == {}
+    for t in held:
+        hog.release(t)
+
+
+@pytest.mark.parametrize("held, by", [
+    (("recovery",) * 4, "recovery"),
+    (("recovery", "recovery", "foreground", "foreground"), "recovery"),  # the tie
+    (("recovery", "foreground", "foreground", "foreground"), "foreground"),
+    (("scrub", "scrub", "scrub", "recovery"), "scrub"),
+])
+def test_a_full_window_is_laid_to_the_class_that_holds_most_of_it(held, by):
+    clock = ManualClock()
+    q = DeviceQueue(window=4, clock=clock, label="chip0")
+    streams = {c: q.stream(c) for c in set(held)}
+    tickets = [(c, streams[c].dispatch(lambda: None, 10_000)[0]) for c in held]
+    order: list = []
+    t, go = _waiter(q, "foreground", order)
+    _until(lambda: q.stats()["foreground"]["depth"] == 1, "the waiter queued")
+    clock.now += 0.25
+    cls, first = tickets[0]
+    streams[cls].release(first)
+    _until(lambda: order == ["foreground"], "the waiter admitted")
+    assert q.stats()["foreground"]["blocked"] == {by: 1}
+    assert q.stats()["foreground"]["blocked_s"] == {by: 0.25}
+    go.set()
+    t.join(10)
+    for c, ticket in tickets[1:]:
+        streams[c].release(ticket)
+    assert q.inflight == 0
+
+
+def test_slot_seconds_by_class_add_up_to_the_time_the_slots_were_held():
+    clock = ManualClock()
+    q = DeviceQueue(window=4, clock=clock, label="chip0")
+    rec, fg = q.stream("recovery"), q.stream("foreground")
+    r1, _ = rec.dispatch(lambda: None, 10_000)
+    clock.now += 1.0
+    r2, _ = rec.dispatch(lambda: None, 10_000)
+    f1, _ = fg.dispatch(lambda: None, 1000)
+    clock.now += 0.5
+    fg.release(f1)  # held 0.5
+    assert q.stats()["foreground"]["slot_s"] == 0.5
+    assert q.stats()["recovery"]["slot_s"] == 0.0  # counted at release, whole
+    clock.now += 2.0
+    rec.release(r1)  # held 3.5
+    rec.release(r2)  # held 2.5
+    rec.release(r2)  # a second release counts nothing
+    st = q.stats()
+    assert st["recovery"]["slot_s"] == 6.0 and st["foreground"]["slot_s"] == 0.5
+    assert st["scrub"]["slot_s"] == 0.0
+
+
+def test_a_chip_full_on_the_ledger_blocks_like_a_full_window():
+    """Two scopes' queues on one physical chip: the second's window is
+    empty, the chip's budget is not, and the waiter is laid to the class
+    that holds the ledger's slots."""
+    from seaweedfs_tpu.ec.device_queue import ResidencyLedger
+
+    clock = ManualClock()
+    ledger = ResidencyLedger(budget=2, clock=clock)
+    a = DeviceQueue(window=4, clock=clock, label="chip0", residency=ledger, tenant="a")
+    b = DeviceQueue(window=4, clock=clock, label="chip0", residency=ledger, tenant="b")
+    hog = a.stream("recovery")
+    held = [hog.dispatch(lambda: None, 10_000)[0] for _ in range(2)]
+    order: list = []
+    t, go = _waiter(b, "foreground", order)
+    _until(lambda: len(ledger._waiters) == 1, "the waiter at the ledger")
+    clock.now += 0.75
+    hog.release(held[0])
+    _until(lambda: order == ["foreground"], "the waiter admitted")
+    st = b.stats()["foreground"]
+    assert st["blocked"] == {"recovery": 1} and st["blocked_s"] == {"recovery": 0.75}
+    assert a.stats()["recovery"]["blocked"] == {}
+    go.set()
+    t.join(10)
+    hog.release(held[1])
+    assert ledger.snapshot()["chips"]["chip0"]["inflight"] == 0
+
+
+def test_the_new_counters_are_on_metrics_and_in_the_snapshot():
+    from seaweedfs_tpu.ec.device_queue import QueueScope
+    from seaweedfs_tpu.utils import metrics
+
+    scope = QueueScope(window=1, residency=False)
+    be = CpuBackend(CTX)
+    q = scope.for_backend(be)
+    hog = q.stream("recovery")
+    ticket, _ = hog.dispatch(lambda: None, 10_000)
+    order: list = []
+    t, go = _waiter(q, "foreground", order)
+    _until(lambda: q.stats()["foreground"]["depth"] == 1, "the waiter queued")
+    hog.release(ticket)
+    _until(lambda: order == ["foreground"], "the waiter admitted")
+    go.set()
+    t.join(10)
+    (snap,) = scope.stats_snapshot()
+    assert snap["classes"]["foreground"]["blocked"] == {"recovery": 1}
+    assert set(snap["classes"]["recovery"]) >= {"blocked", "blocked_s", "slot_s"}
+    text = metrics.REGISTRY.render().decode()
+    for name in ("sw_ec_queue_blocked_total", "sw_ec_queue_blocked_seconds_total",
+                 "sw_ec_queue_slot_seconds_total"):
+        assert name in text, name
+    assert f'sw_ec_queue_blocked_total{{cls="foreground",by="recovery",chip="{q.label}"}}' in text
+
+
+def test_the_window_full_event_lands_on_the_armed_span_and_the_disarmed_path_reads_no_clock(
+    monkeypatch,
+):
+    from seaweedfs_tpu.utils import trace
+
+    clock = ManualClock()
+    q, hog, tickets = _full_of_recovery(clock, window=2)
+    trace.configure(enabled=True)
+    try:
+        trace.reset()
+        sp = trace.start("ec.degraded_read")
+        order: list = []
+        t, go = _waiter(q, "foreground", order, span=sp)
+        _until(lambda: q.stats()["foreground"]["depth"] == 1, "the waiter queued")
+        clock.now += 0.5
+        hog.release(tickets[0])
+        _until(lambda: order == ["foreground"], "the waiter admitted")
+        go.set()
+        t.join(10)
+        with q.admission("foreground", 1000, span=sp):  # a free slot: no event
+            pass
+        trace.finish(sp)
+        doc = trace.traces()[-1]
+        (ev,) = [e for e in doc["events"] if e["name"] == "window_full"]
+        assert ev["attrs"] == {"by": "recovery", "held": {"recovery": 2}}
+        assert doc["stages"]["admission_wait"]["seconds"] == 0.5
+    finally:
+        trace.configure(enabled=False)
+        trace.reset()
+
+    # disarmed: a blocked admission counts, and touches nothing of the tracer
+    class NoClock:
+        def __getattr__(self, name):
+            raise AssertionError(f"the disarmed path read the tracer's time.{name}")
+
+    monkeypatch.setattr(trace, "time", NoClock())
+    monkeypatch.setattr(trace, "_StageTimer", None)  # constructing one fails
+    monkeypatch.setattr(trace.Span, "event", None)
+    tickets[0], _ = hog.dispatch(lambda: None, 10_000)  # the window full again
+    order = []
+    t, go = _waiter(q, "foreground", order)
+    _until(lambda: q.stats()["foreground"]["depth"] == 1, "the waiter queued")
+    hog.release(tickets[1])
+    _until(lambda: order == ["foreground"], "the waiter admitted")
+    go.set()
+    t.join(10)
+    assert q.stats()["foreground"]["blocked"] == {"recovery": 2}
+    hog.close()
+
+
+def test_blocked_counts_and_slot_seconds_lose_no_update_under_contention():
+    """More threads than cores on a window of 2, the interpreter
+    switching every 10 us: what the queue counted is what the tickets it
+    handed out say, to the unit."""
+    import collections
+    import sys
+
+    q = DeviceQueue(window=2, label="chip0")
+    tallies = [collections.Counter() for _ in range(24)]
+
+    def work(n: int) -> None:
+        cls = ("foreground", "recovery", "scrub")[n % 3]
+        s = q.stream(cls)
+        for _ in range(150):
+            t, _ = s.dispatch(lambda: None, 1000)
+            if t.blocked is not None:
+                tallies[n][(cls, t.blocked[0])] += 1
+                assert sum(t.blocked[1].values()) == 2  # a full window, at arrival
+            s.release(t)
+        s.close()
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=work, args=(n,), daemon=True) for n in range(24)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(120)
+        assert not any(t.is_alive() for t in threads)
+    finally:
+        sys.setswitchinterval(interval)
+    want = sum(tallies, collections.Counter())
+    st = q.stats()
+    got = {(cls, by): n for cls in st for by, n in st[cls]["blocked"].items()}
+    assert got == dict(want) and sum(want.values()) > 0
+    assert all(st[c]["admitted"] == st[c]["drained"] == 8 * 150 for c in st)
+    for c in st.values():
+        assert sum(c["blocked_s"].values()) <= c["wait_s_total"] + 1e-6
+        assert c["slot_s"] >= 0 and c["inflight"] == 0
+    assert q.inflight == 0
