@@ -20,11 +20,14 @@
 #include <cstddef>
 #include <cstdlib>
 #include <cerrno>
+#include <atomic>
 #include <thread>
 #include <vector>
 
 #include <fcntl.h>
+#include <pthread.h>
 #include <sys/uio.h>
+#include <time.h>
 #include <unistd.h>
 
 #include "sn_net.h"
@@ -34,6 +37,26 @@
 #endif
 
 extern "C" {
+
+// ---------------------------------------------------------------------------
+// The return stamp. Every call below that does real work without the
+// interpreter (sn_batch_pread, sn_crc32c_granules, sn_sendv,
+// sn_sink_append) writes CLOCK_MONOTONIC through `ret_ns` as its last
+// act: Python's time.perf_counter_ns() is the same clock, so the wrapper
+// reads how long the calling thread then waited to hold the interpreter
+// again (utils/native.py books it when the tracer is armed; a caller
+// that does not ask passes NULL).
+// ---------------------------------------------------------------------------
+
+static inline int64_t mono_ns() {
+    struct timespec ts;
+    clock_gettime(CLOCK_MONOTONIC, &ts);
+    return (int64_t)ts.tv_sec * 1000000000LL + (int64_t)ts.tv_nsec;
+}
+
+static inline void stamp_return(int64_t* ret_ns) {
+    if (ret_ns) *ret_ns = mono_ns();
+}
 
 // ---------------------------------------------------------------------------
 // CRC32C
@@ -441,11 +464,11 @@ static const size_t kThreadMinWidth = (size_t)4 << 20;
 // zero padding); completed granule CRCs land at out_crcs[i*max_out..],
 // counts in out_counts[i] (-1 = out_crcs overflow).
 // Returns 0, or -(i+1) for the first failed row.
-int sn_batch_pread(const int* fds, const uint64_t* offsets, int nrows,
-                   uint8_t* dst, size_t width, size_t stride, int pad_eof,
-                   uint32_t granule, uint32_t* crc_state,
-                   uint64_t* filled_state, uint32_t* out_crcs,
-                   int32_t* out_counts, int32_t max_out) {
+static int batch_pread(const int* fds, const uint64_t* offsets, int nrows,
+                       uint8_t* dst, size_t width, size_t stride, int pad_eof,
+                       uint32_t granule, uint32_t* crc_state,
+                       uint64_t* filled_state, uint32_t* out_crcs,
+                       int32_t* out_counts, int32_t max_out) {
     crc32c_table_init();
     std::vector<int> status((size_t)nrows, 0);
     auto work = [&](int i) {
@@ -511,6 +534,18 @@ int sn_batch_pread(const int* fds, const uint64_t* offsets, int nrows,
     return 0;
 }
 
+int sn_batch_pread(const int* fds, const uint64_t* offsets, int nrows,
+                   uint8_t* dst, size_t width, size_t stride, int pad_eof,
+                   uint32_t granule, uint32_t* crc_state,
+                   uint64_t* filled_state, uint32_t* out_crcs,
+                   int32_t* out_counts, int32_t max_out, int64_t* ret_ns) {
+    int rc = batch_pread(fds, offsets, nrows, dst, width, stride, pad_eof,
+                         granule, crc_state, filled_state, out_crcs,
+                         out_counts, max_out);
+    stamp_return(ret_ns);
+    return rc;
+}
+
 // CRC32C of every `granule`-byte piece of each row of a (nrows, width)
 // matrix whose rows lie `stride` bytes apart; a row's last piece may be
 // short. out[i * per_row + g] with per_row = ceil(width / granule). One
@@ -519,7 +554,8 @@ int sn_batch_pread(const int* fds, const uint64_t* offsets, int nrows,
 // walked in a loop: a reconstruction's few MiB are a millisecond of
 // hardware CRC32C, less than starting a thread per row would cost.
 void sn_crc32c_granules(const uint8_t* rows, int nrows, size_t width,
-                        size_t stride, uint32_t granule, uint32_t* out) {
+                        size_t stride, uint32_t granule, uint32_t* out,
+                        int64_t* ret_ns) {
     size_t per_row = (width + granule - 1) / granule;
     for (int i = 0; i < nrows; i++) {
         const uint8_t* p = rows + (size_t)i * stride;
@@ -529,6 +565,7 @@ void sn_crc32c_granules(const uint8_t* rows, int nrows, size_t width,
             out[(size_t)i * per_row + g] = sn_crc32c(0, p + at, len);
         }
     }
+    stamp_return(ret_ns);
 }
 
 // Best-effort readahead hint for the NEXT batch's extent; the producer
@@ -571,8 +608,8 @@ int64_t sn_send_file(int out_fd, int in_fd, uint64_t offset, uint64_t len,
 // Scatter-gather send of n buffers. Returns total bytes sent (== sum of
 // lens on success) or -errno; a peer that dies mid-stream surfaces as
 // -EPIPE/-ECONNRESET, a stalled peer as -ETIMEDOUT.
-int64_t sn_sendv(int fd, const uint8_t* const* bufs, const uint64_t* lens,
-                 int n, int timeout_ms) {
+static int64_t sendv(int fd, const uint8_t* const* bufs,
+                     const uint64_t* lens, int n, int timeout_ms) {
     int64_t total = 0;
     int i = 0;
     uint64_t off = 0;  // progress within bufs[i]
@@ -615,6 +652,13 @@ int64_t sn_sendv(int fd, const uint8_t* const* bufs, const uint64_t* lens,
             }
         }
     }
+}
+
+int64_t sn_sendv(int fd, const uint8_t* const* bufs, const uint64_t* lens,
+                 int n, int timeout_ms, int64_t* ret_ns) {
+    int64_t sent = sendv(fd, bufs, lens, n, timeout_ms);
+    stamp_return(ret_ns);
+    return sent;
 }
 
 // Receive up to `len` bytes from fd straight into dst. With granule>0
@@ -951,10 +995,10 @@ static int sink_pwrite(SnSink* s, int i, const uint8_t* p, size_t len,
 // leaf level, completed leaf CRCs likewise in out_leaf_*. A -1 count
 // reports out-array overflow. Returns 0 or -(i+1) for the first failed
 // shard.
-int sn_sink_append(void* handle, const uint8_t* const* rows, size_t width,
-                   uint32_t* out_block_crcs, int32_t* out_block_counts,
-                   uint32_t* out_leaf_crcs, int32_t* out_leaf_counts,
-                   int32_t max_out) {
+static int sink_append(void* handle, const uint8_t* const* rows, size_t width,
+                       uint32_t* out_block_crcs, int32_t* out_block_counts,
+                       uint32_t* out_leaf_crcs, int32_t* out_leaf_counts,
+                       int32_t max_out) {
     SnSink* s = (SnSink*)handle;
     int n = (int)s->fds.size();
     uint32_t leaves_per_block =
@@ -1032,6 +1076,17 @@ int sn_sink_append(void* handle, const uint8_t* const* rows, size_t width,
     for (int i = 0; i < n; i++)
         if (status[i] != 0) return -(i + 1);
     return 0;
+}
+
+int sn_sink_append(void* handle, const uint8_t* const* rows, size_t width,
+                   uint32_t* out_block_crcs, int32_t* out_block_counts,
+                   uint32_t* out_leaf_crcs, int32_t* out_leaf_counts,
+                   int32_t max_out, int64_t* ret_ns) {
+    int rc = sink_append(handle, rows, width, out_block_crcs,
+                         out_block_counts, out_leaf_crcs, out_leaf_counts,
+                         max_out);
+    stamp_return(ret_ns);
+    return rc;
 }
 
 // Flush the partial-tail CRC of each level (valid flag per shard) and
@@ -1159,6 +1214,89 @@ int64_t sn_scan_dat(const char* path, uint64_t* ids, uint32_t* offsets,
     }
     munmap((void*)buf, size);
     return count;
+}
+
+// ---------------------------------------------------------------------------
+// The core-wait probe: a thread that never touches Python sleeps
+// `period_ns` at a time (clock_nanosleep to an absolute deadline) and
+// records how late it woke: ready to run until it had a core. Its twin
+// in utils/interp_probe.py does the same from a Python thread, where
+// waking also means taking the interpreter, so the twin's wait less this
+// one is the queue for the interpreter itself. Samples (wake time, wait)
+// go into a fixed ring; sn_probe_read copies out what a cursor has not
+// seen. One probe a process; nothing runs until sn_probe_start.
+// ---------------------------------------------------------------------------
+
+static const uint64_t kProbeRing = 4096;  // 20 s at the probe's 5 ms
+static int64_t probe_ring[kProbeRing][2];
+static std::atomic<uint64_t> probe_head{0};  // samples written, ever
+static std::atomic<int> probe_running{0};
+static int64_t probe_period_ns = 0;
+static pthread_t probe_thread;
+static pid_t probe_pid = 0;  // a forked child inherits the flag, not the thread
+
+static void* probe_main(void*) {
+    int64_t deadline = mono_ns() + probe_period_ns;
+    while (probe_running.load(std::memory_order_relaxed)) {
+        struct timespec ts;
+        ts.tv_sec = (time_t)(deadline / 1000000000LL);
+        ts.tv_nsec = (long)(deadline % 1000000000LL);
+        while (clock_nanosleep(CLOCK_MONOTONIC, TIMER_ABSTIME, &ts, nullptr)
+               == EINTR) {
+        }
+        int64_t now = mono_ns();
+        uint64_t h = probe_head.load(std::memory_order_relaxed);
+        probe_ring[h % kProbeRing][0] = now;
+        probe_ring[h % kProbeRing][1] = now - deadline;
+        probe_head.store(h + 1, std::memory_order_release);
+        deadline = now + probe_period_ns;
+    }
+    return nullptr;
+}
+
+// 0, or -errno (-EBUSY where the probe already runs).
+int sn_probe_start(int64_t period_ns) {
+    if (period_ns <= 0) return -EINVAL;
+    if (probe_pid != getpid()) probe_running.store(0);
+    int was = 0;
+    if (!probe_running.compare_exchange_strong(was, 1)) return -EBUSY;
+    probe_pid = getpid();
+    probe_period_ns = period_ns;
+    int rc = pthread_create(&probe_thread, nullptr, probe_main, nullptr);
+    if (rc != 0) {
+        probe_running.store(0);
+        return -rc;
+    }
+    return 0;
+}
+
+// Stops and joins the probe (at most one period); 0 where none runs.
+int sn_probe_stop() {
+    int was = 1;
+    if (!probe_running.compare_exchange_strong(was, 0)) return 0;
+    if (probe_pid != getpid()) return 0;
+    return -pthread_join(probe_thread, nullptr);
+}
+
+// Samples written so far: a reader's first cursor.
+uint64_t sn_probe_head() {
+    return probe_head.load(std::memory_order_acquire);
+}
+
+// Copy the samples from *cursor on into out (pairs: wake time, wait; both
+// ns on CLOCK_MONOTONIC), at most max_pairs, and move *cursor past them.
+// A reader that fell a whole ring behind loses the overwritten ones.
+int32_t sn_probe_read(uint64_t* cursor, int64_t* out, int32_t max_pairs) {
+    uint64_t head = probe_head.load(std::memory_order_acquire);
+    uint64_t at = *cursor;
+    if (head - at > kProbeRing) at = head - kProbeRing;
+    int32_t n = 0;
+    for (; at < head && n < max_pairs; at++, n++) {
+        out[2 * n] = probe_ring[at % kProbeRing][0];
+        out[2 * n + 1] = probe_ring[at % kProbeRing][1];
+    }
+    *cursor = at;
+    return n;
 }
 
 int sn_has_avx2() {

@@ -193,7 +193,11 @@ class MqAgentServer:
 
     def __init__(self, broker: str, ip: str = "localhost", port: int = 0):
         self.service = MqAgentService(broker)
-        self._grpc = grpc.server(futures.ThreadPoolExecutor(max_workers=16))
+        self._grpc = grpc.server(
+            futures.ThreadPoolExecutor(
+                max_workers=16, thread_name_prefix="grpc-mq-agent"
+            )
+        )
         rpc.add_service(self._grpc, rpc.MQ_AGENT_SERVICE, self.service)
         self.port = self._grpc.add_insecure_port(f"{ip}:{port}")
         self.ip = ip

@@ -1279,7 +1279,11 @@ class MqBrokerServer:
         self.service = MqService(
             self.broker, balancer=self.balancer, load_fn=self.load_score
         )
-        self._grpc = grpc.server(futures.ThreadPoolExecutor(max_workers=32))
+        self._grpc = grpc.server(
+            futures.ThreadPoolExecutor(
+                max_workers=32, thread_name_prefix="grpc-mq-broker"
+            )
+        )
         rpc.add_service(self._grpc, rpc.MQ_SERVICE, self.service)
         self._grpc.add_insecure_port(f"{ip}:{grpc_port}")
         self.kafka = None

@@ -192,7 +192,10 @@ class S3Server:
         self.tls = tls
         if tls is not None:
             tls.wrap_server(self._http)
-        self._thread = threading.Thread(target=self._http.serve_forever, daemon=True)
+        self._thread = threading.Thread(
+            target=self._http.serve_forever, daemon=True,
+            name="http-accept-s3",
+        )
         from .lifecycle import LifecycleScanner
 
         self.lifecycle = LifecycleScanner(filer)

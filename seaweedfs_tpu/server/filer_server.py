@@ -77,7 +77,10 @@ class FilerServer:
         self.tls = tls
         if tls is not None:
             tls.wrap_server(self._http)
-        self._thread = threading.Thread(target=self._http.serve_forever, daemon=True)
+        self._thread = threading.Thread(
+            target=self._http.serve_forever, daemon=True,
+            name="http-accept-filer",
+        )
         # gRPC metadata service (reference weed/pb/filer.proto service)
         from concurrent import futures as _futures
 
@@ -86,7 +89,11 @@ class FilerServer:
         from ..filer.grpc_service import FilerGrpcService
         from ..pb import rpc as _rpc
 
-        self._grpc = _grpc.server(_futures.ThreadPoolExecutor(max_workers=16))
+        self._grpc = _grpc.server(
+            _futures.ThreadPoolExecutor(
+                max_workers=16, thread_name_prefix="grpc-filer"
+            )
+        )
         self._grpc_service = FilerGrpcService(filer, meta_log)
         _rpc.add_service(self._grpc, _rpc.FILER_SERVICE, self._grpc_service)
         self.grpc_port = self._grpc.add_insecure_port(f"{ip}:{grpc_port}")

@@ -580,7 +580,11 @@ class MasterServer:
             config_get=self._maintenance_config,
             config_set=self._apply_maintenance_config,
         )
-        self._grpc = grpc.server(futures.ThreadPoolExecutor(max_workers=32))
+        self._grpc = grpc.server(
+            futures.ThreadPoolExecutor(
+                max_workers=32, thread_name_prefix="grpc-master"
+            )
+        )
         rpc.add_service(self._grpc, rpc.MASTER_SERVICE, self.service)
         rpc.add_service(self._grpc, rpc.WORKER_SERVICE, self.worker_control)
         rpc.add_service(self._grpc, rpc.RAFT_SERVICE, self.raft)
@@ -591,7 +595,8 @@ class MasterServer:
         if tls is not None:
             tls.wrap_server(self._http)
         self._http_thread = threading.Thread(
-            target=self._http.serve_forever, daemon=True
+            target=self._http.serve_forever, daemon=True,
+            name="http-accept-master",
         )
 
         # opt-in phone-home (reference weed/telemetry/collector.go:14):

@@ -1242,7 +1242,12 @@ class VolumeServer:
         except Exception as e:  # port collision etc: gRPC-only peer
             logger("volume").warning("shard net plane disabled: %s", e)
 
-        self._grpc = grpc.server(futures.ThreadPoolExecutor(max_workers=32))
+        # named: the wait probes sum CPU by class of thread from the name
+        self._grpc = grpc.server(
+            futures.ThreadPoolExecutor(
+                max_workers=32, thread_name_prefix="grpc-volume"
+            )
+        )
         rpc.add_service(self._grpc, rpc.VOLUME_SERVICE, self.service)
         self._grpc.add_insecure_port(f"{ip}:{self.grpc_port}")
         # Bounded worker-pool HTTP data plane (utils/http_pool.py):
@@ -1269,7 +1274,8 @@ class VolumeServer:
         if tls is not None:
             tls.wrap_server(self._http)
         self._http_thread = threading.Thread(
-            target=self._http.serve_forever, daemon=True
+            target=self._http.serve_forever, daemon=True,
+            name="http-accept-volume",
         )
         self._hb_queue: "queue.Queue[pb.Heartbeat]" = queue.Queue()
         self._hb_stop = threading.Event()

@@ -7,6 +7,7 @@ histograms with labels, rendered in the text format Prometheus scrapes.
 
 from __future__ import annotations
 
+import bisect
 import threading
 import time
 from typing import Callable, Iterable
@@ -113,6 +114,21 @@ class Histogram(_Metric):
                     counts[i] += 1
             self._sums[key] = self._sums.get(key, 0.0) + value
             self._totals[key] = self._totals.get(key, 0) + 1
+
+    def observe_many(self, values: Iterable[float], **labels) -> None:
+        """observe() for a batch, under one hold of the lock."""
+        key = tuple(labels.get(n, "") for n in self.label_names)
+        with self._lock:
+            counts = self._counts.setdefault(key, [0] * len(self.buckets))
+            total = 0.0
+            n = 0
+            for value in values:
+                for i in range(bisect.bisect_left(self.buckets, value), len(counts)):
+                    counts[i] += 1
+                total += value
+                n += 1
+            self._sums[key] = self._sums.get(key, 0.0) + total
+            self._totals[key] = self._totals.get(key, 0) + n
 
     def time(self, **labels):
         return _Timer(self, labels)

@@ -14,6 +14,8 @@ import subprocess
 
 import numpy as np
 
+from . import trace as _trace
+
 _NATIVE_DIR = os.environ.get(
     "SEAWEED_NATIVE_DIR",
     os.path.join(
@@ -120,6 +122,7 @@ _lib.sn_batch_pread.argtypes = [
     ctypes.c_void_p,                  # out_crcs
     ctypes.c_void_p,                  # out_counts
     ctypes.c_int32,                   # max_out
+    ctypes.c_void_p,                  # ret_ns (i64[1] or NULL: see _stamped)
 ]
 _lib.sn_crc32c_granules.restype = None
 _lib.sn_crc32c_granules.argtypes = [
@@ -129,6 +132,7 @@ _lib.sn_crc32c_granules.argtypes = [
     ctypes.c_size_t,   # stride
     ctypes.c_uint32,   # granule
     ctypes.c_void_p,   # out (u32[nrows * ceil(width / granule)])
+    ctypes.c_void_p,   # ret_ns
 ]
 _lib.sn_fadvise_willneed.restype = ctypes.c_int
 _lib.sn_fadvise_willneed.argtypes = [
@@ -156,6 +160,7 @@ _lib.sn_sink_append.argtypes = [
     ctypes.c_void_p,                   # out_leaf_crcs
     ctypes.c_void_p,                   # out_leaf_counts
     ctypes.c_int32,                    # max_out
+    ctypes.c_void_p,                   # ret_ns
 ]
 _lib.sn_sink_finish.restype = ctypes.c_int
 _lib.sn_sink_finish.argtypes = [
@@ -188,6 +193,7 @@ _lib.sn_sendv.argtypes = [
     ctypes.POINTER(ctypes.c_uint64),  # lens
     ctypes.c_int,                     # n
     ctypes.c_int,                     # timeout_ms
+    ctypes.c_void_p,                  # ret_ns
 ]
 _lib.sn_recv_into.restype = ctypes.c_int64
 _lib.sn_recv_into.argtypes = [
@@ -234,6 +240,39 @@ _lib.sn_scan_dat.argtypes = [
     ctypes.c_void_p,
     ctypes.c_int64,
 ]
+# the core-wait probe (utils/interp_probe.py owns its life)
+_lib.sn_probe_start.restype = ctypes.c_int
+_lib.sn_probe_start.argtypes = [ctypes.c_int64]
+_lib.sn_probe_stop.restype = ctypes.c_int
+_lib.sn_probe_stop.argtypes = []
+_lib.sn_probe_head.restype = ctypes.c_uint64
+_lib.sn_probe_head.argtypes = []
+_lib.sn_probe_read.restype = ctypes.c_int32
+_lib.sn_probe_read.argtypes = [
+    ctypes.POINTER(ctypes.c_uint64),  # cursor, moved past what was read
+    ctypes.c_void_p,                  # out (i64[max_pairs, 2]: wake, wait)
+    ctypes.c_int32,                   # max_pairs
+]
+
+
+# The four calls that do real work without the interpreter (batch_pread,
+# crc32c_granules, sendv, NativeSink.append) end by writing
+# CLOCK_MONOTONIC where their last argument, `ret_ns`, points: how long
+# the thread then waits to hold the interpreter again is
+# `perf_counter_ns()` on return less that stamp. Armed, the call gets a
+# word and the difference goes to its span (trace.book_return);
+# disarmed it gets NULL and the C side writes nothing: one module-bool
+# check at each seam.
+
+
+def _stamped(fn, *args):
+    """fn(*args, ret_ns): the native call, its return booked if armed."""
+    if not _trace.armed:
+        return fn(*args, None)
+    word = ctypes.c_int64(0)
+    rc = fn(*args, ctypes.addressof(word))
+    _trace.book_return(word.value)
+    return rc
 
 
 def crc32c(data, crc: int = 0) -> int:
@@ -260,7 +299,8 @@ def crc32c_granules(rows: np.ndarray, granule: int) -> np.ndarray:
     n, width = rows.shape
     out = np.empty((n, -(-width // granule)), dtype=np.uint32)
     if out.size:
-        _lib.sn_crc32c_granules(
+        _stamped(
+            _lib.sn_crc32c_granules,
             ctypes.c_void_p(rows.ctypes.data), n, width, rows.strides[0],
             granule, ctypes.c_void_p(out.ctypes.data),
         )
@@ -399,7 +439,8 @@ def batch_pread(
         assert out_crcs.dtype == np.uint32 and out_crcs.flags.c_contiguous
         assert out_counts.dtype == np.int32
         max_out = out_crcs.shape[1]
-    rc = _lib.sn_batch_pread(
+    rc = _stamped(
+        _lib.sn_batch_pread,
         (ctypes.c_int * n)(*fds),
         (ctypes.c_uint64 * n)(*offsets),
         n,
@@ -484,7 +525,7 @@ def sendv(out_fd: int, parts, timeout_ms: int = -1) -> int:
         ptrs[i] = addr
         lens[i] = ln
         total += ln
-    sent = _lib.sn_sendv(out_fd, ptrs, lens, n, timeout_ms)
+    sent = _stamped(_lib.sn_sendv, out_fd, ptrs, lens, n, timeout_ms)
     if sent < 0:
         raise OSError(-sent, f"sn_sendv: {os.strerror(-sent)}")
     if sent != total:  # pragma: no cover - C side only shorts on error
@@ -658,7 +699,8 @@ class NativeSink:
         if self._h is None:
             raise OSError("sink already destroyed")
         assert len(row_ptrs) == self.n
-        rc = _lib.sn_sink_append(
+        rc = _stamped(
+            _lib.sn_sink_append,
             self._h,
             (ctypes.c_void_p * self.n)(*row_ptrs),
             width,

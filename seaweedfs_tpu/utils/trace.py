@@ -49,6 +49,14 @@ Model
   trace id spanning master task → rebuilding holder → every peer's
   shard-read stream.
 
+- While armed, two WAIT PROBES (``utils/interp_probe.py``, started and
+  stopped by :func:`configure`) measure what no span can: how long a
+  thread that is ready to run waits for the interpreter, how long for a
+  core, and which class of thread burnt the CPU. Every 100 ms they close
+  a root span :data:`PROBE_OP`, kept in a ring of its own. The native
+  calls of ``utils/native.py`` book what their return to the interpreter
+  cost (:func:`book_return`: ``interp_wait_ns``, ``interp_returns``).
+
 Canonical stage names (the Prometheus ``stage`` label of
 ``sw_ec_stage_seconds``):
 
@@ -253,6 +261,11 @@ armed = False
 _lock = threading.Lock()
 _ring: deque = deque(maxlen=DEFAULT_RING)
 _ring_spans = 0  # total span count across the retained docs
+# The wait probes (utils/interp_probe.py) close a root span every 100 ms
+# for as long as the tracer is armed: they get a ring of their own, of the
+# same size, so that ten a second never push an operation's trace out.
+PROBE_OP = "interp.probe"
+_probe_ring: deque = deque(maxlen=DEFAULT_RING)
 _max_ring_spans = DEFAULT_RING_SPANS
 _slow_op_s = 0.0
 
@@ -544,12 +557,15 @@ class Span:
 
     # --------------------------------------------------------- lifecycle
 
-    def finish(self) -> None:
+    def finish(self, end_ns: int = 0) -> None:
+        """`end_ns`: the span ended at that clock reading, before it
+        could be closed (backdate()'s twin: the wait probes close an
+        interval after they have summed it up)."""
         with self._lock:
             if self._finished:
                 return
             self._finished = True
-            self.end_ns = time.perf_counter_ns()
+            self.end_ns = end_ns or time.perf_counter_ns()
             self.duration_s = (self.end_ns - self.start_ns) / 1e9
             if threading.get_ident() == self._ident:
                 self.cpu_s = (time.thread_time_ns() - self._c0) / 1e9
@@ -646,6 +662,11 @@ def _doc_span_count(doc: dict) -> int:
 def _complete_root(span: Span) -> None:
     global _ring_spans
     doc = span.to_dict()
+    if span.op == PROBE_OP:
+        doc["span_count"] = 1
+        with _lock:
+            _probe_ring.append(doc)
+        return
     eff = overlap_efficiency(doc)
     if eff is not None:
         doc["overlap_efficiency"] = round(eff, 4)
@@ -732,11 +753,12 @@ def configure(
     ``slow_op_s`` <= 0 disables the slow-op log. ``ring_spans`` bounds
     the TOTAL span count retained across the ring (memory bound for
     span-heavy op classes). Returns the effective config."""
-    global armed, _ring, _ring_spans, _max_ring_spans, _slow_op_s
+    global armed, _ring, _probe_ring, _ring_spans, _max_ring_spans, _slow_op_s
     with _lock:
         if ring_size is not None and ring_size > 0:
             if _ring.maxlen != ring_size:
                 _ring = deque(_ring, maxlen=int(ring_size))
+                _probe_ring = deque(_probe_ring, maxlen=int(ring_size))
                 _ring_spans = sum(
                     d.get("span_count", 1) for d in _ring
                 )
@@ -748,12 +770,22 @@ def configure(
             _slow_op_s = max(float(slow_op_s), 0.0)
         if enabled is not None:
             armed = bool(enabled)
-        return {
+        config = {
             "enabled": armed,
             "ring_size": _ring.maxlen,
             "ring_spans": _max_ring_spans,
             "slow_op_s": _slow_op_s,
         }
+    if enabled is not None:
+        # the wait probes live exactly as long as the tracer is armed;
+        # outside the lock: a probe closing a span takes it
+        from . import interp_probe
+
+        if enabled:
+            interp_probe.start()
+        else:
+            interp_probe.stop()
+    return config
 
 
 def reset() -> None:
@@ -761,6 +793,7 @@ def reset() -> None:
     global _ring_spans
     with _lock:
         _ring.clear()
+        _probe_ring.clear()
         _ring_spans = 0
     with _ewma_lock:
         _stage_ewma.clear()
@@ -917,6 +950,26 @@ def count(name: str, n: int) -> None:
     _seam_counters[name].inc(n, op=parent.span.op)
 
 
+def book_return(stamp_ns: int) -> None:
+    """A native call of utils/native.py has come back to the interpreter
+    (armed only: the caller checks). `stamp_ns` is what the C side read
+    off ``CLOCK_MONOTONIC`` as its last act; now less that is how long
+    this thread, ready to run, waited to hold the interpreter again. It
+    goes to the span whose stage the thread has open, else to the
+    ambient span, as ``interp_wait_ns`` beside ``interp_returns``."""
+    now = time.perf_counter_ns()
+    timer = _open_stage.get()
+    span = timer.span if timer is not None else _current.get()
+    if span is None or stamp_ns <= 0:
+        return
+    with span._lock:
+        attrs = span.attrs
+        attrs["interp_wait_ns"] = (
+            attrs.get("interp_wait_ns", 0) + max(now - stamp_ns, 0)
+        )
+        attrs["interp_returns"] = attrs.get("interp_returns", 0) + 1
+
+
 def add_stage(span, name: str, seconds: float, chip: str = "") -> None:
     if span is not None:
         span.add_stage(name, seconds, chip)
@@ -967,12 +1020,13 @@ def metadata_dict(context) -> dict:
 def traces(
     trace_id: str = "", op: str = "", min_ms: float = 0.0
 ) -> list[dict]:
-    """Completed root spans, oldest first. Filters: one trace id (a
-    cross-server trace is several roots sharing it), a root ``op``
-    class, and/or a minimum root duration in milliseconds — the
-    ``/debug/traces?op=&min_ms=`` query surface."""
+    """Completed root spans, oldest first (the wait probes' interval
+    spans, which have a ring of their own, before all others). Filters:
+    one trace id (a cross-server trace is several roots sharing it), a
+    root ``op`` class, and/or a minimum root duration in milliseconds —
+    the ``/debug/traces?op=&min_ms=`` query surface."""
     with _lock:
-        docs = list(_ring)
+        docs = list(_probe_ring) + list(_ring)
     if trace_id:
         docs = [d for d in docs if d["trace_id"] == trace_id]
     if op:
