@@ -15,14 +15,16 @@ import json
 import os
 import struct
 import threading
+import time
 from typing import Optional
 
 import numpy as np
 
 from .. import faults
-from ..storage.needle import CrcError, Needle
+from ..storage.needle import Needle, NeedleError
 from ..storage.needle_map import SortedFileNeedleMap
 from ..storage.types import actual_offset
+from ..utils import metrics as M
 from ..utils import trace
 from ..utils.chunk_cache import ChunkCache
 from ..utils.glog import logger
@@ -77,7 +79,9 @@ class EcVolume:
         lets the cluster layer serve shards held by peer servers
         (reference store_ec.go:599 streaming VolumeEcShardRead; the
         generation is the EncodeTsNs fence so a stale peer never answers);
-        recovery by local reconstruction remains the fallback.
+        recovery by local reconstruction remains the fallback. A reader
+        with a `peers(shard_id)` method (the volume server's) is asked
+        only for shards that some peer holds.
 
         `interval_cache_bytes` bounds the LRU of verified reconstructed
         extents (0 disables): repeated reads of needles on a missing
@@ -247,15 +251,17 @@ class EcVolume:
         rec_size = record_actual_size(nv.size, self.version)
         try:
             return self._parse(self._read_extent(off, rec_size), cookie, needle_id)
-        except CrcError:
-            # Local bytes are rotten (bitrot / torn shard). Self-heal on
-            # read: re-derive every interval by sidecar-verified
-            # reconstruction, bypassing the local shard copies. Either
-            # the record comes back bit-exact or this raises — a corrupt
-            # needle is never served.
+        except NeedleError as e:
+            # The bytes read are rotten (bitrot / torn shard / a peer
+            # that answered from the wrong place): the body fails its
+            # CRC, or the record's head does not parse or is another
+            # needle's. Self-heal on read: re-derive every interval by
+            # sidecar-verified reconstruction, bypassing the shard
+            # copies. Either the record comes back bit-exact or this
+            # raises — a corrupt needle is never served.
             log.warning(
-                "needle %x failed CRC from local shards; retrying via "
-                "verified reconstruction", needle_id,
+                "needle %x read from shards is not the record (%s); "
+                "retrying via verified reconstruction", needle_id, e,
             )
             return self._parse(
                 self._read_extent(off, rec_size, prefer_recovery=True),
@@ -264,6 +270,12 @@ class EcVolume:
 
     def _parse(self, raw: bytes, cookie: Optional[int], needle_id: int) -> Needle:
         n = Needle.from_bytes(raw, self.version)
+        if n.needle_id != needle_id:
+            # the sealed index says which record lies here
+            raise NeedleError(
+                f"the record at needle {needle_id:x}'s offset says it is "
+                f"needle {n.needle_id:x}"
+            )
         if cookie is not None and n.cookie != cookie:
             raise EcCookieMismatch(f"needle {needle_id:x} cookie mismatch")
         return n
@@ -304,12 +316,41 @@ class EcVolume:
                 self.bytes_read += size
                 return got
             # short read = truncated shard; fall through to recovery
-        if self.remote_reader is not None:
-            got = self.remote_reader(shard_id, offset, size, self.encode_ts_ns)
-            if got is not None and len(got) == size:
+        if self.remote_reader is not None and self._peer_lists(shard_id):
+            trace.lap("peer")
+            sp = trace.current()
+            with trace.stage(sp, "peer_read"):
+                got = self._read_from_peer("interval", shard_id, offset, size)
+            if sp is not None:
+                sp.count("peer_reads", 1)
+                sp.count("peer_read_bytes", 0 if got is None else size)
+            if got is not None:
                 self.bytes_read += size
                 return got
         return self._recover_interval(shard_id, offset, size)
+
+    def _peer_lists(self, shard_id: int) -> bool:
+        """Whether a read of this shard through `remote_reader` asks a
+        peer. The cluster's reader says which peers hold a shard
+        (`peers`), so a shard that no live server holds is a look-up
+        and no read; a bare callable cannot say and is taken to ask."""
+        peers = getattr(self.remote_reader, "peers", None)
+        return peers is None or bool(peers(shard_id))
+
+    def _read_from_peer(
+        self, kind: str, shard_id: int, offset: int, size: int
+    ) -> Optional[bytes]:
+        """[offset, offset+size) of a shard from the peers that hold it,
+        or None where none answered in full; counted at this, the
+        reader's, side as a read of `kind` (interval | sibling)."""
+        t0 = time.perf_counter()
+        got = self.remote_reader(shard_id, offset, size, self.encode_ts_ns)
+        M.ec_peer_reads_total.inc(kind=kind)
+        M.ec_peer_read_seconds_total.inc(time.perf_counter() - t0, kind=kind)
+        if got is None or len(got) != size:
+            return None
+        M.ec_peer_read_bytes_total.inc(size, kind=kind)
+        return got
 
     # ---------------------------------------------------------- recovery
 
@@ -487,18 +528,19 @@ class EcVolume:
             except OSError:
                 continue
             admit(row, [i], "sibling_rows_single")
+        missing = []
         if None in ids and self.remote_reader is not None:
-            import contextvars
-            from concurrent.futures import FIRST_COMPLETED, ThreadPoolExecutor, wait
-
             missing = [
                 i
                 for i in range(self.ctx.total)
-                if i != shard_id and i not in ids
+                if i != shard_id and i not in ids and self._peer_lists(i)
             ]
+        if missing:
+            import contextvars
+            from concurrent.futures import FIRST_COMPLETED, ThreadPoolExecutor, wait
 
             def fetch(i):
-                return i, self.remote_reader(i, offset, size, self.encode_ts_ns)
+                return i, self._read_from_peer("sibling", i, offset, size)
 
             def submit(ex, i):
                 # Per-task contextvar copy: the fetch thread sees the
@@ -509,25 +551,39 @@ class EcVolume:
             # stop as soon as k rows are filled: one hung peer must not
             # stall the read for the full RPC timeout
             ex = ThreadPoolExecutor(max_workers=min(len(missing), 8))
+            asked: list = []
+            looked_at = 0
             try:
-                # "sibling_read" covers only the blocked wait on peer
+                # "peer_read" covers only the blocked wait on peer
                 # fetches; admit() tags its own time "crc_verify".
-                with trace.stage(sp, "sibling_read"):
-                    futures = {submit(ex, i) for i in missing}
+                with trace.stage(sp, "peer_read"):
+                    asked = [submit(ex, i) for i in missing]
+                futures = set(asked)
                 while futures and None in ids:
-                    with trace.stage(sp, "sibling_read"):
+                    with trace.stage(sp, "peer_read"):
                         done, futures = wait(
                             futures, return_when=FIRST_COMPLETED
                         )
                     for f in done:
+                        if None not in ids:
+                            break  # full: what is left over is unused
+                        looked_at += 1
                         i, got = f.result()
-                        if got is None or len(got) != size or None not in ids:
+                        if got is None:
                             continue
                         row = ids.index(None)
                         matrix[row] = np.frombuffer(got, dtype=np.uint8)
                         admit(row, [i], "sibling_rows_single")
+                        if sp is not None and ids[row] == i:
+                            sp.count("sibling_rows_remote", 1)
             finally:
+                # fetches still waiting for a thread are cancelled; those
+                # that run finish unread, and their peers' work is wasted
                 ex.shutdown(wait=False, cancel_futures=True)
+                if sp is not None:
+                    started = sum(1 for f in asked if not f.cancelled())
+                    sp.count("peer_fetches_started", started)
+                    sp.count("peer_fetches_unused", started - looked_at)
         if None in ids:
             raise ECError(
                 f"shard {shard_id} unavailable and only "

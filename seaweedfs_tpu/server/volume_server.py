@@ -28,7 +28,7 @@ from ..ec.encoder import ec_encode_volume
 from ..ec.rebuild import rebuild_ec_files
 from ..ec.volume_info import VolumeInfo
 from ..storage.file_id import FileId, FileIdError
-from ..storage.needle import CrcError, Needle
+from ..storage.needle import Needle, NeedleError
 from ..storage.store import Store
 from ..storage.volume import (
     CookieMismatch,
@@ -282,7 +282,7 @@ class VolumeService:
             )
         except (NotFoundError, ECError) as e:
             return pb.ReadNeedleResponse(error=f"not found: {e}")
-        except (CookieMismatch, CrcError, VolumeError, ValueError, OSError) as e:
+        except (CookieMismatch, NeedleError, VolumeError, ValueError, OSError) as e:
             return pb.ReadNeedleResponse(error=str(e))
         return pb.ReadNeedleResponse(
             data=n.data,
@@ -1098,6 +1098,57 @@ class VolumeService:
         return None
 
 
+class _PeerShardReader:
+    """`EcVolume.remote_reader` of one EC volume on a volume server:
+    ranges of shards that lie elsewhere, from the peers the master lists
+    for them, over `VolumeEcShardRead` (generation-fenced at the holder;
+    reference store_ec.go:599-651)."""
+
+    def __init__(self, server: "VolumeServer", vid: int):
+        self.server = server
+        self.vid = vid
+
+    def peers(self, shard_id: int) -> list[str]:
+        """gRPC addresses of the OTHER servers the master lists for the
+        shard, from the client's cached map: none where it lists none,
+        so a caller can tell a read that asks a peer from a look-up
+        that asks nobody."""
+        vs = self.server
+        try:
+            locs = vs._master_client().lookup_ec(self.vid).get(shard_id, [])
+        except (LookupError, grpc.RpcError):
+            return []
+        me = f"{vs.ip}:{vs.grpc_port}"
+        addrs = (f"{loc.url.split(':')[0]}:{loc.grpc_port}" for loc in locs)
+        return [peer for peer in addrs if peer != me]
+
+    def __call__(self, shard_id: int, offset: int, size: int, generation: int):
+        for peer in self.peers(shard_id):
+            try:
+                buf = b"".join(
+                    c.data
+                    for c in self.server._peer_stub(peer).VolumeEcShardRead(
+                        pb.EcShardReadRequest(
+                            volume_id=self.vid,
+                            shard_id=shard_id,
+                            offset=offset,
+                            size=size,
+                            generation=generation,
+                        ),
+                        timeout=30,
+                        # request id + trace context ride to the
+                        # peer: a degraded read's remote sibling
+                        # fetches join the reader's trace
+                        metadata=trace.grpc_metadata(),
+                    )
+                )
+                if len(buf) == size:
+                    return buf
+            except grpc.RpcError:
+                continue
+        return None
+
+
 class VolumeServer:
     def __init__(
         self,
@@ -1513,41 +1564,7 @@ class VolumeServer:
             return rpc.volume_stub(ch)
 
     def _remote_reader_factory(self, vid: int, collection: str):
-        def read(shard_id: int, offset: int, size: int, generation: int):
-            try:
-                locs = self._master_client().lookup_ec(vid).get(shard_id, [])
-            except (LookupError, grpc.RpcError):
-                return None
-            my_url = f"{self.ip}:{self.grpc_port}"
-            for loc in locs:
-                peer = f"{loc.url.split(':')[0]}:{loc.grpc_port}"
-                if peer == my_url:
-                    continue
-                try:
-                    buf = b"".join(
-                        c.data
-                        for c in self._peer_stub(peer).VolumeEcShardRead(
-                            pb.EcShardReadRequest(
-                                volume_id=vid,
-                                shard_id=shard_id,
-                                offset=offset,
-                                size=size,
-                                generation=generation,
-                            ),
-                            timeout=30,
-                            # request id + trace context ride to the
-                            # peer: a degraded read's remote sibling
-                            # fetches join the reader's trace
-                            metadata=trace.grpc_metadata(),
-                        )
-                    )
-                    if len(buf) == size:
-                        return buf
-                except grpc.RpcError:
-                    continue
-            return None
-
-        return read
+        return _PeerShardReader(self, vid)
 
     # ---------------------------------------------- peer-fetch rebuild
 
@@ -2491,7 +2508,9 @@ class VolumeServer:
                         )
                 except (NotFoundError, ECError) as e:
                     return self._error(404, str(e))
-                except (CookieMismatch, CrcError) as e:
+                except (CookieMismatch, NeedleError) as e:
+                    # a record that fails its CRC or does not parse is an
+                    # error RESPONSE too, never a dropped connection
                     return self._error(404, str(e))
                 except (VolumeError, ValueError, OSError) as e:
                     # volume closed/converted mid-read: an error RESPONSE,

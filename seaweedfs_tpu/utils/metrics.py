@@ -352,6 +352,23 @@ ec_ops_total = REGISTRY.counter(
 ec_bytes_total = REGISTRY.counter(
     "sw_ec_bytes_total", "bytes through the EC pipeline", ("op", "backend")
 )
+# An EC read that asked a PEER for bytes of a shard (ec/ec_volume.py),
+# counted at the reader, always on: kind = interval (a healthy interval
+# of a needle) | sibling (a row of a reconstruction's matrix). The
+# holder's side is sw_net_bytes_sent_total{plane="python",direction="read"}.
+ec_peer_reads_total = REGISTRY.counter(
+    "sw_ec_peer_reads_total",
+    "shard ranges an EC read asked a peer for", ("kind",),
+)
+ec_peer_read_bytes_total = REGISTRY.counter(
+    "sw_ec_peer_read_bytes_total",
+    "bytes of shard ranges that peers answered EC reads with", ("kind",),
+)
+ec_peer_read_seconds_total = REGISTRY.counter(
+    "sw_ec_peer_read_seconds_total",
+    "seconds EC reads waited for peers' shard ranges (a reconstruction's "
+    "fetches run side by side: each counts its own)", ("kind",),
+)
 ec_leaf_repairs_total = REGISTRY.counter(
     "sw_ec_leaf_repairs_total",
     "leaf-granular in-place EC shard repairs by outcome "

@@ -170,6 +170,9 @@ STAGES = frozenset({
     "sibling_read", "h2d_dispatch", "device_drain", "write_sink",
     "crc_verify", "verify", "reconstruct", "fsync_publish", "stream",
     "index_sort", "peer_fetch",
+    # an EC read's wait for bytes of a shard that a peer holds
+    # (ec/ec_volume.py: an interval of a needle, a reconstruction's rows)
+    "peer_read",
     # leaf repair (PR 8)
     "repair_patch", "repair_fetch",
     # streaming EC (PR 14): incremental parity math + delta pwrites
@@ -187,7 +190,7 @@ _SUB_PARTS = {
     "device_drain": ("ready", "d2h", "host_copy"),
     "reconstruct": ("put", "launch", "ready", "d2h"),
     # an EC needle read (ec/ec_volume.py), under the HTTP handler's stage
-    "volume.read": ("index", "shard", "recover", "parse"),
+    "volume.read": ("index", "shard", "peer", "recover", "parse"),
 }
 _SUB_NAME = {
     (parent, part): f"{parent}.{part}"

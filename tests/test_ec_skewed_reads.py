@@ -26,7 +26,7 @@ from ecbench import harness
 from ecbench import reference_decode
 from seaweedfs_tpu.ec import CpuBackend, EcVolume, ec_encode_volume
 from seaweedfs_tpu.ec.context import DEFAULT_EC_CONTEXT as CTX
-from seaweedfs_tpu.utils import trace
+from seaweedfs_tpu.utils import metrics, trace
 
 from test_trace import _NoClock
 
@@ -332,6 +332,7 @@ def test_a_traced_server_records_the_parts_under_its_http_roots(ycsb, encoded, t
         healthy = next(i for i in range(len(vol.sizes)) if i not in on_lost)
         degraded = next(iter(sorted(on_lost)))
         trace.reset()
+        peer_reads_before = metrics.ec_peer_reads_total.snapshot()
         conn = http.client.HTTPConnection(*cl.volume_host, timeout=30)
         for i in (healthy, degraded):
             status, body = ycsb.G._get(conn, vol.fid(i))
@@ -361,6 +362,12 @@ def test_a_traced_server_records_the_parts_under_its_http_roots(ycsb, encoded, t
             st = doc["stages"]
             total = sum(st[p]["seconds"] for p in PARTS if p in st)
             assert 0.5 * st["volume.read"]["seconds"] <= total <= st["volume.read"]["seconds"]
+        # no peer holds a shard of this volume: the lost shards are looked
+        # up and nobody is asked, so nothing is booked as a peer's read
+        for doc in (plain, recovering, read):
+            assert not {"peer_read", "volume.read.peer"} & set(doc["stages"])
+            assert not {"peer_reads", "peer_fetches_started"} & set(doc["attrs"])
+        assert metrics.ec_peer_reads_total.snapshot() == peer_reads_before
     finally:
         cl.stop()
 
