@@ -28,6 +28,14 @@ The window's rules, the three `fg_*` metrics and the comparison of
 bodies are `http_gets_ycsb`'s and `http_gets`'; the client loop is this
 driver's own, since it draws a server per request. Each client keeps
 one keep-alive connection to EACH live server.
+
+Beside them `correct` rests on `no_peer_read`: the HOLDERS sent bytes
+for shard ranges in the window, whichever plane carried them.
+`peer_bytes_served` = `peer_bytes_served_stream` (the
+`VolumeEcShardRead` streams) + `peer_bytes_served_plane` (the live
+servers' native shard planes, `VolumeServer.net_plane`); `ServedMeter`
+says how each is made up. The READERS' count of the same bytes
+(`peer_reader_bytes`) stands beside the sum.
 """
 
 from __future__ import annotations
@@ -175,6 +183,7 @@ def setup(cell) -> State:
             clients,
         )
         cell.mark("warmed")
+        cl.arm_tracer()
     except BaseException:
         cl.stop()
         raise
@@ -195,11 +204,62 @@ def _reconstructed(st: State) -> int:
 def _python_plane_sent() -> int:
     """Bytes the process has sent on the Python byte plane for reads
     (`sw_net_bytes_sent_total{plane="python",direction="read"}`): the
-    chunks of every `VolumeEcShardRead` stream, all holders, and the
-    HTTP bodies that the pooled front end writes through `wfile`."""
+    chunks of every `VolumeEcShardRead` stream, all holders, the HTTP
+    bodies that the pooled front end writes through `wfile`, and what a
+    shard plane without the native library sends."""
     from seaweedfs_tpu.utils import metrics
 
     return int(metrics.net_bytes_sent_total.snapshot().get(("python", "read"), 0))
+
+
+def _shard_planes_sent(st: State) -> tuple[int, int]:
+    """(native, Python) bytes that the live servers' shard planes
+    (`VolumeServer.net_plane`, `ec/net_plane.ShardNetPlane`) have sent:
+    `sendfile_bytes` and `python_bytes` of each. Shard ranges and
+    nothing else in this cell: no HTTP body passes there, and no
+    gateway asks a plane for a needle. A program or a server without a
+    plane reads 0. NOT `sw_net_bytes_sent_total{plane="native"}`, which
+    also books every large GET body (`utils/http_pool`)."""
+    planes = [getattr(st.cluster.servers[s], "net_plane", None) for s in st.live]
+    return (
+        sum(int(getattr(p, "sendfile_bytes", 0)) for p in planes),
+        sum(int(getattr(p, "python_bytes", 0)) for p in planes),
+    )
+
+
+class ServedMeter:
+    """What the holders sent for shard ranges from its making to
+    `read()`, whichever plane carried them: the `VolumeEcShardRead`
+    streams (the Python plane's read bytes less the window's small HTTP
+    bodies and less the shard planes' own Python egress, which that
+    counter holds too) and the shard planes (native and Python egress
+    together)."""
+
+    def __init__(self, st: State):
+        self.st = st
+        self.python0 = _python_plane_sent()
+        self.planes0 = _shard_planes_sent(st)
+
+    def read(self, http_python_bytes: int) -> dict[str, int]:
+        """`http_python_bytes`: the bodies of the window's GETs that
+        left through `wfile` and so lie in the Python plane's counter."""
+        native, python = _shard_planes_sent(self.st)
+        native, python = native - self.planes0[0], python - self.planes0[1]
+        stream = _python_plane_sent() - self.python0 - http_python_bytes - python
+        return {
+            "peer_bytes_served_stream": stream,
+            "peer_bytes_served_plane": native + python,
+            "peer_bytes_served": stream + native + python,
+        }
+
+
+def served_compared(counters: dict) -> list[Compared]:
+    """The holders' bytes, by plane and summed (shown, held to
+    nothing), and what `correct` rests on: SOME peer served a byte."""
+    return [
+        Compared(name, counters[name], None)
+        for name in ("peer_bytes_served_stream", "peer_bytes_served_plane", "peer_bytes_served")
+    ] + [Compared("no_peer_read", int(counters["peer_bytes_served"] <= 0), 0)]
 
 
 def _peer_reader_bytes() -> int | None:
@@ -230,9 +290,9 @@ def window(cell, st: State, slice_) -> Observed:
     gets: list[tuple[float, float, bool, int]] = []  # (t0, t1, on a lost shard, server)
     hits0, misses0 = _cache_counts(st)
     rec0 = _reconstructed(st)
-    sent0 = _python_plane_sent()
+    served = ServedMeter(st)
     read0 = _peer_reader_bytes()
-    http_python_bytes = [0] * clients  # bodies counted on the peers' plane
+    http_python_bytes = [0] * clients  # bodies counted on the streams' plane
     native_min = _native_body_min()
     t_begin = time.perf_counter()
     deadline = t_begin + cell.seconds
@@ -310,10 +370,8 @@ def window(cell, st: State, slice_) -> Observed:
         gets_on_lost_shard=n_lost,
         # with no interval cache every GET on a lost shard reconstructs
         gets_reconstructing=misses1 - misses0 if has_cache else n_lost,
-        # what the holders' `VolumeEcShardRead` streams sent: the plane's
-        # bytes less the small bodies that left through it
-        peer_bytes_served=_python_plane_sent() - sent0 - sum(http_python_bytes),
         entry_servers_unused=sum(1 for n in by_server.values() if n == 0),
+        **served.read(sum(http_python_bytes)),
     )
     if read0 is not None:
         obs.counters["peer_reader_bytes"] = _peer_reader_bytes() - read0
@@ -334,29 +392,6 @@ def window(cell, st: State, slice_) -> Observed:
     return obs
 
 
-# The readers of what the spread adds to the program's records (layer
-# "peer shard reads"). BENCHMARK.json does not list them: a test of the
-# benchmark's own holds an earlier PR's entries to the END of `per_layer`
-# (tests/test_interp_layers.py), and no PR of this kind may edit it. Until
-# a `benchmark` PR lists them, a traced run prints them on standard error,
-# as `probelib` prints the probes' tables.
-PEER_READERS = (
-    "peer_read_ms_per_get", "peer_reads_per_get", "peer_serve_ms_per_read",
-    "remote_sibling_share", "peer_fetch_unused_share",
-)
-
-
-def peer_readings(obs: Observed, cell) -> dict[str, float]:
-    """What each reader finds in the window's spans; a program that does
-    not record what a reader reads leaves it out."""
-    found = {}
-    for name in PEER_READERS:
-        value = load_module("layers", name).read(obs, cell)
-        if value is not None:
-            found[name] = value
-    return found
-
-
 def verify(cell, st: State, obs: Observed, control: bool = False) -> list[Compared]:
     """`http_gets_ycsb`'s comparison, with caches, reconstructed bytes
     and faults summed over the servers, and what makes the cell the
@@ -364,17 +399,9 @@ def verify(cell, st: State, obs: Observed, control: bool = False) -> list[Compar
     no live server holds a shard beyond its own two."""
     compared = Y.verify(cell, st, obs, control=control)
     placed = cell.config["placement"]["shards_of_server"]
-    if obs.spans:
-        print(
-            "ecbench: peer shard reads: "
-            + " ".join(f"{n}={v:.6g}" for n, v in peer_readings(obs, cell).items()),
-            file=sys.stderr, flush=True,
-        )
     if "peer_reader_bytes" in obs.counters:  # the readers' side of the same bytes
         compared.append(Compared("peer_reader_bytes", obs.counters["peer_reader_bytes"], None))
-    return compared + [
-        Compared("peer_bytes_served", obs.counters["peer_bytes_served"], None),
-        Compared("no_peer_read", int(obs.counters["peer_bytes_served"] <= 0), 0),
+    return compared + served_compared(obs.counters) + [
         Compared("entry_servers_unused", obs.counters["entry_servers_unused"], 0),
         Compared(
             "shards_on_entry_server_only",

@@ -216,6 +216,24 @@ def find_devices(cell: Cell, require_tpu: bool):
     return info
 
 
+def read_layers(cell: Cell, obs: Observed) -> dict:
+    """The `metrics` of a traced run: what the reader file of each
+    per-layer metric that lists the cell finds in the window; a reader
+    that finds nothing leaves its metric out."""
+    from ecbench.cluster import BenchError
+
+    metrics = {}
+    for m in cell.per_layer:
+        value = load_module("layers", m["name"]).read(obs, cell)
+        if value is None:
+            continue
+        if m["name"].endswith("_roofline") and not 0 < value <= 100:
+            # bytes counted too high, or time that leaves out work
+            raise BenchError(f"{m['name']} reads {value} %: malformed")
+        metrics[m["name"]] = {"value": value, "unit": m["unit"]}
+    return metrics
+
+
 def run_cell(
     manifest: dict, workload: str, seed: int, seconds: float, traced: bool,
     require_tpu: bool = True, overrides: dict | None = None,
@@ -306,14 +324,7 @@ def run_cell(
         compared = driver.verify(cell, state, obs, control=control)
         metrics = {}
         if traced:
-            for m in cell.per_layer:
-                value = load_module("layers", m["name"]).read(obs, cell)
-                if value is None:
-                    continue
-                if m["name"].endswith("_roofline") and not 0 < value <= 100:
-                    # bytes counted too high, or time that leaves out work
-                    raise C.BenchError(f"{m['name']} reads {value} %: malformed")
-                metrics[m["name"]] = {"value": value, "unit": m["unit"]}
+            metrics = read_layers(cell, obs)
         else:
             for m in cell.end_to_end:
                 metrics[m["name"]] = {

@@ -52,18 +52,16 @@ class SpreadCluster:
                 max_volume_count=16,
                 ec_backend=config["ec_backend"],
                 ec_interval_cache_mb=config.get("ec_interval_cache_mb"),
-                ec_trace=traced,
             )
             vs.start()
             self.servers.append(vs)
             self.dirs.append(d)
-        # the tracer is process-wide: an untraced run keeps it off, a
-        # traced one keeps every root of the window (a GET of this
-        # cluster leaves its own root and one for every peer's stream)
-        if traced:
-            trace.configure(enabled=True, ring_size=100_000, ring_spans=1_000_000)
-        else:
-            trace.configure(enabled=False)
+        # the tracer is process-wide and stays off through set-up, in a
+        # traced run too: armed, the warm-up's 5,900 GETs take 55 s
+        # longer, and a traced run then ends at the edge of the time a
+        # run may take (`arm_tracer`)
+        self.traced = traced
+        trace.configure(enabled=False)
         trace.reset()
         deadline = time.time() + 30
         while len(self.master.topo.nodes) < servers:
@@ -158,6 +156,15 @@ class SpreadCluster:
                     f"is {want}"
                 )
             time.sleep(0.01)
+
+    def arm_tracer(self) -> None:
+        """The end of a traced run's set-up: from here the program
+        records, and keeps every root of the window (a GET of this
+        cluster leaves its own root and one for every peer's stream)."""
+        from seaweedfs_tpu.utils import trace
+
+        if self.traced:
+            trace.configure(enabled=True, ring_size=100_000, ring_spans=1_000_000)
 
     def stop_server(self, i: int) -> None:
         """A dead host: the server's threads end and its ports close;

@@ -1,12 +1,15 @@
 """The cell `vol1g-10p4-7vs-node-down.ycsb-c-spread`: its configuration
 against the one it shares a volume with, its entries in BENCHMARK.json,
 its entry-server draws, its five readers on kept and hand-worked span
-documents, and the cell rehearsed at 8 MiB on the CPU with its control
-and two planted faults."""
+documents, the holders' bytes counted on either plane, and the cell
+rehearsed at 8 MiB on the CPU with its control and two planted faults."""
 
 import io
 import json
+import os
 import pathlib
+import shutil
+import time
 import types
 
 import numpy as np
@@ -24,12 +27,24 @@ SHARED = (
     "rs_apply_ms_per_get", "sibling_batched_share", "rs_device_ms_per_get",
     "rs_glue_share_of_device", "get_idle_unattributed_share",
 )
-# what the spread adds to the program's records: readers that a traced
-# run of this cell prints on standard error, and BENCHMARK.json does not
-# list (`http_gets_ycsb_spread.PEER_READERS` says why)
-PEERS = (
-    "peer_read_ms_per_get", "peer_reads_per_get", "peer_serve_ms_per_read",
-    "remote_sibling_share", "peer_fetch_unused_share",
+# what the spread adds to the program's records (layer `peer shard
+# reads`, this cell alone), in ISSUE 36's order with what each moves
+PEERS = {
+    "peer_read_ms_per_get": ("ms", "program_span", "fg_p50_ms"),
+    "peer_reads_per_get": ("1", "program_counter", "fg_ops_per_s"),
+    "peer_serve_ms_per_read": ("ms", "program_span", "fg_p50_ms"),
+    "remote_sibling_share": ("%", "program_counter", "fg_p95_ms"),
+    "peer_fetch_unused_share": ("%", "program_counter", "fg_ops_per_s"),
+}
+# the probe metrics of the GET cells and the metrics that were `ycsb-c`'s
+# alone: the cell is on their lists since ISSUE 36
+PROBES = (
+    "interp_wait_ms", "core_wait_ms", "interp_wait_ms_per_get", "interp_returns_per_get",
+    "other_python_cpu_share", "cores_busy",
+)
+YCSB = (
+    "reconstructing_get_share", "healthy_get_p50_ms", "shard_read_ms_per_get",
+    "needle_parse_ms_per_get", "singleflight_wait_share",
 )
 SMALL = {"volume_bytes": 8 << 20, "ec_interval_cache_mb": 1}
 
@@ -89,22 +104,34 @@ def test_the_traffic_is_ycsb_cs_with_an_entry_server_drawn_per_request():
     assert mine["warm_draws"] == 3000 and mine["down_server"] == 1
 
 
-def test_the_cell_is_listed_where_its_readers_find_something_and_nowhere_pinned(manifest, driver):
+def test_the_cell_is_listed_where_its_readers_find_something(manifest):
     listed = {
         m["name"] for m in manifest["end_to_end"] + manifest["per_layer"]
         if CELL in m.get("workloads", ())
     }
-    assert listed == set(SHARED) | {"fg_p50_ms", "fg_p95_ms", "fg_ops_per_s"}
+    assert listed == (
+        set(SHARED) | set(PEERS) | set(PROBES) | set(YCSB)
+        | {"fg_p50_ms", "fg_p95_ms", "fg_ops_per_s"}
+    )
     for m in manifest["per_layer"]:
-        if m["name"] in SHARED:
+        if m["name"] in SHARED + PROBES + YCSB:
             assert m["workloads"][-1] == CELL and CONTROL in m["workloads"]
     assert manifest["configs"][-1]["name"] == CELL.split(".")[0]
     assert manifest["workloads"][-1]["name"] == CELL
-    # the readers of the peers' reads are files that wait for their entries
-    assert driver.PEER_READERS == PEERS
-    assert not set(PEERS) & {m["name"] for m in manifest["per_layer"]}
-    for name in PEERS:
-        assert callable(reader(name))
+
+
+def test_the_five_readers_are_entries_of_a_layer_of_their_own_at_the_end(manifest):
+    entries = manifest["per_layer"][-len(PEERS):]
+    assert [m["name"] for m in entries] == list(PEERS)
+    older = {m["layer"] for m in manifest["per_layer"][: -len(PEERS)]}
+    reported = {e["name"]: harness.metric_cells(e, manifest) for e in manifest["end_to_end"]}
+    for m in entries:
+        unit, source, moves = PEERS[m["name"]]
+        assert set(m) == {"name", "unit", "better", "source", "layer", "moves", "workloads"}
+        assert (m["unit"], m["better"], m["source"], m["moves"]) == (unit, "lower", source, moves)
+        assert m["layer"] == "peer shard reads" and m["layer"] not in older
+        assert m["workloads"] == [CELL] and CELL in reported[moves]
+        assert callable(reader(m["name"]))
 
 
 def test_entry_draws_are_uniform_seeded_and_on_a_stream_of_their_own(driver):
@@ -217,6 +244,37 @@ def test_the_readers_on_documents_kept_from_a_run():
     )
     # both sides saw the same reads and the same bytes
     assert gets[0]["attrs"]["peer_read_bytes"] == served[0]["attrs"]["size"] == 1192
+
+
+def test_the_kept_documents_give_the_same_values_through_metrics(manifest):
+    """What a traced run's result line carries: the harness's walk over
+    the entries that list the cell finds the five readers' files and
+    their hand-worked values, with the entries' units."""
+    docs = json.loads((HERE / "spread.span_docs.json").read_text())
+    obs = harness.Observed()
+    obs.spans = docs
+    obs.counters["compiles_in_window"] = 0
+    metrics = harness.read_layers(harness.resolve_cell(manifest, CELL, 1, 20.0, True), obs)
+    gets = [d for d in docs if d["op"] == "http.volume"]
+    served = [d["duration_s"] for d in docs if d["op"] == "rpc.ec_shard_read"]
+    (recon,) = [c for g in gets for c in g["children"] if c["stages"]]
+    waited = gets[0]["stages"]["peer_read"]["seconds"] + recon["stages"]["peer_read"]["seconds"]
+    by_hand = {
+        "peer_read_ms_per_get": 1e3 * waited / 3, "peer_reads_per_get": 11 / 3,
+        "peer_serve_ms_per_read": 1e3 * sum(served) / 11,
+        "remote_sibling_share": 80.0, "peer_fetch_unused_share": 20.0,
+    }
+    for name, want in by_hand.items():
+        assert metrics[name] == {"value": pytest.approx(want), "unit": PEERS[name][0]}, name
+    # the metrics that were `ycsb-c`'s alone read these documents too:
+    # one GET of three reconstructed, two held an `ec.degraded_read`
+    assert metrics["reconstructing_get_share"]["value"] == pytest.approx(100 / 3)
+    assert metrics["healthy_get_p50_ms"]["value"] == pytest.approx(1e3 * gets[0]["duration_s"])
+    assert metrics["singleflight_wait_share"]["value"] == 0.0
+    # the seam stamps are on the kept spans, twelve returns in three GETs;
+    # no `interp.probe` root was kept: its four readers say nothing
+    assert metrics["interp_returns_per_get"]["value"] == pytest.approx(4.0)
+    assert set(PROBES) & set(metrics) == {"interp_wait_ms_per_get", "interp_returns_per_get"}
     assert recon["attrs"]["peer_fetches_started"] == len(served) - gets[0]["attrs"]["peer_reads"]
     assert "sibling_read" in recon["stages"] and "peer_read" in recon["stages"]
 
@@ -238,34 +296,141 @@ def test_a_traced_rehearsal_is_correct_and_reports_the_shared_and_the_peers_metr
     manifest, capsys
 ):
     result = run(manifest, traced=True, seconds=3.5)
-    (line,) = [ln for ln in capsys.readouterr().err.splitlines()
-               if ln.startswith("ecbench: peer shard reads: ")]
-    peers = {k: float(v) for k, v in (kv.split("=") for kv in line.split(": ")[2].split())}
+    assert "ecbench: peer shard reads" not in capsys.readouterr().err  # once, in `metrics`
     assert result["correct"] is True and result["failed"] == 0
     compared = result["compared"]
     for name in ("no_peer_read", "entry_servers_unused", "shards_on_entry_server_only",
                  "no_healthy_get", "gets_wrong", "gets_failed", "fallback_batches"):
         assert compared[name] == {"value": 0, "limit": 0}, name
-    assert compared["peer_bytes_served"]["value"] > 0
+    served = {
+        name: compared[name]["value"]
+        for name in ("peer_bytes_served", "peer_bytes_served_stream", "peer_bytes_served_plane")
+    }
+    assert all(compared[name]["limit"] is None for name in served)
+    assert served["peer_bytes_served"] > 0
+    assert served["peer_bytes_served"] == (
+        served["peer_bytes_served_stream"] + served["peer_bytes_served_plane"]
+    )
+    # this program's readers know the stream alone
+    assert served["peer_bytes_served_plane"] == 0
     metrics = {k: v["value"] for k, v in result["metrics"].items()}
     assert set(SHARED) - {"rs_device_ms_per_get", "rs_glue_share_of_device",
                           "get_idle_unattributed_share"} <= set(metrics)  # no device trace here
-    assert set(peers) == set(PEERS) and not set(PEERS) & set(metrics)
+    # every newly listed metric comes with a number: none is listed to say nothing
+    for name in tuple(PEERS) + PROBES + YCSB:
+        assert isinstance(metrics[name], float) and metrics[name] >= 0, name
     # two rows of a reconstruction are this server's own, eight its peers'
     assert metrics["sibling_batched_share"] == pytest.approx(20.0, abs=5.0)
-    assert peers["remote_sibling_share"] == pytest.approx(80.0, abs=5.0)
+    assert metrics["remote_sibling_share"] == pytest.approx(80.0, abs=5.0)
     # ten shards have a holder, eight rows are needed
-    assert 0 <= peers["peer_fetch_unused_share"] <= 20.0 + 1e-4
-    assert peers["peer_reads_per_get"] > 0.5 and peers["peer_read_ms_per_get"] > 0
-    assert 0 < peers["peer_serve_ms_per_read"]
+    assert 0 <= metrics["peer_fetch_unused_share"] <= 20.0 + 1e-4
+    assert metrics["peer_reads_per_get"] > 0.5 and metrics["peer_read_ms_per_get"] > 0
+    assert 0 < metrics["peer_serve_ms_per_read"]
     assert metrics["sibling_read_ms_per_get"] > 0  # the rows that lie here
-    # both sides count the same bytes, but for streams that the window's
-    # end found running
+    assert 0 < metrics["reconstructing_get_share"] < 100 and metrics["healthy_get_p50_ms"] > 0
+    assert metrics["cores_busy"] > 0.1
+    # both sides count the same bytes, whatever the plane, but for streams
+    # that the window's end found running
     readers_bytes = compared["peer_reader_bytes"]["value"]
-    assert abs(readers_bytes - compared["peer_bytes_served"]["value"]) <= 0.02 * readers_bytes
+    assert abs(readers_bytes - served["peer_bytes_served"]) <= 0.02 * readers_bytes
     from seaweedfs_tpu.utils import trace
 
     trace.configure(enabled=False)
+
+
+@pytest.fixture
+def small_cluster(manifest, driver):
+    """The cell's own set-up at 8 MiB: seven servers, server 1 stopped,
+    swept and warmed; the driver's state, stopped and removed after."""
+    cell = harness.resolve_cell(manifest, CELL, 2**31 + 36, 1.0, False, overrides=SMALL)
+    cell.started = time.perf_counter()
+    shutil.rmtree(harness.DATA_DIR, ignore_errors=True)
+    os.makedirs(cell.data_dir)
+    st = None
+    try:
+        st = driver.setup(cell)
+        yield cell, st
+    finally:
+        if st is not None:
+            driver.teardown(st)
+        shutil.rmtree(harness.DATA_DIR, ignore_errors=True)
+
+
+def by_name(compared):
+    return {c.name: c for c in compared}
+
+
+def test_a_byte_served_on_the_native_shard_plane_counts_and_an_empty_window_does_not(
+    driver, small_cluster
+):
+    """The case the rehearsal cannot reach while the program's reader
+    knows only the stream: one `NetPlaneClient.read_into` of a live
+    peer's range inside a window of the driver's counting."""
+    from seaweedfs_tpu.ec.net_plane import NetPlaneClient
+    from seaweedfs_tpu.utils import metrics
+
+    _cell, st = small_cluster
+    holder = next(s for s in st.live if st.cluster.servers[s].net_plane is not None)
+    plane = st.cluster.servers[holder].net_plane
+    ev = st.evs[st.live.index(holder)]
+    sid = sorted(ev.shard_ids)[0]
+    size = min(4096 + 123, os.fstat(ev.shard_fds[sid]).st_size - 512)
+    want = os.pread(ev.shard_fds[sid], size, 512)
+    # the warm-up's reconstructions left answers unread: let the holders'
+    # streams run out before the counting opens
+    quiet_since, last = time.monotonic(), driver._python_plane_sent()
+    while time.monotonic() - quiet_since < 0.5:
+        time.sleep(0.05)
+        if driver._python_plane_sent() != last:
+            quiet_since, last = time.monotonic(), driver._python_plane_sent()
+    readers0 = driver._peer_reader_bytes()
+    native0 = metrics.net_bytes_sent_total.snapshot().get(("native", "read"), 0)
+
+    nobody = driver.ServedMeter(st).read(0)
+    assert nobody == {"peer_bytes_served_stream": 0, "peer_bytes_served_plane": 0,
+                      "peer_bytes_served": 0}
+    empty = by_name(driver.served_compared(nobody))
+    assert (empty["no_peer_read"].value, empty["no_peer_read"].limit) == (1, 0)
+    assert not empty["no_peer_read"].ok  # a window in which nobody serves is not correct
+
+    meter = driver.ServedMeter(st)
+    client = NetPlaneClient()
+    try:
+        dst = np.zeros(size, np.uint8)
+        client.read_into((plane.ip, plane.port), st.volume.vid, sid, ev.encode_ts_ns, 512,
+                         size, dst)
+    finally:
+        client.close()
+    assert dst.tobytes() == want  # the holder's bytes, generation-fenced as the stream's
+    counted = meter.read(0)
+    assert counted == {"peer_bytes_served_stream": 0, "peer_bytes_served_plane": size,
+                       "peer_bytes_served": size}
+    compared = by_name(driver.served_compared(counted))
+    assert compared["no_peer_read"].value == 0 and compared["no_peer_read"].ok
+    assert all(compared[n].limit is None and compared[n].value == counted[n] for n in counted)
+    # the program's reader took no part, and the process's counter of the
+    # native plane is not what was read: GET bodies are booked there too
+    assert driver._peer_reader_bytes() == readers0
+    assert metrics.net_bytes_sent_total.snapshot().get(("native", "read"), 0) - native0 in (0, size)
+
+
+def test_a_program_or_a_server_without_a_plane_reads_nought_there(driver):
+    """Three live servers: one with a plane that has sent on both of its
+    egresses, one whose plane's port was taken, one of a program that
+    never had a plane."""
+    plane = types.SimpleNamespace(sendfile_bytes=700, python_bytes=30)
+    servers = [types.SimpleNamespace(net_plane=plane), "stopped",
+               types.SimpleNamespace(net_plane=None), types.SimpleNamespace()]
+    st = types.SimpleNamespace(cluster=types.SimpleNamespace(servers=servers), live=[0, 2, 3])
+    assert driver._shard_planes_sent(st) == (700, 30)
+    meter = driver.ServedMeter(st)
+    plane.sendfile_bytes += 5
+    got = meter.read(0)
+    assert got["peer_bytes_served_plane"] == 5
+    assert got["peer_bytes_served"] == got["peer_bytes_served_stream"] + 5
+    st.live = [2, 3]
+    assert driver._shard_planes_sent(st) == (0, 0)
+    assert driver.ServedMeter(st).read(0)["peer_bytes_served_plane"] == 0
 
 
 def test_the_control_comes_out_as_not_correct(manifest):
