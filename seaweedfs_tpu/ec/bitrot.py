@@ -310,11 +310,20 @@ class BitrotProtection:
         Python loop over granules); -> one verdict per row."""
         gsize, _ = self.verify_granularity(shard_ids[0])
         got = crc32c_granules(rows, gsize)
-        first, n = lo // gsize, got.shape[1]
         return [
-            got[r].tolist() == self.verify_granularity(sid)[1][first : first + n]
+            self.granules_match(sid, lo, got[r])
             for r, sid in enumerate(shard_ids)
         ]
+
+    def granules_match(self, shard_id: int, lo: int, crcs) -> bool:
+        """Whether `crcs` are the sidecar's for the granules of shard
+        `shard_id` from `lo` (granule-aligned) on: the verdict on bytes
+        whose granule CRCs were rolled elsewhere, by `verify_rows` or
+        by a transport that checksums as it lands them
+        (`NetPlaneClient.read_into`)."""
+        gsize, want = self.verify_granularity(shard_id)
+        first = lo // gsize
+        return [int(c) for c in crcs] == want[first : first + len(crcs)]
 
     # ---- file io ----
 

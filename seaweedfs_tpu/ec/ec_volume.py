@@ -16,7 +16,7 @@ import os
 import struct
 import threading
 import time
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import numpy as np
 
@@ -55,6 +55,34 @@ STAGED_RECOVERY_BATCH = 4 << 20
 DEFAULT_INTERVAL_CACHE_BYTES = 16 << 20
 
 
+# Name prefix of the threads that carry a reconstruction's rows from
+# peers (the wait probes sum CPU by class of thread from the name), and
+# how many of them a server keeps: its HTTP workers' number.
+PEER_FETCH_THREAD_PREFIX = "ec-peer-fetch"
+PEER_FETCH_THREADS = 32
+
+
+def peer_fetch_pool():
+    """The pool a reconstruction's fetches from peers run on: made once
+    by whoever serves EC volumes (a `Store`; a bare `EcVolume` makes its
+    own on first need) and kept, since a thread started is a hand-off a
+    GET waits for."""
+    from concurrent.futures import ThreadPoolExecutor
+
+    return ThreadPoolExecutor(
+        max_workers=PEER_FETCH_THREADS,
+        thread_name_prefix=PEER_FETCH_THREAD_PREFIX,
+    )
+
+
+class _PeerAnswer(NamedTuple):
+    """A shard range that a peer answered in full."""
+
+    data: "np.ndarray | bytes"  # where the bytes lie
+    plane: str  # native | stream: what carried them
+    crcs: "np.ndarray | None"  # granule CRCs rolled while they landed
+
+
 class EcNotFoundError(ECError):
     pass
 
@@ -74,6 +102,7 @@ class EcVolume:
         interval_cache_bytes: int = DEFAULT_INTERVAL_CACHE_BYTES,
         interval_cache: ChunkCache | None = None,
         scheduler=None,
+        fetch_pool=None,
     ):
         """remote_reader(shard_id, offset, size, generation) -> bytes|None
         lets the cluster layer serve shards held by peer servers
@@ -81,7 +110,10 @@ class EcVolume:
         generation is the EncodeTsNs fence so a stale peer never answers);
         recovery by local reconstruction remains the fallback. A reader
         with a `peers(shard_id)` method (the volume server's) is asked
-        only for shards that some peer holds.
+        only for shards that some peer holds; one with a `read_into(
+        shard_id, offset, size, generation, dst, granule) -> (plane,
+        crcs)|None` method (the volume server's) lands a range in the
+        buffer where it is used (`_read_from_peer`).
 
         `interval_cache_bytes` bounds the LRU of verified reconstructed
         extents (0 disables): repeated reads of needles on a missing
@@ -99,7 +131,9 @@ class EcVolume:
 
         `scheduler` (Store wiring) is the QueueScope whose placement/
         admission config wide degraded reconstructions run under (None
-        = the process-wide default scope)."""
+        = the process-wide default scope). `fetch_pool` (Store wiring)
+        is the executor a reconstruction's fetches from peers run on
+        (None = one of this volume's own, made on first need)."""
         from ..storage.volume import Volume
 
         self.volume_id = volume_id
@@ -154,6 +188,8 @@ class EcVolume:
         )
         self.remote_reader = remote_reader
         self.scheduler = scheduler
+        self._fetch_pool = fetch_pool
+        self._owns_fetch_pool = False
         # Bitrot sidecar, loaded lazily for degraded-read verification.
         # False = not loaded yet (absence is re-probed per degraded
         # read; only a successful load is cached).
@@ -296,7 +332,9 @@ class EcVolume:
         trace.lap("parse")
         return b"".join(parts)
 
-    def _read_shard_interval(self, shard_id: int, offset: int, size: int) -> bytes:
+    def _read_shard_interval(
+        self, shard_id: int, offset: int, size: int
+    ) -> "bytes | np.ndarray":
         trace.lap("shard")
         fd = self.shard_fds.get(shard_id)
         if fd is not None:
@@ -324,9 +362,12 @@ class EcVolume:
             if sp is not None:
                 sp.count("peer_reads", 1)
                 sp.count("peer_read_bytes", 0 if got is None else size)
+                if got is not None and got.plane == "native":
+                    sp.count("peer_reads_native", 1)
             if got is not None:
                 self.bytes_read += size
-                return got
+                # a buffer of this read's own, which the join takes as it is
+                return got.data
         return self._recover_interval(shard_id, offset, size)
 
     def _peer_lists(self, shard_id: int) -> bool:
@@ -338,19 +379,56 @@ class EcVolume:
         return peers is None or bool(peers(shard_id))
 
     def _read_from_peer(
-        self, kind: str, shard_id: int, offset: int, size: int
-    ) -> Optional[bytes]:
+        self, kind: str, shard_id: int, offset: int, size: int,
+        dst: Optional[np.ndarray] = None, granule: int = 0,
+    ) -> Optional[_PeerAnswer]:
         """[offset, offset+size) of a shard from the peers that hold it,
         or None where none answered in full; counted at this, the
-        reader's, side as a read of `kind` (interval | sibling)."""
+        reader's, side as a read of `kind` (interval | sibling) on the
+        plane that carried the answer (native | stream; a read that
+        nobody answered counts under `stream`, the transport asked
+        last).
+
+        A reader that offers `read_into` lands the range in `dst` (1-D
+        uint8; a fresh buffer where the caller has none) and says which
+        plane carried it; with `granule` it hands back the granule
+        CRCs that were rolled while the bytes landed, where the plane
+        rolls them (None over the stream). A bare callable returns
+        `bytes`, which are copied into a `dst` that was given."""
         t0 = time.perf_counter()
-        got = self.remote_reader(shard_id, offset, size, self.encode_ts_ns)
-        M.ec_peer_reads_total.inc(kind=kind)
-        M.ec_peer_read_seconds_total.inc(time.perf_counter() - t0, kind=kind)
-        if got is None or len(got) != size:
+        data, plane, crcs = None, "stream", None
+        read_into = getattr(self.remote_reader, "read_into", None)
+        if read_into is not None:
+            if dst is None:
+                dst = np.empty(size, dtype=np.uint8)
+            got = read_into(
+                shard_id, offset, size, self.encode_ts_ns, dst, granule
+            )
+            if got is not None:
+                data, (plane, crcs) = dst, got
+        else:
+            got = self.remote_reader(shard_id, offset, size, self.encode_ts_ns)
+            if got is not None and len(got) == size:
+                data = got
+                if dst is not None:
+                    dst[:] = np.frombuffer(got, dtype=np.uint8)
+                    data = dst
+        M.ec_peer_reads_total.inc(kind=kind, plane=plane)
+        M.ec_peer_read_seconds_total.inc(
+            time.perf_counter() - t0, kind=kind, plane=plane
+        )
+        if data is None:
             return None
-        M.ec_peer_read_bytes_total.inc(size, kind=kind)
-        return got
+        M.ec_peer_read_bytes_total.inc(size, kind=kind, plane=plane)
+        return _PeerAnswer(data, plane, crcs)
+
+    def _peer_fetch_pool(self):
+        if self._fetch_pool is None:
+            with self._lock:
+                if self._fetch_pool is None:
+                    self._fetch_pool = peer_fetch_pool()
+                    self._owns_fetch_pool = True
+        return self._fetch_pool
 
     # ---------------------------------------------------------- recovery
 
@@ -536,60 +614,136 @@ class EcVolume:
                 if i != shard_id and i not in ids and self._peer_lists(i)
             ]
         if missing:
-            import contextvars
-            from concurrent.futures import FIRST_COMPLETED, ThreadPoolExecutor, wait
-
-            def fetch(i):
-                return i, self._read_from_peer("sibling", i, offset, size)
-
-            def submit(ex, i):
-                # Per-task contextvar copy: the fetch thread sees the
-                # caller's request id + active span, so the peer
-                # shard-read RPC hop carries both in its metadata.
-                return ex.submit(contextvars.copy_context().run, fetch, i)
-
-            # stop as soon as k rows are filled: one hung peer must not
-            # stall the read for the full RPC timeout
-            ex = ThreadPoolExecutor(max_workers=min(len(missing), 8))
-            asked: list = []
-            looked_at = 0
-            try:
-                # "peer_read" covers only the blocked wait on peer
-                # fetches; admit() tags its own time "crc_verify".
-                with trace.stage(sp, "peer_read"):
-                    asked = [submit(ex, i) for i in missing]
-                futures = set(asked)
-                while futures and None in ids:
-                    with trace.stage(sp, "peer_read"):
-                        done, futures = wait(
-                            futures, return_when=FIRST_COMPLETED
-                        )
-                    for f in done:
-                        if None not in ids:
-                            break  # full: what is left over is unused
-                        looked_at += 1
-                        i, got = f.result()
-                        if got is None:
-                            continue
-                        row = ids.index(None)
-                        matrix[row] = np.frombuffer(got, dtype=np.uint8)
-                        admit(row, [i], "sibling_rows_single")
-                        if sp is not None and ids[row] == i:
-                            sp.count("sibling_rows_remote", 1)
-            finally:
-                # fetches still waiting for a thread are cancelled; those
-                # that run finish unread, and their peers' work is wasted
-                ex.shutdown(wait=False, cancel_futures=True)
-                if sp is not None:
-                    started = sum(1 for f in asked if not f.cancelled())
-                    sp.count("peer_fetches_started", started)
-                    sp.count("peer_fetches_unused", started - looked_at)
+            matrix = self._fill_from_peers(
+                matrix, ids, missing, offset, size, prot, sp
+            )
         if None in ids:
             raise ECError(
                 f"shard {shard_id} unavailable and only "
                 f"{k - ids.count(None)} sibling shards readable (need {k})"
             )
         return matrix, tuple(ids)
+
+    def _fill_from_peers(
+        self, matrix: np.ndarray, ids: list, missing: list[int],
+        offset: int, size: int, prot, sp,
+    ) -> np.ndarray:
+        """The open rows of a sibling matrix (`ids[r] is None`) from the
+        peers that hold the shards `missing`, every one asked at once and
+        every answer landed where it is to be used: the first fetches in
+        the open rows themselves, those beyond in spare rows. Returns the
+        matrix: the same one where each open row's own fetch filled it;
+        else a gathered copy, with spares in the place of rows whose fetch
+        failed, came rotten or is still running (a running fetch owns its
+        row, so nothing else is written there). `ids` is filled as far as
+        good rows came.
+
+        No row enters `ids` unchecked against the sidecar (`prot`; None =
+        no ground truth): the granule CRCs that the native shard plane
+        rolled while a row landed are compared as it arrives; rows that
+        came over a peer's stream wait until enough rows are there and are
+        checked under ONE `crc_verify` stage."""
+        import contextvars
+        from concurrent.futures import FIRST_COMPLETED, wait
+
+        open_rows = [r for r, sid in enumerate(ids) if sid is None]
+        need = len(open_rows)
+        spare = np.empty((max(0, len(missing) - need), size), dtype=np.uint8)
+        dsts = [matrix[r] for r in open_rows] + list(spare)
+        granule = prot.verify_granularity(missing[0])[0] if prot is not None else 0
+        n_crcs = -(-size // granule) if granule else 0
+
+        def fetch(j):
+            return j, self._read_from_peer(
+                "sibling", missing[j], offset, size, dsts[j], granule
+            )
+
+        pool = self._peer_fetch_pool()
+        asked: list = []
+        ready: list = []  # answers that came and were not looked at yet
+        good: list[int] = []  # fetches whose row is checked, as they came
+        unchecked: list[int] = []  # landed over a stream: no CRCs with them
+        looked_at = landed = native = 0
+        try:
+            # "peer_read" covers only the blocked wait on peer fetches.
+            # Per-task contextvar copy: the fetch thread sees the caller's
+            # request id + active span, so the peer's span joins this
+            # read's trace whichever plane carries the bytes.
+            with trace.stage(sp, "peer_read"):
+                asked = [
+                    pool.submit(contextvars.copy_context().run, fetch, j)
+                    for j in range(len(missing))
+                ]
+            futures = set(asked)
+            # stop as soon as the rows are filled: one hung peer must not
+            # stall the read for the full time-out
+            while len(good) < need:
+                if len(good) + len(unchecked) < need and (ready or futures):
+                    if not ready:
+                        with trace.stage(sp, "peer_read"):
+                            done, futures = wait(
+                                futures, return_when=FIRST_COMPLETED
+                            )
+                        ready.extend(done)
+                    looked_at += 1
+                    j, got = ready.pop().result()
+                    if got is None:
+                        continue
+                    landed += 1
+                    native += got.plane == "native"
+                    if prot is None:
+                        good.append(j)
+                    elif got.crcs is None:
+                        unchecked.append(j)
+                    elif len(got.crcs) == n_crcs and prot.granules_match(
+                        missing[j], offset, got.crcs
+                    ):
+                        good.append(j)
+                    continue
+                if not unchecked:
+                    break  # every answer is in, and they are too few
+                with trace.stage(sp, "crc_verify"):
+                    for j in unchecked:
+                        (ok,) = prot.verify_rows(
+                            [missing[j]], offset, dsts[j][None, :]
+                        )
+                        if ok:
+                            good.append(j)
+                unchecked = []
+        finally:
+            # fetches still waiting for a thread are cancelled; those
+            # that run finish unread, and their peers' work is wasted
+            for f in asked:
+                f.cancel()
+            self.bytes_read += landed * size
+            if sp is not None:
+                started = sum(1 for f in asked if not f.cancelled())
+                sp.count("peer_fetches_started", started)
+                sp.count("peer_fetches_unused", started - looked_at)
+                if native:
+                    # checked as they landed, with no call of their own
+                    sp.count("sibling_rows_batched", native)
+                    sp.count("peer_reads_native", native)
+                if landed > native:
+                    sp.count("sibling_rows_single", landed - native)
+        # rows that lie where they are used before spares
+        used = sorted(good, key=lambda j: j >= need)[:need]
+        for j in used:
+            if j < need:
+                ids[open_rows[j]] = missing[j]
+        moved = dict(zip(
+            (r for r in open_rows if ids[r] is None),
+            (j for j in used if j >= need),
+        ))
+        if moved:
+            for r, j in moved.items():
+                ids[r] = missing[j]
+            matrix = np.stack(
+                [dsts[moved[r]] if r in moved else matrix[r] for r in range(len(ids))]
+            )
+        if sp is not None and used:
+            sp.count("sibling_rows_remote", len(used))
+        return matrix
 
     def _decode_row(self, shard_id: int, src_ids: tuple[int, ...]) -> np.ndarray:
         """(1, k) coefficients taking shards `src_ids`, in that order,
@@ -830,6 +984,9 @@ class EcVolume:
     def close(self) -> None:
         with self._lock:
             self._save_heat()
+            if self._owns_fetch_pool:
+                self._fetch_pool.shutdown(wait=False, cancel_futures=True)
+                self._fetch_pool, self._owns_fetch_pool = None, False
             for fd in self.shard_fds.values():
                 os.close(fd)
             self.shard_fds.clear()

@@ -102,6 +102,9 @@ _RESP = struct.Struct("<BQ")      # status, n
 _NEEDLE_CRC = struct.Struct("<I")  # appended to an OK needle response
 NET_PLANE_PORT_OFFSET = 10000     # net plane port = grpc port + this
 
+# name prefix of a plane's connection threads (the port follows)
+CONN_THREAD_PREFIX = "shard-net-conn-"
+
 _SEND_CHUNK = 1 << 20             # python-plane egress chunking
 _MAX_REQUEST = 1 << 32
 _MAX_META = 4096
@@ -245,6 +248,13 @@ def egress_native() -> bool:
     return native_io.enabled() and not faults.active()
 
 
+def _close(sock: socket.socket) -> None:
+    try:
+        sock.close()
+    except OSError:
+        pass
+
+
 def _recv_exact(sock: socket.socket, n: int) -> bytes:
     buf = bytearray(n)
     view = memoryview(buf)
@@ -322,6 +332,10 @@ class ShardNetPlane:
         self._conns_lock = threading.Lock()
         self.requests = 0
         self.needle_requests = 0
+        # what left through either egress, summed under a lock: many
+        # connection threads add to them, and the readers' counters are
+        # held against them to the byte
+        self._sent_lock = threading.Lock()
         self.sendfile_bytes = 0
         self.python_bytes = 0
         self.write_requests = 0
@@ -347,10 +361,16 @@ class ShardNetPlane:
         with self._conns_lock:
             conns = list(self._conns)
         for c in conns:
+            # as with the listener: close() alone neither wakes the
+            # connection's thread out of its recv nor tells the peer, so
+            # a reader that has the connection parked in its pool would
+            # send its next request into a socket that nobody serves and
+            # wait out its whole time-out
             try:
-                c.close()
+                c.shutdown(socket.SHUT_RDWR)
             except OSError:
                 pass
+            _close(c)
         self._thread.join(timeout=2.0)
 
     # ------------------------------------------------------------ serving
@@ -363,8 +383,11 @@ class ShardNetPlane:
                 return  # listener closed
             with self._conns_lock:
                 self._conns.add(conn)
+            # named: the wait probes sum CPU by class of thread from the
+            # name (utils/interp_probe.py)
             threading.Thread(
-                target=self._serve_conn, args=(conn,), daemon=True
+                target=self._serve_conn, args=(conn,), daemon=True,
+                name=f"{CONN_THREAD_PREFIX}{self.port}",
             ).start()
 
     def _serve_conn(self, conn: socket.socket) -> None:
@@ -449,10 +472,16 @@ class ShardNetPlane:
         finally:
             with self._conns_lock:
                 self._conns.discard(conn)
-            try:
-                conn.close()
-            except OSError:
-                pass
+            _close(conn)
+
+    def _sent(self, egress: str, n: int) -> None:
+        """`n` bytes left through `egress` (native | python)."""
+        with self._sent_lock:
+            if egress == "native":
+                self.sendfile_bytes += n
+            else:
+                self.python_bytes += n
+        M.net_bytes_sent_total.inc(n, plane=egress, direction="read")
 
     def _error(self, conn, msg: str, status: int = 1) -> bool:
         body = msg.encode(errors="replace")
@@ -492,8 +521,7 @@ class ShardNetPlane:
                 )
             except OSError:
                 return False  # peer died mid-splice: header already out
-            self.sendfile_bytes += sent
-            M.net_bytes_sent_total.inc(sent, plane="native", direction="read")
+            self._sent("native", sent)
             return sent == n
         # Python egress (fallback plane / armed registry): pread ->
         # mutate -> sendall, byte-identical to the gRPC stream's
@@ -514,8 +542,7 @@ class ShardNetPlane:
                     conn.sendall(chunk)
             except OSError:
                 return False
-            self.python_bytes += len(chunk)
-            M.net_bytes_sent_total.inc(len(chunk), plane="python", direction="read")
+            self._sent("python", len(chunk))
             if len(chunk) < orig:
                 return False  # torn stream: connection is dead
             o += orig
@@ -562,8 +589,7 @@ class ShardNetPlane:
                     )
                 except OSError:
                     return False
-                self.sendfile_bytes += sent
-                M.net_bytes_sent_total.inc(sent, plane="native", direction="read")
+                self._sent("native", sent)
                 return sent == size
             # Python egress (no .so): pread -> sendall, the same bytes.
             remaining, o = size, off
@@ -576,8 +602,7 @@ class ShardNetPlane:
                     conn.sendall(chunk)
                 except OSError:
                     return False
-                self.python_bytes += len(chunk)
-                M.net_bytes_sent_total.inc(len(chunk), plane="python", direction="read")
+                self._sent("python", len(chunk))
                 o += len(chunk)
                 remaining -= len(chunk)
             return True
@@ -871,16 +896,20 @@ class NetPlaneClient:
     payload bytes straight in caller buffers (``sn_recv_into``) with the
     fused granule CRC rolled during the copy-in.
 
-    One cached connection per peer address (requests on one address are
-    serialized — peer-fetch streams one shard from a given holder at a
-    time, so the lock is uncontended on the rebuild path). A peer whose
-    plane port refuses the connect is memoized and later calls raise
-    :class:`NetPlaneUnavailable` immediately — but only for
-    ``unavailable_ttl`` seconds (``SEAWEED_EC_NET_PLANE_RETRY_S``,
-    default 30): a sidecar that comes up later (rolling restart, late
-    boot) is re-probed and re-adopted instead of being written off for
-    the life of the process. :meth:`reset` drops the memo immediately
-    (operator hook — e.g. right after healing a peer).
+    One connection per IN-FLIGHT request, whatever the opcode: a call
+    checks a connection to its address out of the pool (dialling one
+    where the pool is empty) and back in once the response has been read
+    to its end, so the GETs of many HTTP workers that read ranges from
+    one holder run side by side and none waits for another's socket. A
+    connection whose stream was left torn, short or out of sync is
+    closed, never parked. A peer whose plane port refuses the connect
+    is memoized and later calls raise :class:`NetPlaneUnavailable`
+    immediately — but only for ``unavailable_ttl`` seconds
+    (``SEAWEED_EC_NET_PLANE_RETRY_S``, default 30): a sidecar that comes
+    up later (rolling restart, late boot) is re-probed and re-adopted
+    instead of being written off for the life of the process.
+    :meth:`reset` drops the memo immediately (operator hook — e.g.
+    right after healing a peer).
     """
 
     def __init__(self, timeout: float = 30.0, connect_timeout: float = 2.0,
@@ -895,17 +924,12 @@ class NetPlaneClient:
             except ValueError:
                 unavailable_ttl = 30.0
         self.unavailable_ttl = unavailable_ttl
-        self._conns: dict[tuple[str, int], socket.socket] = {}
-        self._locks: dict[tuple[str, int], threading.Lock] = {}
-        # needle-read connection pool: warm GETs arrive from N HTTP
-        # workers concurrently, so chunk fetches check OUT a connection
-        # per request (creating one on empty) instead of serializing on
-        # the shard paths' one-conn-per-addr lock. Entries are
-        # (socket, checkin-time): the server reaps idle connections at
-        # its request_timeout (60 s), so anything parked longer than
+        # the connection pool, per address. Entries are (socket,
+        # checkin-time): the server reaps idle connections at its
+        # request_timeout (60 s), so anything parked longer than
         # _npool_idle_s is discarded at checkout instead of burning a
         # request on a dead socket (which would silently demote that
-        # GET to the HTTP path).
+        # read to its fallback transport).
         self._npool: dict[
             tuple[str, int], list[tuple[socket.socket, float]]
         ] = {}
@@ -917,16 +941,10 @@ class NetPlaneClient:
 
     def close(self) -> None:
         with self._lock:
-            conns = list(self._conns.values())
-            self._conns.clear()
-            for lst in self._npool.values():
-                conns.extend(s for s, _t in lst)
+            conns = [s for lst in self._npool.values() for s, _t in lst]
             self._npool.clear()
         for c in conns:
-            try:
-                c.close()
-            except OSError:
-                pass
+            _close(c)
 
     def reset(self, addr: tuple[str, int] | None = None) -> None:
         """Forget the no-plane memo for `addr` (or every peer): the
@@ -937,10 +955,6 @@ class NetPlaneClient:
                 self._no_plane.clear()
             else:
                 self._no_plane.pop(addr, None)
-
-    def _addr_lock(self, addr) -> threading.Lock:
-        with self._lock:
-            return self._locks.setdefault(addr, threading.Lock())
 
     def _check_memo(self, addr) -> None:
         """Raise if `addr` is inside its no-plane TTL; forget an
@@ -965,24 +979,13 @@ class NetPlaneClient:
         s.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
         return s
 
-    def _conn(self, addr) -> socket.socket:
-        with self._lock:
-            self._check_memo(addr)
-            s = self._conns.get(addr)
-        if s is not None:
-            return s
-        s = self._connect(addr)
-        with self._lock:
-            self._conns[addr] = s
-        return s
-
     def _checkout(self, addr) -> socket.socket:
-        """Take a pooled needle-read connection (or dial a new one):
-        one connection per IN-FLIGHT request, so concurrent warm GETs
-        fan out instead of serializing on one socket. Connections
-        parked longer than `_npool_idle_s` are discarded — the server
-        side reaps idle peers, and a dead pooled socket would cost the
-        next GET its fast path."""
+        """Take a pooled connection (or dial a new one): one connection
+        per IN-FLIGHT request, so concurrent reads fan out instead of
+        serializing on one socket. Connections parked longer than
+        `_npool_idle_s` are discarded — the server side reaps idle
+        peers, and a dead pooled socket would cost the next read its
+        fast path."""
         stale: list[socket.socket] = []
         fresh = None
         with self._lock:
@@ -996,10 +999,7 @@ class NetPlaneClient:
                     break
                 stale.append(s)
         for s in stale:
-            try:
-                s.close()
-            except OSError:
-                pass
+            _close(s)
         if fresh is not None:
             return fresh
         return self._connect(addr)
@@ -1019,64 +1019,59 @@ class NetPlaneClient:
                 lst.append((s, now))
                 s = None  # type: ignore[assignment]
         for dead in expired:
-            try:
-                dead.close()
-            except OSError:
-                pass
+            _close(dead)
         if s is not None:
-            try:
-                s.close()
-            except OSError:
-                pass
+            _close(s)
 
-    def _drop(self, addr) -> None:
-        with self._lock:
-            s = self._conns.pop(addr, None)
-        if s is not None:
-            try:
-                s.close()
-            except OSError:
-                pass
+    def _release(self, addr, s: socket.socket, healthy: bool) -> None:
+        """Back to the pool where the response was read to its end (a
+        refusal's too: it leaves the stream in sync); closed where not."""
+        if healthy:
+            self._checkin(addr, s)
+        else:
+            _close(s)
 
     def _request(
         self, addr, vid, sid, gen, off, size, exact: bool = True
     ) -> tuple[socket.socket, int]:
-        """Send one range request, parse the response header, return
-        (connection positioned at the payload, payload length). With
-        `exact` (the default) a server-side EOF clamp raises — range
-        callers sized their landing buffer; `exact=False` accepts the
-        clamp (whole-shard fetches discover the size this way)."""
-        s = self._conn(addr)
-        meta = _encode_meta()
+        """Send one range request on a connection checked out for it,
+        parse the response header, return (connection positioned at the
+        payload, payload length): the caller reads the payload and
+        releases the connection. Whatever raises here has released it.
+        With `exact` (the default) a server-side EOF clamp raises —
+        range callers sized their landing buffer; `exact=False` accepts
+        the clamp (whole-shard fetches discover the size this way)."""
+        s = self._checkout(addr)
+        healthy = False
         try:
-            s.sendall(
-                _REQ.pack(MAGIC, vid, sid, gen, off, size, len(meta)) + meta
-            )
-            head = _recv_exact(s, _RESP.size)
-        except (OSError, NetPlaneError) as e:
-            self._drop(addr)
-            raise NetPlaneError(f"{addr}: {e}") from e
-        status, n = _RESP.unpack(head)
-        if status != 0:
+            meta = _encode_meta()
             try:
+                s.sendall(
+                    _REQ.pack(MAGIC, vid, sid, gen, off, size, len(meta))
+                    + meta
+                )
+                head = _recv_exact(s, _RESP.size)
+            except (OSError, NetPlaneError) as e:
+                raise NetPlaneError(f"{addr}: {e}") from e
+            status, n = _RESP.unpack(head)
+            if status != 0:
                 msg = self._read_refusal(addr, s, n)
-            except NetPlaneError:
-                self._drop(addr)
-                raise
-            raise NetPlaneError(f"{addr}: {msg}")
-        if n > size:
-            # the server only ever clamps DOWN (n = min(size, fsize));
-            # a longer claim is a desynced or hostile peer — honoring
-            # it would stream garbage past the caller's sizing
-            self._drop(addr)
-            raise NetPlaneError(f"{addr}: oversized frame {n}/{size}")
-        if exact and n != size:
-            # EOF clamp — the gRPC stream's short read. The connection
-            # still holds n payload bytes; cheaper to drop it than to
-            # drain and resync.
-            self._drop(addr)
-            raise NetPlaneError(f"{addr}: short stream {n}/{size}")
-        return s, n
+                healthy = True  # refusal leaves the stream in sync
+                raise NetPlaneError(f"{addr}: {msg}")
+            if n > size:
+                # the server only ever clamps DOWN (n = min(size, fsize));
+                # a longer claim is a desynced or hostile peer — honoring
+                # it would stream garbage past the caller's sizing
+                raise NetPlaneError(f"{addr}: oversized frame {n}/{size}")
+            if exact and n != size:
+                # EOF clamp — the gRPC stream's short read. The connection
+                # still holds n payload bytes; cheaper to drop it than to
+                # drain and resync.
+                raise NetPlaneError(f"{addr}: short stream {n}/{size}")
+            return s, n
+        except BaseException:
+            self._release(addr, s, healthy)
+            raise
 
     def read_into(
         self,
@@ -1091,72 +1086,71 @@ class NetPlaneClient:
         granule: int = 0,
     ) -> np.ndarray | None:
         """Land `size` bytes of shard `sid` @`off` DIRECTLY in `dst`
-        (1-D C-contiguous uint8 view of a pooled aligned buffer). With
-        granule > 0 returns the granule CRCs rolled during the copy-in
-        (completed granules plus the partial tail) as a u32 ndarray —
-        the caller compares them against the .ecsum sidecar with no
-        extra pass over the bytes."""
+        (1-D C-contiguous uint8: a pooled aligned buffer, a row of a
+        reconstruction's matrix). With granule > 0 returns the granule
+        CRCs rolled during the copy-in (completed granules plus the
+        partial tail) as a u32 ndarray — the caller compares them
+        against the .ecsum sidecar with no extra pass over the bytes.
+        Calls to one address run side by side, each on a connection of
+        its own."""
         native = _native_mod()
-        with self._addr_lock(addr):
-            return self._read_into_locked(
-                addr, vid, sid, gen, off, size, dst,
-                granule=granule, native=native,
-            )
-
-    def _read_into_locked(
-        self, addr, vid, sid, gen, off, size, dst, *, granule, native
-    ):
         s, _n = self._request(addr, vid, sid, gen, off, size)
+        healthy = False
         try:
-            if native is not None:
-                crc_state = np.zeros(1, np.uint32)
-                filled = np.zeros(1, np.uint64)
-                max_out = (size // granule + 2) if granule else 1
-                out_crcs = np.zeros(max_out, np.uint32)
-                out_counts = np.zeros(1, np.int32)
-                got = native.recv_into(
-                    s.fileno(), dst, size,
-                    timeout_ms=int(self.timeout * 1000),
-                    granule=granule, crc_state=crc_state,
-                    filled_state=filled, out_crcs=out_crcs,
-                    out_counts=out_counts,
-                )
-                if got != size:
-                    raise NetPlaneError(
-                        f"{addr}: torn stream {got}/{size}"
-                    )
-                M.net_bytes_received_total.inc(got, plane="native", direction="read")
-                if not granule:
-                    return None
-                crcs = list(out_crcs[: int(out_counts[0])])
-                if size % granule:
-                    crcs.append(int(crc_state[0]))
-                return np.asarray(crcs, dtype=np.uint32)
-            # Python landing (no .so): same buffer, Python recv loop.
-            view = memoryview(dst)[:size]
-            got = 0
-            while got < size:
-                r = s.recv_into(view[got:], size - got)
-                if r == 0:
-                    raise NetPlaneError(f"{addr}: torn stream {got}/{size}")
-                got += r
-            M.net_bytes_received_total.inc(got, plane="python", direction="read")
+            crcs = self._land(addr, s, size, dst, granule, native)
+            healthy = True
+            return crcs
+        except OSError as e:
+            raise NetPlaneError(f"{addr}: {e}") from e
+        finally:
+            self._release(addr, s, healthy)
+
+    def _land(self, addr, s, size, dst, granule, native):
+        """`size` payload bytes of the response `s` stands at, into
+        `dst`; -> the granule CRCs rolled on the way (None without
+        `granule`). Raises on a torn stream."""
+        if native is not None:
+            crc_state = np.zeros(1, np.uint32)
+            filled = np.zeros(1, np.uint64)
+            max_out = (size // granule + 2) if granule else 1
+            out_crcs = np.zeros(max_out, np.uint32)
+            out_counts = np.zeros(1, np.int32)
+            got = native.recv_into(
+                s.fileno(), dst, size,
+                timeout_ms=int(self.timeout * 1000),
+                granule=granule, crc_state=crc_state,
+                filled_state=filled, out_crcs=out_crcs,
+                out_counts=out_counts,
+            )
+            if got != size:
+                raise NetPlaneError(f"{addr}: torn stream {got}/{size}")
+            M.net_bytes_received_total.inc(got, plane="native", direction="read")
             if not granule:
                 return None
-            from ..utils.crc import crc32c as _crc
+            crcs = list(out_crcs[: int(out_counts[0])])
+            if size % granule:
+                crcs.append(int(crc_state[0]))
+            return np.asarray(crcs, dtype=np.uint32)
+        # Python landing (no .so): same buffer, Python recv loop.
+        view = memoryview(dst)[:size]
+        got = 0
+        while got < size:
+            r = s.recv_into(view[got:], size - got)
+            if r == 0:
+                raise NetPlaneError(f"{addr}: torn stream {got}/{size}")
+            got += r
+        M.net_bytes_received_total.inc(got, plane="python", direction="read")
+        if not granule:
+            return None
+        from ..utils.crc import crc32c as _crc
 
-            return np.array(
-                [
-                    _crc(dst[i : min(i + granule, size)])
-                    for i in range(0, size, granule)
-                ],
-                dtype=np.uint32,
-            )
-        except (OSError, NetPlaneError) as e:
-            self._drop(addr)
-            if isinstance(e, NetPlaneError):
-                raise
-            raise NetPlaneError(f"{addr}: {e}") from e
+        return np.array(
+            [
+                _crc(dst[i : min(i + granule, size)])
+                for i in range(0, size, granule)
+            ],
+            dtype=np.uint32,
+        )
 
     def read_bytes(
         self, addr, vid, sid, gen, off, size
@@ -1167,13 +1161,15 @@ class NetPlaneClient:
         tests/test_native_net_plane.py does, for its same-wire
         Python-plane comparison and the generation fence (ROADMAP
         Design 4)."""
-        with self._addr_lock(addr):
-            s, _n = self._request(addr, vid, sid, gen, off, size)
-            try:
-                data = _recv_exact(s, size)
-            except (OSError, NetPlaneError) as e:
-                self._drop(addr)
-                raise NetPlaneError(f"{addr}: {e}") from e
+        s, _n = self._request(addr, vid, sid, gen, off, size)
+        healthy = False
+        try:
+            data = _recv_exact(s, size)
+            healthy = True
+        except (OSError, NetPlaneError) as e:
+            raise NetPlaneError(f"{addr}: {e}") from e
+        finally:
+            self._release(addr, s, healthy)
         M.net_bytes_received_total.inc(size, plane="python", direction="read")
         M.net_bytes_copied_total.inc(size, plane="python", direction="read")
         return data
@@ -1196,62 +1192,35 @@ class NetPlaneClient:
         from . import native_io
 
         native = _native_mod() if native_io.enabled() else None
-        plane = "native" if native is not None else "python"
         pool = native_io.landing_pool()
         buf = pool.get(chunk)
         row = buf[0]
         total = 0
+        s = None
+        healthy = False
         try:
-            with self._addr_lock(addr):
-                # one request for the whole file: ask for the 4 GiB
-                # protocol max and let the server clamp to the size
-                s, n = self._request(
-                    addr, vid, sid, gen, 0, _MAX_REQUEST, exact=False
-                )
+            # one request for the whole file: ask for the 4 GiB
+            # protocol max and let the server clamp to the size
+            s, n = self._request(
+                addr, vid, sid, gen, 0, _MAX_REQUEST, exact=False
+            )
+            while total < n:
+                want = min(chunk, n - total)
                 try:
-                    remaining = n
-                    while remaining > 0:
-                        want = min(chunk, remaining)
-                        if native is not None:
-                            got = native.recv_into(
-                                s.fileno(), row, want,
-                                timeout_ms=int(self.timeout * 1000),
-                                granule=0,
-                                crc_state=np.zeros(1, np.uint32),
-                                filled_state=np.zeros(1, np.uint64),
-                                out_crcs=np.zeros(1, np.uint32),
-                                out_counts=np.zeros(1, np.int32),
-                            )
-                            if got != want:
-                                raise NetPlaneError(
-                                    f"{addr}: torn stream "
-                                    f"{total + got}/{n}"
-                                )
-                        else:
-                            view = memoryview(row)[:want]
-                            got = 0
-                            while got < want:
-                                r = s.recv_into(view[got:], want - got)
-                                if r == 0:
-                                    raise NetPlaneError(
-                                        f"{addr}: torn stream "
-                                        f"{total + got}/{n}"
-                                    )
-                                got += r
-                        M.net_bytes_received_total.inc(want, plane=plane, direction="read")
-                        fobj.write(row[:want])
-                        total += want
-                        remaining -= want
+                    self._land(addr, s, want, row, 0, native)
                 except (OSError, NetPlaneError) as e:
-                    self._drop(addr)
-                    if isinstance(e, NetPlaneError):
-                        raise
-                    raise NetPlaneError(f"{addr}: {e}") from e
+                    raise NetPlaneError(
+                        f"{addr}: {e} (after {total}/{n})"
+                    ) from e
+                fobj.write(row[:want])
+                total += want
+            healthy = True
         finally:
+            if s is not None:
+                self._release(addr, s, healthy)
             if buf.shape[1] <= _POOL_MAX_WIDTH:
                 pool.put(buf)
         return total
-
 
     # ------------------------------------------------------- needle reads
 
@@ -1323,13 +1292,7 @@ class NetPlaneClient:
             healthy = True
             return data
         finally:
-            if healthy:
-                self._checkin(addr, s)
-            else:
-                try:
-                    s.close()
-                except OSError:
-                    pass
+            self._release(addr, s, healthy)
 
     # pool width class for an n-byte needle payload (see _pool_width —
     # shared with the server's write landing so the classes can't drift)
@@ -1344,44 +1307,11 @@ class NetPlaneClient:
         row = buf[0]
         try:
             try:
-                if native is not None:
-                    crc_state = np.zeros(1, np.uint32)
-                    filled = np.zeros(1, np.uint64)
-                    out_crcs = np.zeros(2, np.uint32)
-                    out_counts = np.zeros(1, np.int32)
-                    got = native.recv_into(
-                        s.fileno(), row, n,
-                        timeout_ms=int(self.timeout * 1000),
-                        granule=n, crc_state=crc_state,
-                        filled_state=filled, out_crcs=out_crcs,
-                        out_counts=out_counts,
-                    )
-                    if got != n:
-                        raise NetPlaneError(
-                            f"{addr}: torn needle stream {got}/{n}"
-                        )
-                    landed_crc = (
-                        int(out_crcs[0]) if int(out_counts[0]) > 0
-                        else int(crc_state[0])
-                    )
-                    M.net_bytes_received_total.inc(got, plane="native", direction="read")
-                else:
-                    view = memoryview(row)[:n]
-                    got = 0
-                    while got < n:
-                        r = s.recv_into(view[got:], n - got)
-                        if r == 0:
-                            raise NetPlaneError(
-                                f"{addr}: torn needle stream {got}/{n}"
-                            )
-                        got += r
-                    from ..utils.crc import crc32c as _crc
-
-                    landed_crc = _crc(row[:n])
-                    M.net_bytes_received_total.inc(n, plane="python", direction="read")
+                # one granule as long as the payload: its CRC is the needle's
+                (landed_crc,) = self._land(addr, s, n, row, n, native)
             except OSError as e:
                 raise NetPlaneError(f"{addr}: {e}") from e
-            if landed_crc != (want_crc & 0xFFFFFFFF):
+            if int(landed_crc) != (want_crc & 0xFFFFFFFF):
                 raise NetPlaneError(f"{addr}: needle CRC mismatch")
             # the one Python-level materialization on this path: pooled
             # landing buffer -> the bytes object the chunk cache keeps
@@ -1448,13 +1378,7 @@ class NetPlaneClient:
             )
             return int(n), int(stored_crc)
         finally:
-            if healthy:
-                self._checkin(addr, s)
-            else:
-                try:
-                    s.close()
-                except OSError:
-                    pass
+            self._release(addr, s, healthy)
 
     def write_needle(
         self, addr: tuple[str, int], vid: int, nid: int, cookie: int,

@@ -19,6 +19,7 @@ from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from urllib.parse import parse_qs, urlparse
 
 import grpc
+import numpy as np
 
 from ..ec import context as ec_context
 from ..ec import fleet
@@ -664,7 +665,8 @@ class VolumeService:
                 # bytes_copied_per_byte_served). The native twin of
                 # this loop is ec/net_plane.ShardNetPlane, which
                 # sendfile(2)s the same fd range with zero Python-side
-                # byte handling — clients prefer it and fall back here.
+                # byte handling — `_PeerShardReader` and the peer rebuild
+                # prefer it and fall back here.
                 chunk = os.pread(fd, min(_EC_STREAM_CHUNK, remaining), off)
                 if not chunk:
                     break
@@ -1101,8 +1103,11 @@ class VolumeService:
 class _PeerShardReader:
     """`EcVolume.remote_reader` of one EC volume on a volume server:
     ranges of shards that lie elsewhere, from the peers the master lists
-    for them, over `VolumeEcShardRead` (generation-fenced at the holder;
-    reference store_ec.go:599-651)."""
+    for them, generation-fenced at the holder (reference
+    store_ec.go:599-651). A range crosses the peer's native shard plane
+    (`ec/net_plane.py`: `sendfile` there, received here into the buffer
+    where it is used) and, where that plane does not answer, the
+    `VolumeEcShardRead` stream."""
 
     def __init__(self, server: "VolumeServer", vid: int):
         self.server = server
@@ -1122,31 +1127,71 @@ class _PeerShardReader:
         addrs = (f"{loc.url.split(':')[0]}:{loc.grpc_port}" for loc in locs)
         return [peer for peer in addrs if peer != me]
 
-    def __call__(self, shard_id: int, offset: int, size: int, generation: int):
+    def read_into(
+        self, shard_id: int, offset: int, size: int, generation: int,
+        dst: np.ndarray, granule: int = 0,
+    ):
+        """Land [offset, offset+size) of the shard in `dst` (1-D uint8)
+        from the first peer that answers in full. -> ("native", the
+        granule CRCs rolled while the bytes landed; None without
+        `granule`) where the peer's shard plane carried them, ("stream",
+        None) where its `VolumeEcShardRead` did, None where no peer
+        answered. Which of the two is chosen by what the connection
+        shows: a plane that refuses the connect (memoized for 30 s by
+        the client), refuses the request (stale generation, shard gone)
+        or leaves the range short or torn sends the read to that peer's
+        stream, then to the next peer."""
+        from ..ec import net_plane as _netp
+
+        client = self.server._net_plane_client()
         for peer in self.peers(shard_id):
             try:
-                buf = b"".join(
-                    c.data
-                    for c in self.server._peer_stub(peer).VolumeEcShardRead(
-                        pb.EcShardReadRequest(
-                            volume_id=self.vid,
-                            shard_id=shard_id,
-                            offset=offset,
-                            size=size,
-                            generation=generation,
-                        ),
-                        timeout=30,
-                        # request id + trace context ride to the
-                        # peer: a degraded read's remote sibling
-                        # fetches join the reader's trace
-                        metadata=trace.grpc_metadata(),
-                    )
+                crcs = client.read_into(
+                    _netp.net_addr(peer), self.vid, shard_id, generation,
+                    offset, size, dst, granule=granule,
                 )
-                if len(buf) == size:
-                    return buf
-            except grpc.RpcError:
-                continue
+                return "native", crcs
+            except (_netp.NetPlaneUnavailable, _netp.NetPlaneError):
+                pass
+            if self._stream_into(peer, shard_id, offset, size, generation, dst):
+                return "stream", None
         return None
+
+    def _stream_into(
+        self, peer: str, shard_id: int, offset: int, size: int,
+        generation: int, dst: np.ndarray,
+    ) -> bool:
+        """The same range over `peer`'s `VolumeEcShardRead`, chunk by
+        chunk into `dst`; whether it came in full."""
+        got = 0
+        try:
+            for c in self.server._peer_stub(peer).VolumeEcShardRead(
+                pb.EcShardReadRequest(
+                    volume_id=self.vid, shard_id=shard_id, offset=offset,
+                    size=size, generation=generation,
+                ),
+                timeout=30,
+                # request id + trace context ride to the peer: a
+                # degraded read's remote sibling fetches join the
+                # reader's trace
+                metadata=trace.grpc_metadata(),
+            ):
+                n = len(c.data)
+                if got + n > size:
+                    return False
+                dst[got : got + n] = np.frombuffer(c.data, dtype=np.uint8)
+                got += n
+        except grpc.RpcError:
+            return False
+        return got == size
+
+    def __call__(self, shard_id: int, offset: int, size: int, generation: int):
+        """The range as `bytes` (None where no peer answered): the bare
+        callable that `EcVolume` takes from a caller without buffers."""
+        dst = np.empty(size, dtype=np.uint8)
+        if self.read_into(shard_id, offset, size, generation, dst) is None:
+            return None
+        return dst.tobytes()
 
 
 class VolumeServer:

@@ -15,7 +15,7 @@ from typing import Optional
 
 from ..ec.context import ECError
 from ..ec.device_queue import QueueScope, default_scope
-from ..ec.ec_volume import EcVolume
+from ..ec.ec_volume import EcVolume, peer_fetch_pool
 from ..utils.chunk_cache import ChunkCache
 from .needle import Needle
 from .volume import NotFoundError, Volume, VolumeError
@@ -59,13 +59,15 @@ class DiskLocation:
         remote_reader_factory=None,
         ec_interval_cache: "ChunkCache | None | str" = "default",
         ec_scheduler: "QueueScope | None" = None,
+        ec_fetch_pool=None,
     ) -> None:
         """`ec_interval_cache`: a ChunkCache = the Store-level shared
         budget; None = cache disabled (Store budget 0); "default"
         (direct callers) = each EcVolume keeps its own private default
         cache, the pre-store-cache behavior. `ec_scheduler` is the
         Store's device-queue scope (placement + admission config) for
-        the mounted volumes' degraded reads."""
+        the mounted volumes' degraded reads, `ec_fetch_pool` its pool
+        for their reconstructions' fetches from peers."""
         if ec_interval_cache == "default":
             cache_kwargs = {}
         else:
@@ -77,6 +79,8 @@ class DiskLocation:
             }
         if ec_scheduler is not None:
             cache_kwargs["scheduler"] = ec_scheduler
+        if ec_fetch_pool is not None:
+            cache_kwargs["fetch_pool"] = ec_fetch_pool
         for name in sorted(os.listdir(self.directory)):
             m = _DAT_RE.match(name) or _VIF_RE.match(name)
             # a .vif with no local .dat is a cold-tiered volume: it must
@@ -183,6 +187,11 @@ class Store:
             if ec_interval_cache_bytes > 0
             else None
         )
+        # ONE pool for the fetches of every reconstruction that gathers
+        # rows from peers, made once (its threads start on first use): a
+        # pool made and torn down per reconstruction is thread starts,
+        # and every one is a hand-off that a GET waits for
+        self.ec_fetch_pool = peer_fetch_pool()
         self._lock = threading.RLock()
         # a directory spec may carry a type tag: "/data1:ssd"
         # (reference -dir=/d1 -disk=ssd); bare paths default to hdd
@@ -203,6 +212,7 @@ class Store:
             loc.load_existing(
                 ec_backend, ec_remote_reader_factory, self.ec_interval_cache,
                 ec_scheduler=self.ec_scheduler,
+                ec_fetch_pool=self.ec_fetch_pool,
             )
 
     # ----------------------------------------------------------- lookup
@@ -363,6 +373,7 @@ class Store:
                         interval_cache=self.ec_interval_cache,
                         interval_cache_bytes=0,
                         scheduler=self.ec_scheduler,
+                        fetch_pool=self.ec_fetch_pool,
                     )
                     loc.ec_volumes[vid] = ev
                     return ev
@@ -475,3 +486,4 @@ class Store:
                     ev.close()
                 loc.volumes.clear()
                 loc.ec_volumes.clear()
+        self.ec_fetch_pool.shutdown(wait=False, cancel_futures=True)

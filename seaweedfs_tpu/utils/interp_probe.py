@@ -93,6 +93,11 @@ _CLASS_BY_PREFIX = (
     ("ec-pipe-sink", "pipe_sink"),
     # the servers' RPC pools; a rebuild's dispatcher is one of them
     ("grpc-", "rpc"),
+    # what carries a peer's shard range: the holder's connection threads
+    # of its native shard plane (ec/net_plane.py), the reader's fetches
+    # of a reconstruction's rows (ec/ec_volume.py)
+    ("shard-net-conn-", "shard_plane"),
+    ("ec-peer-fetch", "peer_fetch"),
     (THREAD_NAME, "probe"),
 )
 _PACKAGE = __name__.split(".")[0]

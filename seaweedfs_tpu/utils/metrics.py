@@ -354,20 +354,24 @@ ec_bytes_total = REGISTRY.counter(
 )
 # An EC read that asked a PEER for bytes of a shard (ec/ec_volume.py),
 # counted at the reader, always on: kind = interval (a healthy interval
-# of a needle) | sibling (a row of a reconstruction's matrix). The
-# holder's side is sw_net_bytes_sent_total{plane="python",direction="read"}.
+# of a needle) | sibling (a row of a reconstruction's matrix); plane =
+# native (the peer's shard net plane carried the answer) | stream (its
+# VolumeEcShardRead did, or nobody answered). The holders' side is the
+# planes' sendfile_bytes + python_bytes (/status `ec_net_plane`) and,
+# for the streams, sw_net_bytes_sent_total{plane="python",direction="read"}.
 ec_peer_reads_total = REGISTRY.counter(
     "sw_ec_peer_reads_total",
-    "shard ranges an EC read asked a peer for", ("kind",),
+    "shard ranges an EC read asked a peer for", ("kind", "plane"),
 )
 ec_peer_read_bytes_total = REGISTRY.counter(
     "sw_ec_peer_read_bytes_total",
-    "bytes of shard ranges that peers answered EC reads with", ("kind",),
+    "bytes of shard ranges that peers answered EC reads with",
+    ("kind", "plane"),
 )
 ec_peer_read_seconds_total = REGISTRY.counter(
     "sw_ec_peer_read_seconds_total",
     "seconds EC reads waited for peers' shard ranges (a reconstruction's "
-    "fetches run side by side: each counts its own)", ("kind",),
+    "fetches run side by side: each counts its own)", ("kind", "plane"),
 )
 ec_leaf_repairs_total = REGISTRY.counter(
     "sw_ec_leaf_repairs_total",

@@ -2,7 +2,8 @@
 `ec.balance` leave them (ISSUE 35): spread over seven volume servers,
 server i holding shards i and i+7, server 1 dead. A GET enters at any
 live server, which reads the intervals of the needle from its own two
-shards, from its peers over `VolumeEcShardRead`, and those on a lost
+shards, from its peers over their native shard planes (ISSUE 37; over
+`VolumeEcShardRead` where a plane does not answer), and those on a lost
 shard by a reconstruction whose sibling rows it gathers from its peers.
 
 The reference of a GET is the body written under its file id (the
@@ -57,6 +58,12 @@ class Spread:
 
     def ev(self, s):
         return self.cl.servers[s].store.find_ec_volume(self.vol.vid)
+
+    def healthy_on(self, sid):
+        """Needles off the lost shards that have bytes on shard `sid`."""
+        gets = harness.load_module("drivers", "http_gets")
+        on = gets.needles_on_shard(self.vol, sid, LAYOUT)
+        return [i for i in self.healthy if i in on]
 
     def get(self, s, i, timeout=30.0):
         conn = http.client.HTTPConnection(*self.cl.host(s), timeout=timeout)
@@ -279,11 +286,17 @@ def test_sibling_rows_that_go_bad_in_flight_are_refused_and_other_peers_taken(
              and "reconstruct" in d["stages"]]
     assert reads
     for d in reads:
-        # (an armed fault registry takes the native plane off: every row
-        # is filled one at a time.) A rotten row was filled, checked and
-        # given up; a torn one is short and never reaches a row
-        filled = d["attrs"]["sibling_rows_single"]
-        assert filled == CTX.data_shards + (len(bad) if how == "rotten" else 0)
+        # (an armed fault registry takes the LOCAL native plane off: this
+        # server's own two rows are filled one at a time.) The peers' rows
+        # are admitted together; a rotten one was filled, checked and
+        # given up; a torn one is short and never counts as a row
+        a = d["attrs"]
+        assert a["sibling_rows_single"] == len(PLACED[entry])
+        from_peers = CTX.data_shards - len(PLACED[entry])
+        # (a rotten row that comes after the eighth good one is not looked at)
+        rotten_seen = a["sibling_rows_batched"] - from_peers
+        assert 0 <= rotten_seen <= (len(bad) if how == "rotten" else 0)
+        assert a["sibling_rows_remote"] == from_peers
     assert ev._coeff_cache
     for _t, src in ev._coeff_cache:
         assert set(src).isdisjoint(bad) and set(src).isdisjoint(LOST)
@@ -307,6 +320,7 @@ def test_a_healthy_interval_that_rots_in_flight_fails_the_needles_crc_and_is_reb
     vol, entry = spread.vol, 6
     ev = spread.ev(entry)
     i = next(i for i in spread.healthy if vol.sizes[i] >= 1 << 20)
+    quiet(spread)  # no earlier read's leftover fetch takes the one rotten chunk
     rec0 = ev.bytes_reconstructed
     rot = faults.bit_flip(seed=37, flips=1)
     # the first chunk any peer streams for this GET is an interval's
@@ -333,6 +347,7 @@ def test_a_record_whose_head_rots_in_flight_is_rebuilt_as_one_whose_body_does(sp
     def another_needles_id(ctx: dict, data: bytes) -> bytes:
         return data[:5] + bytes([data[5] ^ 0x40]) + data[6:]  # cookie 4 bytes, then the id
 
+    quiet(spread)
     rec0 = ev.bytes_reconstructed
     with faults.injected("server.ec_shard_read", another_needles_id, count=1):
         status, body = spread.get(entry, i)
@@ -398,13 +413,18 @@ COUNTERS = {
 }
 
 
-def counted(kind):
-    """The readers' three counters for `kind`, whole process."""
-    return {name: c.snapshot().get((kind,), 0) for name, c in COUNTERS.items()}
+def counted(kind, plane=None):
+    """The readers' three counters for `kind`, whole process, on one
+    plane (`native` | `stream`) or summed over both."""
+    return {
+        name: sum(v for (k, p), v in c.snapshot().items()
+                  if k == kind and plane in (None, p))
+        for name, c in COUNTERS.items()
+    }
 
 
-def grown(before, kind):
-    now = counted(kind)
+def grown(before, kind, plane=None):
+    now = counted(kind, plane)
     return {name: now[name] - before[name] for name in now}
 
 
@@ -416,6 +436,7 @@ def test_a_healthy_gets_reads_from_peers_are_counted_at_the_reader_and_seen_at_t
     i = next(i for i in spread.healthy if vol.sizes[i] >= 1 << 20)
     trace.configure(enabled=armed)
     trace.reset()
+    quiet(spread)  # an earlier reconstruction's unread fetches have ended
     before, siblings_before = counted("interval"), counted("sibling")
     status, body = spread.get(entry, i)
     assert status == 200 and body == vol.body(i)
@@ -452,6 +473,7 @@ def test_a_reconstructions_fetches_are_counted_started_used_and_unused(spread, d
     spread.drop_caches()
     trace.configure(enabled=True)
     trace.reset()
+    quiet(spread)
     before = counted("sibling")
     status, body = spread.get(entry, i)
     assert status == 200 and body == vol.body(i)
@@ -463,8 +485,11 @@ def test_a_reconstructions_fetches_are_counted_started_used_and_unused(spread, d
     askable = CTX.total - len(PLACED[entry]) - len(LOST)  # shards a live peer holds
     for d in reads:
         a = d["attrs"]
-        assert a["sibling_rows_batched"] == len(PLACED[entry])
-        assert a["sibling_rows_single"] == a["sibling_rows_remote"] == from_peers
+        # this server's own two rows in one batched read, its peers' eight
+        # admitted together: no row one at a time
+        assert a["sibling_rows_batched"] == len(PLACED[entry]) + from_peers
+        assert a["sibling_rows_remote"] == from_peers
+        assert "sibling_rows_single" not in a
         # a lost shard has no holder: it is looked up and not asked for
         assert from_peers <= a["peer_fetches_started"] <= askable
         assert a["peer_fetches_unused"] == a["peer_fetches_started"] - from_peers
@@ -499,6 +524,7 @@ def test_a_cached_extent_asks_no_peer_for_rows(spread, disarmed):
     assert spread.get(entry, i)[0] == 200
     trace.configure(enabled=True)
     trace.reset()
+    quiet(spread)  # the first GET's unread fetches have ended
     before = counted("sibling")
     status, body = spread.get(entry, i)
     assert status == 200 and body == vol.body(i)
@@ -507,3 +533,304 @@ def test_a_cached_extent_asks_no_peer_for_rows(spread, disarmed):
     assert hits and all("reconstruct" not in d["stages"] for d in hits)
     assert all("peer_fetches_started" not in d["attrs"] for d in hits)
     assert grown(before, "sibling")["reads"] == 0
+
+
+# ------------------------------------- which plane carries a peer's range
+
+
+def planes_sent(spread):
+    """What the live servers' shard planes have sent, either egress."""
+    planes = [spread.cl.servers[s].net_plane for s in LIVE]
+    return sum(p.sendfile_bytes + p.python_bytes for p in planes)
+
+
+def by_plane():
+    """The readers' counters, both kinds, by plane."""
+    return {plane: {kind: counted(kind, plane) for kind in ("interval", "sibling")}
+            for plane in ("native", "stream")}
+
+
+def plane_grown(before, plane, what):
+    """Growth of the readers' `what` (reads | bytes) on `plane`, both kinds."""
+    now = by_plane()[plane]
+    return sum(now[kind][what] - before[plane][kind][what] for kind in now)
+
+
+def bytes_grown(before, plane):
+    return plane_grown(before, plane, "bytes")
+
+
+def reads_grown(before, plane):
+    return plane_grown(before, plane, "reads")
+
+
+def settled(fn, timeout=10.0):
+    """A holder books its bytes after the last one is on the wire, and a
+    fetch that nobody reads ends after its GET: poll, do not race."""
+    deadline = time.time() + timeout
+    while not fn() and time.time() < deadline:
+        time.sleep(0.02)
+    return fn()
+
+
+def quiet(spread, still_s=0.3):
+    """(the readers' counters, the planes' bytes) once neither moves any
+    more: the fetches that earlier GETs left running have ended."""
+    seen, since = (by_plane(), planes_sent(spread)), time.time()
+    while time.time() - since < still_s:
+        time.sleep(0.05)
+        now = (by_plane(), planes_sent(spread))
+        if now != seen:
+            seen, since = now, time.time()
+    return seen
+
+
+@pytest.mark.parametrize("armed", [True, False], ids=["armed", "disarmed"])
+def test_interval_reads_and_sibling_rows_travel_the_native_shard_plane(spread, disarmed, armed):
+    """A healthy GET and a reconstructing one through server 3: every
+    byte that a peer answers crosses the peer's shard plane. Holders and
+    readers count the same bytes, to the byte; no stream carries one."""
+    vol, entry = spread.vol, 3
+    healthy = next(i for i in spread.healthy if vol.sizes[i] >= 1 << 20)
+    lost = spread.on_lost[-1]
+    spread.drop_caches()
+    trace.configure(enabled=armed)
+    trace.reset()
+    before, sent0 = quiet(spread)
+    for i in (healthy, lost):
+        status, body = spread.get(entry, i)
+        assert status == 200 and body == vol.body(i)
+    assert settled(lambda: planes_sent(spread) - sent0 == bytes_grown(before, "native") > 0)
+    assert bytes_grown(before, "stream") == 0 and reads_grown(before, "stream") == 0
+    now = by_plane()["native"]
+    assert all(now[kind]["reads"] > before["native"][kind]["reads"]
+               for kind in ("interval", "sibling"))
+    if not armed:
+        assert trace.traces() == []
+        return
+    for i in (healthy, lost):
+        (root,) = spread.roots(vol.fid(i))
+        attrs = root["attrs"]  # a needle that lies on lost shards alone reads no interval
+        assert attrs.get("peer_reads_native", 0) == attrs.get("peer_reads", 0) >= (i == healthy)
+        served = served_under(root)
+        assert served and all(d["attrs"]["plane"] == "native" for d in served)
+        assert all("stream" in d["stages"] for d in served)  # the stage keeps its name
+        for d in root["children"]:
+            if d["op"] == "ec.degraded_read" and "reconstruct" in d["stages"]:
+                a = d["attrs"]
+                assert a["peer_reads_native"] == a["sibling_rows_remote"] == 8
+                # the peers' rows were checked as they landed: `crc_verify` is
+                # this server's own two rows, then the output row
+                assert d["stages"]["crc_verify"]["count"] == 2
+
+
+def test_a_peers_rows_land_in_the_matrix_the_decode_takes(spread, disarmed):
+    """What `_sibling_matrix` hands to Reed-Solomon IS the shards' bytes,
+    row by row, whichever fetches came first; rows that landed where
+    they are used stay there (no second matrix when no spare was
+    needed)."""
+    vol, entry = spread.vol, 6
+    ev = spread.ev(entry)
+    seen = []
+    real = ev._sibling_matrix
+
+    def recording(shard_id, offset, size, prot, sp):
+        matrix, ids = real(shard_id, offset, size, prot, sp)
+        seen.append((offset, matrix, ids))
+        return matrix, ids
+
+    spread.drop_caches()
+    ev._sibling_matrix = recording
+    try:
+        for i in spread.on_lost[:6]:
+            status, body = spread.get(entry, i)
+            assert status == 200 and body == vol.body(i)
+    finally:
+        del ev._sibling_matrix
+    assert seen
+    for offset, matrix, ids in seen:
+        assert matrix.flags.c_contiguous and matrix.shape[0] == CTX.data_shards
+        assert set(PLACED[entry]) <= set(ids) and not set(ids) & set(LOST)
+        for row, sid in zip(matrix, ids):
+            assert np.array_equal(row, spread.shards[sid][offset : offset + matrix.shape[1]])
+
+
+@pytest.mark.parametrize("how", ["stopped", "refusing"])
+def test_with_a_holders_plane_gone_its_ranges_come_over_the_stream_and_every_get_is_right(
+    spread, disarmed, how
+):
+    """Server 4's shard plane is stopped (its port refuses: the client
+    remembers that for 30 s) or refuses every request. Its two shards
+    still reach their readers, over `VolumeEcShardRead`; the readers'
+    counters say `stream` for them and `native` for the other peers'."""
+    from seaweedfs_tpu.ec import net_plane
+
+    cl, vol, holder = spread.cl, spread.vol, 4
+    vs = cl.servers[holder]
+    plane = vs.net_plane
+    addr = net_plane.net_addr(cl.grpc_addr(holder))
+    resolve = plane.resolve
+
+    def refuse(vid, sid, gen):
+        raise net_plane.NetPlaneError("not today")
+
+    spread.drop_caches()
+    if how == "stopped":
+        plane.stop()
+    else:
+        plane.resolve = refuse
+    trace.configure(enabled=True)
+    trace.reset()
+    before, sent0 = quiet(spread)
+    try:
+        on_holder = [i for sid in PLACED[holder]
+                     for i in spread.healthy_on(sid)][:4]
+        assert on_holder
+        for entry in (0, 5):
+            for i in on_holder + spread.on_lost[4:6]:
+                status, body = spread.get(entry, i)
+                assert status == 200 and body == vol.body(i), (entry, i, status)
+        assert reads_grown(before, "stream") >= len(on_holder)
+        assert bytes_grown(before, "stream") > 0 and bytes_grown(before, "native") > 0
+        # the stopped or refusing plane sent nothing; the others' bytes
+        # are the readers' native bytes, still to the byte
+        assert settled(lambda: planes_sent(spread) - sent0 == bytes_grown(before, "native"))
+        if how == "stopped":
+            for entry in (0, 5):
+                assert addr in cl.servers[entry]._net_plane_client()._no_plane
+        # at the holder the same reads are `VolumeEcShardRead` roots
+        here = f"localhost:{vs.port}"
+        streamed = [d for d in trace.traces() if d["op"] == "rpc.ec_shard_read"
+                    and d["server"] == here and d["attrs"].get("plane") != "native"]
+        assert len(streamed) >= len(on_holder)
+        assert {d["attrs"]["shard"] for d in streamed} <= set(PLACED[holder])
+    finally:
+        plane.resolve = resolve
+        if how == "stopped":
+            vs.net_plane = net_plane.ShardNetPlane(
+                vs.ip, plane.port, vs._net_plane_resolve,
+                server_label=plane.server_label,
+            )
+            vs.net_plane.start()
+            for s in LIVE:  # forget the refusal and the dead sockets
+                client = cl.servers[s]._net_plane_client()
+                client.close()
+                client.reset()
+    # and with the plane back, the next read of that holder's shard takes it
+    before = quiet(spread)[0]
+    assert spread.get(0, on_holder[0])[0] == 200
+    assert reads_grown(before, "native") >= 1 and reads_grown(before, "stream") == 0
+
+
+def test_a_stale_generation_is_refused_on_the_plane_as_on_the_stream(spread, disarmed):
+    from seaweedfs_tpu.ec import net_plane
+
+    cl, vol = spread.cl, spread.vol
+    holder, sid = 5, PLACED[5][1]
+    gen = spread.ev(holder).encode_ts_ns
+    client = cl.servers[0]._net_plane_client()
+    addr = net_plane.net_addr(cl.grpc_addr(holder))
+    dst = np.zeros(8192, np.uint8)
+    with pytest.raises(net_plane.NetPlaneError, match="stale generation"):
+        client.read_into(addr, vol.vid, sid, gen + 1, 4096, 8192, dst)
+    assert not dst.any()  # refused before a byte was sent
+    read = cl.servers[0]._remote_reader_factory(vol.vid, "")
+    before = quiet(spread)[0]
+    assert read.read_into(sid, 4096, 8192, gen + 1, dst) is None  # plane, then stream: both fence
+    assert read.read_into(sid, 4096, 8192, gen, dst) == ("native", None)
+    assert dst.tobytes() == spread.shards[sid][4096 : 4096 + 8192].tobytes()
+    # the reader's own method counts nothing: `EcVolume._read_from_peer` does
+    assert by_plane() == before
+    crcs = read.read_into(sid, 0, 65536 + 100, gen, np.zeros(65536 + 100, np.uint8), 65536)
+    assert crcs[0] == "native" and len(crcs[1]) == 2
+
+
+def test_a_row_that_a_peer_sends_rotten_over_the_plane_never_enters_the_matrix(spread, disarmed):
+    """Server 2 reads each of its two shards from the other one's file
+    and `sendfile`s that: rot that no fault registry made, on the native
+    egress. The CRCs rolled while those rows land are not the sidecar's:
+    the rows are dropped as they arrive and the spares take their place;
+    the eight rows of the decode are the other peers' and the right ones."""
+    vol, entry, liar = spread.vol, 0, 2
+    ev, lying = spread.ev(entry), spread.ev(liar)
+    a, b = PLACED[liar]
+    seen = []
+    real = ev._sibling_matrix
+
+    def recording(shard_id, offset, size, prot, sp):
+        matrix, ids = real(shard_id, offset, size, prot, sp)
+        seen.append((offset, matrix, ids))
+        return matrix, ids
+
+    spread.drop_caches()
+    ev._coeff_cache.clear()
+    trace.configure(enabled=True)
+    trace.reset()
+    i = spread.on_lost[0]
+    lying.shard_fds[a], lying.shard_fds[b] = lying.shard_fds[b], lying.shard_fds[a]
+    ev._sibling_matrix = recording
+    try:
+        status, body = spread.get(entry, i)
+    finally:
+        del ev._sibling_matrix
+        lying.shard_fds[a], lying.shard_fds[b] = lying.shard_fds[b], lying.shard_fds[a]
+        spread.drop_caches()
+    assert status == 200 and body == vol.body(i)
+    assert seen
+    for offset, matrix, ids in seen:
+        assert not set(ids) & {a, b} and not set(ids) & set(LOST)
+        for row, sid in zip(matrix, ids):
+            assert np.array_equal(row, spread.shards[sid][offset : offset + matrix.shape[1]])
+    (root,) = spread.roots(vol.fid(i))
+    reads = [d for d in root["children"] if d["op"] == "ec.degraded_read"
+             and "reconstruct" in d["stages"]]
+    assert reads
+    for d in reads:
+        attrs = d["attrs"]
+        # ten fetches over the plane: eight rows kept, and each rotten one
+        # either landed, was looked at and dropped, or came after the eighth
+        assert attrs["peer_fetches_started"] == 10 and attrs["sibling_rows_remote"] == 8
+        looked_at = 10 - attrs["peer_fetches_unused"]
+        assert 8 <= looked_at == attrs["peer_reads_native"]
+        assert attrs["sibling_rows_batched"] == len(PLACED[entry]) + looked_at
+        assert "sibling_rows_single" not in attrs
+        assert d["stages"]["crc_verify"]["count"] == 2  # no stage of their own for peers' rows
+    served = served_under(root)
+    assert all(d["attrs"]["plane"] == "native" for d in served)
+
+
+def test_the_threads_that_carry_peers_bytes_have_names_and_classes_and_live_with_the_server(
+    spread, disarmed
+):
+    import threading
+
+    from seaweedfs_tpu.ec import ec_volume, net_plane
+    from seaweedfs_tpu.utils import interp_probe
+
+    vol, entry = spread.vol, 2
+    spread.drop_caches()
+    assert spread.get(entry, spread.on_lost[-1])[0] == 200
+
+    def named(prefix):
+        return {t for t in threading.enumerate() if t.name.startswith(prefix)}
+
+    fetchers = named(ec_volume.PEER_FETCH_THREAD_PREFIX)
+    conns = named(net_plane.CONN_THREAD_PREFIX)
+    assert fetchers and conns
+    assert {interp_probe.thread_class(t) for t in fetchers} == {"peer_fetch"}
+    assert {interp_probe.thread_class(t) for t in conns} == {"shard_plane"}
+    assert {t.name.rsplit("-", 1)[1] for t in conns} <= {
+        str(spread.cl.servers[s].net_plane.port) for s in LIVE
+    }
+    # one pool per store, made once: the threads of one reconstruction
+    # are there for the next
+    pool = spread.cl.servers[entry].store.ec_fetch_pool
+    assert spread.ev(entry)._fetch_pool is pool
+    assert pool._max_workers == ec_volume.PEER_FETCH_THREADS == 32
+    mine = {t for t in fetchers if t in pool._threads}
+    assert mine
+    quiet(spread)
+    spread.drop_caches()
+    assert spread.get(entry, spread.on_lost[-2])[0] == 200
+    now = {t for t in named(ec_volume.PEER_FETCH_THREAD_PREFIX) if t in pool._threads}
+    assert mine <= now and len(now) <= ec_volume.PEER_FETCH_THREADS

@@ -238,6 +238,9 @@ def test_other_python_grows_only_while_the_unnamed_thread_spins(armed):
     ("ec-pipe-reader", "pipe_reader"),
     ("ec-pipe-sink", "pipe_sink"),
     ("grpc-volume_3", "rpc"),
+    ("shard-net-conn-18080", "shard_plane"),
+    ("ec-peer-fetch_7", "peer_fetch"),
+    ("shard-net-plane", "other_python"),  # the acceptor: its target classes it
     (interp_probe.THREAD_NAME, "probe"),
     ("Thread-12 (work)", "other_python"),
     ("MainThread", "other_python"),

@@ -302,6 +302,151 @@ def test_shard_range_reads_native_vs_python_and_generation_fence(
         c.read_bytes(fp.addr, 1, 2, 999, 0, 16)
 
 
+# ------------------------------------- a connection per in-flight read
+
+
+def pooled(client, addr):
+    with client._lock:
+        return [s for s, _t in client._npool.get(addr, [])]
+
+
+def test_two_range_reads_to_one_address_are_in_flight_at_once(tmp_path, planes_env):
+    """Each `read_into` checks a connection out for itself: two to one
+    holder meet INSIDE the holder's resolver, which lets neither go on
+    before the other has arrived. A client that kept one socket per
+    address would send the second only after the first had ended."""
+    make, client = planes_env
+    _base, pdir, blobs = synth(tmp_path, local=())
+    fp = make(pdir)
+    both_here = threading.Barrier(2, timeout=10)
+    resolve = fp.server.resolve
+
+    def meet(vid, sid, gen):
+        try:
+            both_here.wait()
+        except threading.BrokenBarrierError:
+            raise net_plane.NetPlaneError("the other read never came") from None
+        return resolve(vid, sid, gen)
+
+    fp.server.resolve = meet
+    c = client()
+    got: dict[int, object] = {}
+
+    def read(sid):
+        dst = np.zeros(SHARD_SIZE, np.uint8)
+        try:
+            crcs = c.read_into(fp.addr, 1, sid, 3, 0, SHARD_SIZE, dst, granule=BLOCK)
+            got[sid] = (dst.tobytes(), [int(x) for x in crcs])
+        except Exception as e:  # noqa: BLE001 - shown by the assertion below
+            got[sid] = e
+
+    threads = [threading.Thread(target=read, args=(sid,)) for sid in (2, 4)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(20)
+    for sid in (2, 4):
+        assert not isinstance(got[sid], Exception), got[sid]
+        body, crcs = got[sid]
+        assert body == blobs[sid]
+        assert crcs == [crc32c(blobs[sid][lo : lo + BLOCK]) for lo in range(0, SHARD_SIZE, BLOCK)]
+    # both connections went back to the pool, and the next read takes one
+    fp.server.resolve = resolve
+    assert len(pooled(c, fp.addr)) == 2
+    c.read_into(fp.addr, 1, 2, 3, 0, 64, np.zeros(64, np.uint8))
+    assert len(pooled(c, fp.addr)) == 2
+
+
+@pytest.mark.parametrize("how", ["refused", "short", "torn"])
+def test_a_range_reads_connection_is_kept_after_a_refusal_and_closed_after_a_bad_stream(
+    tmp_path, planes_env, how
+):
+    """A refusal leaves the stream in frame sync: its connection serves
+    the next read. A range that comes short of what was asked, or stops
+    half way, leaves bytes nobody will read: that connection is closed,
+    and the read after it gets a fresh one and the right bytes."""
+    make, client = planes_env
+    _base, pdir, blobs = synth(tmp_path, local=())
+    fp = make(pdir, plane_cls=TruncatingPlane if how == "torn" else None)
+    c = client()
+    dst = np.zeros(64, np.uint8)
+    with pytest.raises(net_plane.NetPlaneError) as refused:
+        if how == "refused":
+            c.read_into(fp.addr, 1, 2, 999, 0, 64, dst)  # a stale generation
+        elif how == "short":
+            c.read_into(fp.addr, 1, 2, 3, SHARD_SIZE - 10, 64, dst)  # past the end
+        else:
+            c.read_into(fp.addr, 1, 2, 3, 0, 64, dst)
+    assert {"refused": "stale generation", "short": "short stream", "torn": "torn stream"}[
+        how
+    ] in str(refused.value)
+    assert len(pooled(c, fp.addr)) == (1 if how == "refused" else 0)
+    if how == "torn":
+        fp.server._serve_one = net_plane.ShardNetPlane._serve_one.__get__(fp.server)
+    c.read_into(fp.addr, 1, 2, 3, 7, 64, dst)
+    assert dst.tobytes() == blobs[2][7:71]
+    assert len(pooled(c, fp.addr)) == 1
+
+
+def test_a_whole_shard_fetch_and_a_bytes_read_use_the_pool_too(tmp_path, planes_env):
+    make, client = planes_env
+    _base, pdir, blobs = synth(tmp_path, local=())
+    fp = make(pdir)
+    c = client()
+    out = tmp_path / "copy"
+    with open(out, "wb") as f:
+        assert c.fetch_shard_to_file(fp.addr, 1, 5, 3, f, chunk=BLOCK) == SHARD_SIZE
+    assert out.read_bytes() == blobs[5]
+    (kept,) = pooled(c, fp.addr)
+    assert c.read_bytes(fp.addr, 1, 5, 3, 9, 100) == blobs[5][9:109]
+    assert pooled(c, fp.addr) == [kept]
+    assert not hasattr(c, "_conns") and not hasattr(c, "_addr_lock")
+
+
+def test_the_planes_connection_threads_are_named_and_its_egress_sums_are_exact(
+    tmp_path, planes_env
+):
+    """Eight readers at one plane, 40 reads each: every connection
+    thread carries the plane's prefix (the wait probes class it by
+    that), and `sendfile_bytes` + `python_bytes`, summed under a lock,
+    is to the byte what the readers landed."""
+    from seaweedfs_tpu.utils import interp_probe
+
+    make, client = planes_env
+    _base, pdir, blobs = synth(tmp_path, local=())
+    fp = make(pdir)
+    c = client()
+    landed = [0] * 8
+
+    def read(w):
+        dst = np.zeros(SHARD_SIZE, np.uint8)
+        for n in range(40):
+            size = 1 + (w * 131 + n * 17) % SHARD_SIZE
+            c.read_into(fp.addr, 1, (w + n) % CTX.total, 3, 0, size, dst)
+            landed[w] += size
+
+    import sys
+
+    threads = [threading.Thread(target=read, args=(w,)) for w in range(8)]
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)  # a lost update needs a switch between read and write
+    try:
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(60)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in threads)
+    serving = [t for t in threading.enumerate()
+               if t.name == f"{net_plane.CONN_THREAD_PREFIX}{fp.server.port}"]
+    assert 1 <= len(serving) <= 8  # a connection, and its thread, per reader at most
+    assert {interp_probe.thread_class(t) for t in serving} == {"shard_plane"}
+    st = fp.server
+    _settle(lambda: st.sendfile_bytes + st.python_bytes == sum(landed))
+    assert st.sendfile_bytes + st.python_bytes == sum(landed)
+
+
 # ------------------------------------------ chaos on the native ingress
 
 
