@@ -41,7 +41,8 @@ extern "C" {
 // ---------------------------------------------------------------------------
 // The return stamp. Every call below that does real work without the
 // interpreter (sn_batch_pread, sn_crc32c_granules, sn_sendv,
-// sn_sink_append) writes CLOCK_MONOTONIC through `ret_ns` as its last
+// sn_sink_append, sn_send_file, sn_recv_into) writes CLOCK_MONOTONIC
+// through `ret_ns` as its last
 // act: Python's time.perf_counter_ns() is the same clock, so the wrapper
 // reads how long the calling thread then waited to hold the interpreter
 // again (utils/native.py books it when the tracer is armed; a caller
@@ -601,8 +602,10 @@ int sn_fadvise_willneed(int fd, uint64_t off, uint64_t len) {
 // ---------------------------------------------------------------------------
 
 int64_t sn_send_file(int out_fd, int in_fd, uint64_t offset, uint64_t len,
-                     int timeout_ms) {
-    return sn_net::send_file(out_fd, in_fd, offset, len, timeout_ms);
+                     int timeout_ms, int64_t* ret_ns) {
+    int64_t sent = sn_net::send_file(out_fd, in_fd, offset, len, timeout_ms);
+    stamp_return(ret_ns);
+    return sent;
 }
 
 // Scatter-gather send of n buffers. Returns total bytes sent (== sum of
@@ -733,11 +736,11 @@ int sn_recv_overlap_active(uint64_t len) {
     return recv_overlap_wanted(len, mode) ? 1 : 0;
 }
 
-int64_t sn_recv_into(int fd, uint8_t* dst, uint64_t len, int timeout_ms,
-                     uint32_t granule, uint32_t* crc_state,
-                     uint64_t* filled_state, uint32_t* out_crcs,
-                     int32_t* out_count, int32_t max_out,
-                     int32_t overlap_mode) {
+static int64_t recv_into(int fd, uint8_t* dst, uint64_t len, int timeout_ms,
+                         uint32_t granule, uint32_t* crc_state,
+                         uint64_t* filled_state, uint32_t* out_crcs,
+                         int32_t* out_count, int32_t max_out,
+                         int32_t overlap_mode) {
     crc32c_table_init();
     if (out_count) *out_count = 0;
     if (granule == 0)
@@ -821,6 +824,18 @@ int64_t sn_recv_into(int fd, uint8_t* dst, uint64_t len, int timeout_ms,
         *out_count += added;
     }
     return recv_rc;
+}
+
+int64_t sn_recv_into(int fd, uint8_t* dst, uint64_t len, int timeout_ms,
+                     uint32_t granule, uint32_t* crc_state,
+                     uint64_t* filled_state, uint32_t* out_crcs,
+                     int32_t* out_count, int32_t max_out,
+                     int32_t overlap_mode, int64_t* ret_ns) {
+    int64_t got = recv_into(fd, dst, len, timeout_ms, granule, crc_state,
+                            filled_state, out_crcs, out_count, max_out,
+                            overlap_mode);
+    stamp_return(ret_ns);
+    return got;
 }
 
 static int pwrite_full(int fd, const uint8_t* p, size_t len, uint64_t off) {

@@ -381,6 +381,7 @@ class EcVolume:
     def _read_from_peer(
         self, kind: str, shard_id: int, offset: int, size: int,
         dst: Optional[np.ndarray] = None, granule: int = 0,
+        queued_ns: int = 0,
     ) -> Optional[_PeerAnswer]:
         """[offset, offset+size) of a shard from the peers that hold it,
         or None where none answered in full; counted at this, the
@@ -389,7 +390,51 @@ class EcVolume:
         nobody answered counts under `stream`, the transport asked
         last).
 
-        A reader that offers `read_into` lands the range in `dst` (1-D
+        Armed, the read is a span `ec.peer_read` under the ambient one
+        (the GET's root for an interval; for a sibling row the
+        `ec.degraded_read` whose context the fetch thread runs under)
+        and the current span while it lasts, so the holder's
+        `rpc.ec_shard_read` names IT as its parent. Its stages lie end
+        to end from its start to its end: `fetch_queue` (a sibling
+        read's wait for a fetch thread, from `queued_ns`, the
+        `perf_counter_ns` of its submit), then `conn_checkout`,
+        `request_rtt` and `payload_land`, which the transports turn to
+        (`trace.turn`) as they get there. Disarmed: one module-bool
+        check."""
+        # `peer`: the cluster's reader writes the address it asks, as it
+        # asks it (server/volume_server.py _PeerShardReader.read_into)
+        sp = trace.start(
+            "ec.peer_read", name=f"v{self.volume_id}.{shard_id:02d}",
+            kind=kind, shard=shard_id, size=size,
+            peer="", plane="stream", answered=0,
+        ) if trace.armed else None
+        if sp is None:
+            return self._ask_peers(kind, shard_id, offset, size, dst, granule)
+        got = None
+        timer = sp.stage("conn_checkout")
+        try:
+            with trace.activate(sp), timer:
+                # the span starts where its first stage does
+                sp.backdate(queued_ns or timer.began_ns, timer.began_cpu_ns)
+                if queued_ns:
+                    sp.add_interval("fetch_queue", queued_ns, timer.began_ns)
+                got = self._ask_peers(
+                    kind, shard_id, offset, size, dst, granule
+                )
+        finally:
+            # the reconstruction that asked may have closed this span
+            # under the fetch (`unused`): then that stands, whole
+            if got is None:
+                sp.finish(timer.ended_ns)
+            else:
+                sp.finish(timer.ended_ns, answered=1, plane=got.plane)
+        return got
+
+    def _ask_peers(
+        self, kind: str, shard_id: int, offset: int, size: int,
+        dst: Optional[np.ndarray], granule: int,
+    ) -> Optional[_PeerAnswer]:
+        """A reader that offers `read_into` lands the range in `dst` (1-D
         uint8; a fresh buffer where the caller has none) and says which
         plane carried it; with `granule` it hands back the granule
         CRCs that were rolled while the bytes landed, where the plane
@@ -481,7 +526,19 @@ class EcVolume:
                     shard_id, offset, size, sp
                 )
         finally:
-            trace.finish(sp)
+            if sp is not None:
+                # a fetch that nobody waits for any more (the matrix was
+                # full without it) runs on in its thread: its span ends
+                # here with its parent's, at the length it has, and
+                # takes nothing more (Span.finish: the first close holds)
+                end_ns = time.perf_counter_ns()
+                outlived = sum(
+                    c.op == "ec.peer_read" and c.finish(end_ns, unused=1)
+                    for c in list(sp.children)
+                )
+                if outlived:
+                    sp.count("peer_reads_outlived", outlived)
+                sp.finish(end_ns)
 
     def _recover_interval_traced(
         self, shard_id: int, offset: int, size: int, sp
@@ -653,9 +710,10 @@ class EcVolume:
         granule = prot.verify_granularity(missing[0])[0] if prot is not None else 0
         n_crcs = -(-size // granule) if granule else 0
 
-        def fetch(j):
+        def fetch(j, queued_ns):
             return j, self._read_from_peer(
-                "sibling", missing[j], offset, size, dsts[j], granule
+                "sibling", missing[j], offset, size, dsts[j], granule,
+                queued_ns,
             )
 
         pool = self._peer_fetch_pool()
@@ -667,13 +725,16 @@ class EcVolume:
         try:
             # "peer_read" covers only the blocked wait on peer fetches.
             # Per-task contextvar copy: the fetch thread sees the caller's
-            # request id + active span, so the peer's span joins this
-            # read's trace whichever plane carries the bytes.
+            # request id + active span, so the fetch's `ec.peer_read` is
+            # a child of this read's span (its `fetch_queue` begins at
+            # the submit) and the peer's span joins the trace whichever
+            # plane carries the bytes.
             with trace.stage(sp, "peer_read"):
-                asked = [
-                    pool.submit(contextvars.copy_context().run, fetch, j)
-                    for j in range(len(missing))
-                ]
+                for j in range(len(missing)):
+                    asked.append(pool.submit(
+                        contextvars.copy_context().run, fetch, j,
+                        time.perf_counter_ns() if sp is not None else 0,
+                    ))
             futures = set(asked)
             # stop as soon as the rows are filled: one hung peer must not
             # stall the read for the full time-out
@@ -741,8 +802,12 @@ class EcVolume:
             matrix = np.stack(
                 [dsts[moved[r]] if r in moved else matrix[r] for r in range(len(ids))]
             )
-        if sp is not None and used:
-            sp.count("sibling_rows_remote", len(used))
+        if sp is not None:
+            # 1: a spare stands where an open row's own fetch did not
+            # come good in time, and the matrix was gathered once more
+            sp.count("matrix_regathers", 1 if moved else 0)
+            if used:
+                sp.count("sibling_rows_remote", len(used))
         return matrix
 
     def _decode_row(self, shard_id: int, src_ids: tuple[int, ...]) -> np.ndarray:

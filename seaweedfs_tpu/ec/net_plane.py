@@ -456,16 +456,19 @@ class ShardNetPlane:
                     if not ok:
                         return
                     continue
+                # its parent is the reader's `ec.peer_read` span, one to
+                # one; `stream` is open on this thread while the range
+                # is served, so _serve_one laps its parts and the
+                # stamped return of `send_file` is booked here
                 sp = trace.start_from_metadata(
                     "rpc.ec_shard_read", md, server=self.server_label,
                     volume=vid, shard=sid, offset=off, size=size,
                     plane="native",
                 )
-                t0 = time.perf_counter()
                 try:
-                    ok = self._serve_one(conn, vid, sid, gen, off, size)
+                    with trace.stage(sp, "stream"):
+                        ok = self._serve_one(conn, vid, sid, gen, off, size)
                 finally:
-                    trace.add_stage(sp, "stream", time.perf_counter() - t0)
                     trace.finish(sp)
                 if not ok:
                     return
@@ -492,7 +495,11 @@ class ShardNetPlane:
             return False
 
     def _serve_one(self, conn, vid, sid, gen, off, size) -> bool:
-        """Serve one range request; False = connection must close."""
+        """Serve one range request; False = connection must close.
+        Armed, the caller's `stream` stage is split into `.resolve`,
+        `.header` and `.sendfile` (one module-bool check each where
+        not)."""
+        trace.lap("resolve")
         try:
             # Same named chaos point as the gRPC servicer: a raised
             # IOError is a refused stream (client replans); a mutate is
@@ -506,12 +513,14 @@ class ShardNetPlane:
         except NetPlaneError as e:
             return self._error(conn, str(e))
         n = max(0, min(size, fsize - off)) if off < fsize else 0
+        trace.lap("header")
         try:
             conn.sendall(_RESP.pack(0, n))
         except OSError:
             return False
         if n == 0:
             return True
+        trace.lap("sendfile")
         native = _native_mod() if egress_native() else None
         if native is not None:
             try:
@@ -1040,11 +1049,18 @@ class NetPlaneClient:
         releases the connection. Whatever raises here has released it.
         With `exact` (the default) a server-side EOF clamp raises —
         range callers sized their landing buffer; `exact=False` accepts
-        the clamp (whole-shard fetches discover the size this way)."""
+        the clamp (whole-shard fetches discover the size this way).
+
+        Under a read from a peer (`ec.peer_read`, whose thread has one
+        of `trace.TURN_STAGES` open) the check-out is that span's
+        `conn_checkout` and everything from the request's `sendall` to
+        the parsed header its `request_rtt`."""
+        trace.turn("conn_checkout")
         s = self._checkout(addr)
         healthy = False
         try:
             meta = _encode_meta()
+            trace.turn("request_rtt")
             try:
                 s.sendall(
                     _REQ.pack(MAGIC, vid, sid, gen, off, size, len(meta))
@@ -1108,7 +1124,9 @@ class NetPlaneClient:
     def _land(self, addr, s, size, dst, granule, native):
         """`size` payload bytes of the response `s` stands at, into
         `dst`; -> the granule CRCs rolled on the way (None without
-        `granule`). Raises on a torn stream."""
+        `granule`). Raises on a torn stream. A read from a peer's
+        `payload_land`."""
+        trace.turn("payload_land")
         if native is not None:
             crc_state = np.zeros(1, np.uint32)
             filled = np.zeros(1, np.uint64)

@@ -634,7 +634,10 @@ class VolumeService:
             shard=request.shard_id, offset=request.offset,
             size=request.size,
         )
-        t0 = time.perf_counter()
+        # `stream` and the two parts this servicer has (`.resolve`, and
+        # the pread/yield loop as `.sendfile`) from clock readings: a
+        # generator cannot hold a with-scoped stage across its yields
+        t0_ns = resolved_ns = time.perf_counter_ns() if sp is not None else 0
         try:
             ev = self.store.find_ec_volume(request.volume_id)
             if ev is None:
@@ -657,6 +660,8 @@ class VolumeService:
                 )
             except IOError as e:
                 context.abort(grpc.StatusCode.UNAVAILABLE, str(e))
+            if sp is not None:
+                resolved_ns = time.perf_counter_ns()
             remaining = request.size
             off = request.offset
             while remaining > 0:
@@ -684,8 +689,13 @@ class VolumeService:
                 off += orig_len
                 remaining -= orig_len
         finally:
-            trace.add_stage(sp, "stream", time.perf_counter() - t0)
-            trace.finish(sp)
+            if sp is not None:
+                end_ns = time.perf_counter_ns()
+                if resolved_ns > t0_ns:  # not refused before the first chunk
+                    sp.add_interval("stream.resolve", t0_ns, resolved_ns)
+                    sp.add_interval("stream.sendfile", resolved_ns, end_ns)
+                sp.add_interval("stream", t0_ns, end_ns)
+                sp.finish(end_ns)
 
     def VolumeEcBlobDelete(self, request, context):
         # a mutation: on keyed clusters it needs the same peer token the
@@ -1144,7 +1154,12 @@ class _PeerShardReader:
         from ..ec import net_plane as _netp
 
         client = self.server._net_plane_client()
+        read_span = trace.current()  # armed: the caller's `ec.peer_read`
+        if read_span is not None and read_span.op != "ec.peer_read":
+            read_span = None  # a bare call under some other span
         for peer in self.peers(shard_id):
+            if read_span is not None:
+                read_span.attrs["peer"] = peer  # the address asked last
             try:
                 crcs = client.read_into(
                     _netp.net_addr(peer), self.vid, shard_id, generation,
@@ -1162,8 +1177,11 @@ class _PeerShardReader:
         generation: int, dst: np.ndarray,
     ) -> bool:
         """The same range over `peer`'s `VolumeEcShardRead`, chunk by
-        chunk into `dst`; whether it came in full."""
+        chunk into `dst`; whether it came in full. Of the read's
+        `ec.peer_read` span: `request_rtt` to the first chunk,
+        `payload_land` from there."""
         got = 0
+        trace.turn("request_rtt")
         try:
             for c in self.server._peer_stub(peer).VolumeEcShardRead(
                 pb.EcShardReadRequest(
@@ -1176,6 +1194,8 @@ class _PeerShardReader:
                 # reader's trace
                 metadata=trace.grpc_metadata(),
             ):
+                if got == 0:
+                    trace.turn("payload_land")
                 n = len(c.data)
                 if got + n > size:
                     return False
@@ -2169,7 +2189,8 @@ class VolumeServer:
         """Device-telemetry blob riding every full heartbeat: per-chip
         queue load + breaker state (ec/chip_pool.chip_load_hint over
         this server's OWN scheduler scope), the flight recorder's
-        per-op/stage EWMAs, and per-EC-volume HEAT counters (lifetime
+        EWMAs of the two device stages (what ec/placement.py sums, and
+        no more), and per-EC-volume HEAT counters (lifetime
         read/reconstruction bytes — the master's rebalance scanner
         diffs them per sweep to rank hot volumes, ec/rebalance.py).
         The master is the only consumer — it aggregates into
@@ -2342,6 +2363,7 @@ class VolumeServer:
         class Handler(RequestTracingMixin, BaseHTTPRequestHandler):
             protocol_version = "HTTP/1.1"
             trace_server_kind = "volume"
+            trace_addr = f"{server.ip}:{server.port}"
 
             def log_message(self, *a):
                 pass

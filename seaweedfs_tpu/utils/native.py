@@ -185,6 +185,7 @@ _lib.sn_send_file.argtypes = [
     ctypes.c_uint64,  # offset
     ctypes.c_uint64,  # len
     ctypes.c_int,     # timeout_ms (-1 = block)
+    ctypes.c_void_p,  # ret_ns
 ]
 _lib.sn_sendv.restype = ctypes.c_int64
 _lib.sn_sendv.argtypes = [
@@ -208,6 +209,7 @@ _lib.sn_recv_into.argtypes = [
     ctypes.c_void_p,  # out_count (i32[1])
     ctypes.c_int32,   # max_out
     ctypes.c_int32,   # overlap_mode (0 serial / 1 overlap / -1 auto)
+    ctypes.c_void_p,  # ret_ns
 ]
 _lib.sn_recv_overlap_active.restype = ctypes.c_int
 _lib.sn_recv_overlap_active.argtypes = [ctypes.c_uint64]
@@ -255,8 +257,10 @@ _lib.sn_probe_read.argtypes = [
 ]
 
 
-# The four calls that do real work without the interpreter (batch_pread,
-# crc32c_granules, sendv, NativeSink.append) end by writing
+# The six calls that do real work without the interpreter (batch_pread,
+# crc32c_granules, sendv, NativeSink.append, and the two that carry every
+# byte of a read from a peer: send_file at the holder, recv_into at the
+# reader) end by writing
 # CLOCK_MONOTONIC where their last argument, `ret_ns`, points: how long
 # the thread then waits to hold the interpreter again is
 # `perf_counter_ns()` on return less that stamp. Armed, the call gets a
@@ -487,7 +491,9 @@ def send_file(
     buffer, where the kernel path is unsupported). Returns bytes sent;
     SHORT only when in_fd hits EOF. Raises OSError on socket errors or
     timeout."""
-    sent = _lib.sn_send_file(out_fd, in_fd, offset, length, timeout_ms)
+    sent = _stamped(
+        _lib.sn_send_file, out_fd, in_fd, offset, length, timeout_ms
+    )
     if sent < 0:
         raise OSError(-sent, f"sn_send_file: {os.strerror(-sent)}")
     return int(sent)
@@ -591,7 +597,8 @@ def recv_into(
         assert out_crcs.dtype == np.uint32 and out_crcs.flags.c_contiguous
         assert out_counts.dtype == np.int32
         max_out = out_crcs.shape[-1]
-    got = _lib.sn_recv_into(
+    got = _stamped(
+        _lib.sn_recv_into,
         fd,
         ctypes.c_void_p(dst.ctypes.data),
         length,

@@ -70,9 +70,13 @@ class RequestTracingMixin:
     ``sw_request_seconds{server,op}`` SLO histogram. Subclasses set
     ``trace_server_kind`` ("s3", "filer", "volume", "master",
     "webdav") and may refine the op class per request by assigning
-    ``self._sw_op`` (defaults to the lowercased HTTP method)."""
+    ``self._sw_op`` (defaults to the lowercased HTTP method). A handler
+    that knows its server's ``ip:port`` says so in ``trace_addr``, and
+    its root spans carry it as ``addr``: WHICH volume server answered,
+    where ``server`` only says that one did."""
 
     trace_server_kind = "http"
+    trace_addr = ""
 
     def parse_request(self):  # type: ignore[override]
         ok = super().parse_request()
@@ -92,6 +96,8 @@ class RequestTracingMixin:
                     name=f"{self.command} {self.path.split('?', 1)[0]}",
                     server=self.trace_server_kind,
                 )
+                if sp is not None and self.trace_addr:
+                    sp.attrs["addr"] = self.trace_addr
                 self._sw_span = sp
                 self._sw_token = trace.set_current(sp)
                 begun = self.__dict__.pop("_sw_begun", None)

@@ -54,8 +54,10 @@ Model
   thread that is ready to run waits for the interpreter, how long for a
   core, and which class of thread burnt the CPU. Every 100 ms they close
   a root span :data:`PROBE_OP`, kept in a ring of its own. The native
-  calls of ``utils/native.py`` book what their return to the interpreter
-  cost (:func:`book_return`: ``interp_wait_ns``, ``interp_returns``).
+  calls of ``utils/native.py`` that do real work book what their return
+  to the interpreter cost (:func:`book_return`: ``interp_wait_ns``,
+  ``interp_returns``), ``recv_into`` and ``send_file``, which carry every
+  byte of a read from a peer, among them.
 
 Canonical stage names (the Prometheus ``stage`` label of
 ``sw_ec_stage_seconds``):
@@ -77,6 +79,13 @@ Canonical stage names (the Prometheus ``stage`` label of
 ``reconstruct``    synchronous (non-staged) Reed-Solomon apply
 ``fsync_publish``  flush/fsync/rename publication windows
 ``stream``         server-side RPC response streaming
+``fetch_queue``    a read from a peer (``ec.peer_read``): a reconstruction's
+                   fetch handed to the fetch pool, until its thread runs
+``conn_checkout``  ... a connection to the holder's shard plane taken from
+                   the pool, or dialled
+``request_rtt``    ... the request sent, until the answer's header is
+                   parsed (over the stream: until the first chunk)
+``payload_land``   ... the range landing in the buffer it is used in
 ``ready_wait``     HTTP: a readable connection queued for a pool worker
                    (ends where its span starts)
 ``parse``          HTTP: request line and headers
@@ -96,7 +105,16 @@ matrix's one ``device_put``) + ``.launch`` + ``.ready`` + ``.d2h``;
 ``.ecx`` look-up under the volume's lock) + ``.shard`` (intervals read
 from mounted shards or a peer) + ``.recover`` (intervals recovered: the
 ``ec.degraded_read`` child span lies inside) + ``.parse`` (the join and
-``Needle.from_bytes``, the body's CRC).
+``Needle.from_bytes``, the body's CRC); ``stream`` of an
+``rpc.ec_shard_read`` = ``.resolve`` (the fault point, the volume, the
+generation fence, the shard's file) + ``.header`` (the shard plane's
+response header leaving) + ``.sendfile`` (the range leaving: ``sendfile``
+or the Python egress loop).
+
+The four stages of an ``ec.peer_read`` lie END TO END on their span and
+add up to it: the reader opens the first one with its span and whoever
+does the next step turns (:func:`turn`) the thread's open stage into the next
+at one clock reading (``ec/net_plane.py`` is handed no span).
 
 Overlap efficiency
 ------------------
@@ -173,6 +191,8 @@ STAGES = frozenset({
     # an EC read's wait for bytes of a shard that a peer holds
     # (ec/ec_volume.py: an interval of a needle, a reconstruction's rows)
     "peer_read",
+    # one such read, on its own span `ec.peer_read`: see TURN_STAGES
+    "fetch_queue", "conn_checkout", "request_rtt", "payload_land",
     # leaf repair (PR 8)
     "repair_patch", "repair_fetch",
     # streaming EC (PR 14): incremental parity math + delta pwrites
@@ -191,6 +211,9 @@ _SUB_PARTS = {
     "reconstruct": ("put", "launch", "ready", "d2h"),
     # an EC needle read (ec/ec_volume.py), under the HTTP handler's stage
     "volume.read": ("index", "shard", "peer", "recover", "parse"),
+    # the holder's side of a read from a peer (ec/net_plane.py
+    # ShardNetPlane._serve_one, the VolumeEcShardRead servicer)
+    "stream": ("resolve", "header", "sendfile"),
 }
 _SUB_NAME = {
     (parent, part): f"{parent}.{part}"
@@ -198,6 +221,11 @@ _SUB_NAME = {
 }
 SUB_STAGES = frozenset(_SUB_NAME.values())
 STAGES = STAGES | SUB_STAGES
+
+# Stages that turn() may end and begin: the steps of one read from a peer,
+# which lie end to end on its `ec.peer_read` span. Any other open stage
+# (a rebuild's `peer_fetch` around the same client calls) is left alone.
+TURN_STAGES = frozenset({"conn_checkout", "request_rtt", "payload_land"})
 
 # Intervals kept per span; a (10, 16 MiB)-batch rebuild of 1 GiB leaves
 # about a hundred. 5 ints and a name each: some 100 KiB a span at most.
@@ -272,11 +300,11 @@ _probe_ring: deque = deque(maxlen=DEFAULT_RING)
 _max_ring_spans = DEFAULT_RING_SPANS
 _slow_op_s = 0.0
 
-# Per-(op, stage) exponentially-weighted moving averages of stage
-# seconds (armed only — fed by Span.add_stage). These ride volume-server
-# heartbeats to the master as part of the telemetry plane, giving the
-# fleet a "where does this host's op time go" signal without shipping
-# whole traces.
+# Per-(op, stage) exponentially-weighted moving averages of the seconds
+# of the two DEVICE_STAGES (armed only, fed by Span._record). They ride
+# volume-server heartbeats to the master, where ec/placement.py sums them
+# into a node's `ec_stage_ewma_s`; no other stage has a reader, so no
+# other stage entry takes the process-wide lock.
 EWMA_ALPHA = 0.2
 _ewma_lock = threading.Lock()
 _stage_ewma: dict[tuple[str, str], float] = {}
@@ -343,6 +371,7 @@ class _StageTimer:
     __slots__ = (
         "span", "name", "chip", "seconds", "_dropped", "_t0", "_c0",
         "_ann", "_token", "_part", "_part_t0", "_part_c0", "_part_ann",
+        "ended_ns",
     )
 
     def __init__(self, span: "Span", name: str, chip: str):
@@ -352,9 +381,19 @@ class _StageTimer:
         self.seconds = None
         self._dropped = False
         self._part = None
+        self.ended_ns = 0  # the clock reading of __exit__
 
     def drop(self) -> None:
         self._dropped = True
+
+    @property
+    def began_ns(self) -> int:
+        """The clock reading at which the open stage began."""
+        return self._t0
+
+    @property
+    def began_cpu_ns(self) -> int:
+        return self._c0
 
     def __enter__(self):
         self._token = _open_stage.set(self)
@@ -380,19 +419,34 @@ class _StageTimer:
             self._part_c0 = cpu_ns
             self._part_ann = _annotate(self.span.op, part, self.span.trace_id)
 
-    def __exit__(self, *exc):
-        t1 = time.perf_counter_ns()
-        cpu1 = time.thread_time_ns()
+    def _close(self, t1: int, cpu1: int) -> None:
+        """End the stage (and the part open under it) at these clock
+        readings."""
         if self._part is not None:
             self._lap(None, t1, cpu1)
         if self._ann is not None:
             self._ann.__exit__(None, None, None)
-        _open_stage.reset(self._token)
         if not self._dropped:
             self.span._record(
                 self.name, self._t0, t1, cpu1 - self._c0, self.chip,
                 self.seconds,
             )
+
+    def _turn(self, name: str, now_ns: int, cpu_ns: int) -> None:
+        """End the stage at these clock readings and begin `name` at the
+        same ones, on the same span: the stages of one entry lie end to
+        end."""
+        self._close(now_ns, cpu_ns)
+        self.name = name
+        self.seconds = None
+        self._t0 = now_ns
+        self._c0 = cpu_ns
+        self._ann = _annotate(self.span.op, name, self.span.trace_id)
+
+    def __exit__(self, *exc):
+        self.ended_ns = time.perf_counter_ns()
+        self._close(self.ended_ns, time.thread_time_ns())
+        _open_stage.reset(self._token)
         return False
 
 
@@ -485,7 +539,10 @@ class Span:
             local_root=False,
         )
         with self._lock:
-            self.children.append(c)
+            # a child begun after its parent finished (a fetch thread that
+            # got going late) is timed and kept out of the closed tree
+            if not self._finished:
+                self.children.append(c)
         return c
 
     def backdate(self, start_ns: int, cpu_ns: int) -> None:
@@ -506,6 +563,11 @@ class Span:
             seconds = 0.0
         thread = threading.current_thread().name
         with self._lock:
+            if self._finished:
+                # closed under its thread (finish() by the parent of a
+                # fetch that nobody waits for): the span keeps the length
+                # it was given, and no stage may end after it
+                return
             acc = self.stages.get(stage)
             if acc is None:
                 self.stages[stage] = [seconds, 1, chip, max(cpu_ns, 0)]
@@ -522,6 +584,8 @@ class Span:
         if stage in SUB_STAGES:
             return  # inside its parent: the histogram's sum must not double
         _stage_seconds.observe(seconds, op=self.op, stage=stage, chip=chip)
+        if stage not in DEVICE_STAGES:
+            return  # placement reads these two, and nobody any other
         with _ewma_lock:
             key = (self.op, stage)
             prev = _stage_ewma.get(key)
@@ -560,14 +624,20 @@ class Span:
 
     # --------------------------------------------------------- lifecycle
 
-    def finish(self, end_ns: int = 0) -> None:
+    def finish(self, end_ns: int = 0, **attrs) -> bool:
         """`end_ns`: the span ended at that clock reading, before it
         could be closed (backdate()'s twin: the wait probes close an
-        interval after they have summed it up)."""
+        interval after they have summed it up). `attrs` are the closing
+        call's last word on the span. -> whether THIS call closed it: a
+        span that two threads may close (a fetch that outlives the
+        reconstruction it was started for) takes the first one's end
+        and attributes, whole."""
         with self._lock:
             if self._finished:
-                return
+                return False
             self._finished = True
+            if attrs:
+                self.attrs.update(attrs)
             self.end_ns = end_ns or time.perf_counter_ns()
             self.duration_s = (self.end_ns - self.start_ns) / 1e9
             if threading.get_ident() == self._ident:
@@ -576,6 +646,7 @@ class Span:
             self._ann.__exit__(None, None, None)
         if self._local_root:
             _complete_root(self)
+        return True
 
     # ------------------------------------------------------------ export
 
@@ -803,8 +874,9 @@ def reset() -> None:
 
 
 def stage_ewmas() -> dict[str, float]:
-    """Per-``op/stage`` EWMA of stage seconds (armed runs only) — the
-    heartbeat telemetry payload."""
+    """Per-``op/stage`` EWMA of the seconds of ``h2d_dispatch`` and
+    ``device_drain`` entries (armed runs only) — the heartbeat
+    telemetry payload, all of which ec/placement.py reads."""
     with _ewma_lock:
         return {f"{op}/{st}": v for (op, st), v in _stage_ewma.items()}
 
@@ -938,6 +1010,26 @@ def lap(part: str) -> None:
         parent._lap(name, time.perf_counter_ns(), time.thread_time_ns())
 
 
+def turn(name: str) -> None:
+    """The stage that the calling thread has open ends here, and stage
+    `name` begins on the same span at the same clock readings: the
+    steps of one read from a peer (:data:`TURN_STAGES`) lie end to end
+    on its ``ec.peer_read`` span though the code of each step is handed
+    no span. Nothing when disarmed (one module-bool check), where no
+    such stage is open (the same client calls under a rebuild's
+    ``peer_fetch``), where `name` is open already, and for a `name`
+    that is no such stage."""
+    if not armed:
+        return
+    timer = _open_stage.get()
+    if (
+        timer is None or timer.name == name
+        or timer.name not in TURN_STAGES or name not in TURN_STAGES
+    ):
+        return
+    timer._turn(name, time.perf_counter_ns(), time.thread_time_ns())
+
+
 def count(name: str, n: int) -> None:
     """Add `n` to the seam counter `name` (``h2d_bytes``, ``d2h_bytes``,
     ``d2h_dense_bytes``, ``read_bytes``, ``read_reused_bytes``,
@@ -966,6 +1058,8 @@ def book_return(stamp_ns: int) -> None:
     if span is None or stamp_ns <= 0:
         return
     with span._lock:
+        if span._finished:
+            return  # closed under its thread: see Span._record
         attrs = span.attrs
         attrs["interp_wait_ns"] = (
             attrs.get("interp_wait_ns", 0) + max(now - stamp_ns, 0)

@@ -449,8 +449,10 @@ def test_a_healthy_gets_reads_from_peers_are_counted_at_the_reader_and_seen_at_t
         assert trace.traces() == []
         return
     (root,) = spread.roots(vol.fid(i))
-    assert root["children"] == []  # nothing was recovered
+    # nothing was recovered: the root's children are its reads from peers
+    assert {c["op"] for c in root["children"]} == {"ec.peer_read"}
     st, attrs = root["stages"], root["attrs"]
+    assert len(root["children"]) == attrs["peer_reads"]
     assert attrs["peer_reads"] == got["reads"] == st["peer_read"]["count"]
     assert attrs["peer_read_bytes"] == got["bytes"]
     # the reader's wait lies in the part `.peer` of `volume.read`, and is

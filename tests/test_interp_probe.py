@@ -154,7 +154,10 @@ def test_disarmed_there_is_no_probe_thread_and_armed_there_is_one(disarmed):
     finally:
         trace.configure(enabled=False)
     assert not interp_probe.running()
-    assert sorted(t.name for t in threading.enumerate()) == before
+    # no thread more than before (an earlier file's servers may still be
+    # winding theirs down: fewer is no fault of the probe's)
+    after = [t.name for t in threading.enumerate()]
+    assert interp_probe.THREAD_NAME not in after and set(after) <= set(before)
     head = native._lib.sn_probe_head()
     time.sleep(0.02)
     assert native._lib.sn_probe_head() == head
@@ -164,6 +167,10 @@ def test_disarmed_there_is_no_probe_thread_and_armed_there_is_one(disarmed):
 
 
 def test_probe_spans_close_every_interval_with_samples_and_cpu_by_class(armed):
+    """Nothing here assumes an idle machine: the spinners run until five
+    intervals have closed beside them, however long a starved probe takes
+    for that, and their CPU is held against the process's own in those
+    intervals, not against wall time."""
     stop = threading.Event()
 
     def spin() -> None:
@@ -175,11 +182,14 @@ def test_probe_spans_close_every_interval_with_samples_and_cpu_by_class(armed):
     t_begin = time.perf_counter_ns()
     unnamed.start()
     worker.start()
-    time.sleep(0.65)
+    t_spinning = time.perf_counter_ns()
+    # an interval that began before both spun, then five beside them
+    wait_for_spans(len(probe_docs()) + 6, timeout=30.0)
+    t_stopping = time.perf_counter_ns()
     stop.set()
     unnamed.join()
     worker.join()
-    docs = wait_for_spans(5)
+    docs = probe_docs()
     wall_ns = time.perf_counter_ns() - t_begin
     # one span an interval, end to end
     assert 5 <= len(docs) <= wall_ns // interp_probe.INTERVAL_NS + 1
@@ -201,20 +211,29 @@ def test_probe_spans_close_every_interval_with_samples_and_cpu_by_class(armed):
         # the Python probe's samples are the span's own interval
         assert all(d["start_ns"] <= t <= d["end_ns"] for t, _w in attrs["py_samples"])
         cpu = attrs["cpu_ns"]
-        assert set(cpu) >= {"probe", "other_python", "http_workers", "native"}
+        assert set(cpu) >= {"probe", "other_python", "native"}
         assert all(v >= 0 for v in cpu.values())
         assert sum(cpu.values()) >= attrs["process_cpu_ns"] - 30 * MS
+    # the intervals that lie wholly beside both spinners: a class has CPU
+    # booked only while a thread of it lives
+    spun = [
+        d for d in docs
+        if d["start_ns"] >= t_spinning and d["end_ns"] <= t_stopping
+    ]
+    assert len(spun) >= 4
+    assert all("http_workers" in d["attrs"]["cpu_ns"] for d in spun)
     total = {
-        cls: sum(d["attrs"]["cpu_ns"].get(cls, 0) for d in docs)
+        cls: sum(d["attrs"]["cpu_ns"].get(cls, 0) for d in spun)
         for cls in ("probe", "other_python", "http_workers", "pipe_reader")
     }
-    # two spinners share one interpreter: each burns its share of it
-    assert total["other_python"] > 100 * MS, total
-    assert total["http_workers"] > 100 * MS, total
+    process = sum(d["attrs"]["process_cpu_ns"] for d in spun)
+    # two spinners share one interpreter: each burns its share of it,
+    # of whatever the machine gave the process
+    assert total["other_python"] > 0.25 * process, (total, process)
+    assert total["http_workers"] > 0.25 * process, (total, process)
     assert total["pipe_reader"] == 0
     assert 0 < total["probe"] < total["other_python"]
-    process = sum(d["attrs"]["process_cpu_ns"] for d in docs)
-    assert process >= total["other_python"] + total["http_workers"]
+    assert process >= total["other_python"] + total["http_workers"] - 30 * MS
 
 
 def test_other_python_grows_only_while_the_unnamed_thread_spins(armed):

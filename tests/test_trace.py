@@ -649,6 +649,7 @@ def test_disarmed_no_new_site_reads_a_clock_or_allocates(disarmed, monkeypatch, 
     assert not trace.armed
     noop = trace.stage(None, "disk_read")
     assert trace.lap("put") is None and trace.lap("ready") is None
+    assert trace.turn("request_rtt") is None
     with trace.stage(None, "admission_wait") as timer:
         timer.seconds = 1.0  # accepted, ignored, nothing stored
         timer.drop()
@@ -772,6 +773,9 @@ _STAGE_PATTERNS = [
     re.compile(r'add_stage\(\s*"([a-z0-9_.]+)"'),
     # trace.add_stage(span, "name", secs)
     re.compile(r'add_stage\(\s*[A-Za-z_][\w.]*\s*,\s*"([a-z0-9_.]+)"'),
+    # trace.turn("name"), span.add_interval("name", t0, t1)
+    re.compile(r'\bturn\(\s*"([a-z0-9_.]+)"'),
+    re.compile(r'add_interval\(\s*"([a-z0-9_.]+)"'),
     # pipeline stage-name kwargs
     re.compile(r'(?:read_stage|write_stage)\s*=\s*"([a-z0-9_.]+)"'),
 ]
@@ -819,6 +823,9 @@ def test_stage_name_registry_lint():
     for required in (
         "s3.auth", "filer.lookup", "chunk.fetch", "volume.read",
         "disk_read", "h2d_dispatch", "admission_wait",
+        # a read from a peer: turned to, and laid from clock readings
+        "conn_checkout", "request_rtt", "payload_land", "fetch_queue",
+        "stream.resolve", "stream.sendfile",
     ):
         assert required in found, required
 
