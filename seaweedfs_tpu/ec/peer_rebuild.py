@@ -5,9 +5,11 @@ shards are on local disk — correct, but on a balanced cluster EVERY
 holder is a subset holder, so a quarantined shard could never be
 regenerated anywhere. The reference solves this at the maintenance
 layer (ec.rebuild collects shards onto one node first); this module is
-the streaming equivalent: fetch just enough sibling shards from peer
-holders through the shard-read RPC, rebuild locally on the TPU through
-the staged/scheduled path, and publish only the regenerated targets.
+the streaming equivalent: stream just enough sibling shards from peer
+holders, batch by batch, straight into the rows of the staged/scheduled
+rebuild pipeline's batch matrices (the holder's native shard plane
+where it has one, the shard-read RPC where not), rebuild on the TPU,
+and publish only the regenerated targets.
 
 Present-but-corrupt local shards whose rot is pinned to specific 64 KiB
 leaves (v2 sidecar) are repaired at LEAF granularity first: only the
@@ -21,22 +23,25 @@ fetch/rebuild/publish path below.
 Correctness envelope (the same verify-and-exclude rules as the local
 rebuild, extended across the wire):
 
-- every fetched stream is verified against the .ecsum sidecar at the
-  sidecar's own granularity WHILE it streams — a peer serving corrupt
-  bytes is excluded (after one immediate re-read to rule out transient
-  wire corruption) and the plan re-routes to another holder or another
-  shard; transient failures (RPC errors, torn/short streams) retry
-  under utils/retry.py before the holder is abandoned;
+- every granule from a peer is verified against the .ecsum sidecar at
+  the sidecar's own granularity as it lands, before it reaches
+  Reed-Solomon — a peer serving corrupt bytes is excluded (after one
+  immediate re-read to rule out transient wire corruption) and the plan
+  re-routes to another holder or another shard, the heal's batches
+  starting over (nothing is published before the last one); transient
+  failures (RPC errors, torn/short streams) retry under utils/retry.py
+  before the holder is abandoned;
 - fewer than k verified sources reachable = clean refusal: staging is
   wiped, nothing is published, the canonical files are untouched;
-- fetched sources live ONLY in a staging directory next to the volume
-  (hard links for verified-good local shards, downloads for the rest)
-  so the local server never holds publishable copies of shards the
-  master placed on peers — no duplicate minting, even across crashes;
+- no source byte is written to disk: local sources are read where they
+  lie, the peers' rows land in the batch matrix (`PeerSources`), so the
+  local server never holds copies of shards the master placed on peers
+  — no duplicate minting, even across crashes;
 - regenerated targets publish with the local rebuild's own machinery
-  (temp + fsync + sidecar re-verify + atomic rename inside staging,
-  then one rename per target into the canonical directory), so a
-  re-run after any crash window converges idempotently.
+  (temp + fsync + sidecar re-verify + atomic rename inside a staging
+  directory next to the volume, then one rename per target into the
+  canonical directory), so a re-run after any crash window converges
+  idempotently.
 
 The actual byte transport is injected (`fetch`), so the core is
 testable without servers; server/volume_server.py wires it to the
@@ -47,9 +52,10 @@ regenerated shards the local server does not own to planned holders
 
 from __future__ import annotations
 
+import contextvars
 import os
 import shutil
-from concurrent.futures import ThreadPoolExecutor
+from concurrent.futures import ThreadPoolExecutor, wait
 from dataclasses import dataclass, field
 
 import numpy as np
@@ -63,7 +69,8 @@ from ..utils.glog import logger
 from ..utils.retry import RetryError, RetryPolicy, retry_call
 from .bitrot import BitrotError, BitrotProtection
 from .context import ECContext, ECError
-from .rebuild import rebuild_ec_files
+from .encoder import DEFAULT_BATCH
+from .rebuild import _attempt_rebuild
 from .repair_journal import (
     apply_leaf_repair,
     leaf_verdict,
@@ -86,10 +93,9 @@ DEFAULT_FETCH_POLICY = RetryPolicy(
 # per chunk (bounded memory, early corrupt-peer detection).
 FETCH_CHUNK = 1 << 20
 
-# The native ingress lands in pooled reusable buffers, so it can afford
-# a wider window per request: fewer header round trips and Python-level
-# chunk turnarounds on the zero-copy path (memory cost is one pooled
-# matrix per in-flight stream, reused forever).
+# The native ingress lands straight in the batch matrix's row, so it
+# can afford a wider window per request: fewer header round trips and
+# Python-level chunk turnarounds on the zero-copy path.
 NATIVE_FETCH_CHUNK = 4 << 20
 
 STAGING_PREFIX = ".peerfetch-"
@@ -127,9 +133,9 @@ class PeerRebuildReport:
 
     rebuilt: list[int] = field(default_factory=list)
     fetched: dict[int, str] = field(default_factory=dict)  # sid -> peer
-    # Which byte plane carried each fetched stream ("native" = zero-copy
-    # net-plane ingress straight into pooled aligned buffers, "python" =
-    # the bit-identical gRPC/bytes fallback).
+    # Which byte plane carried each fetched source ("native" = zero-copy
+    # net-plane ingress straight into the batch matrix, "python" = the
+    # bit-identical gRPC/bytes fallback).
     fetched_plane: dict[int, str] = field(default_factory=dict)
     local_sources: list[int] = field(default_factory=list)
     corrupt_local: list[int] = field(default_factory=list)
@@ -186,230 +192,285 @@ def _verify_local(
     return [i for i in present if i not in corrupt], corrupt
 
 
-def _fetch_shard_verified(
-    sbase: str,
-    peer: str,
-    sid: int,
-    prot: BitrotProtection,
-    ctx: ECContext,
-    fetch,
-    policy: RetryPolicy,
-    fetch_into=None,
-) -> str:
-    """Stream one whole shard from `peer` into staging, verifying the
-    sidecar CRC per granule as the bytes land, and return the plane
-    that carried it ("native" | "python"). Raises PeerCorruptError when
-    a granule mismatches even after one immediate re-read (the
-    transient-wire-corruption escape), PeerFetchTransient/RetryError
-    when the peer stays unreachable. Publishes atomically INSIDE
-    staging; a partial download never looks like a shard.
+class PeerSourceError(Exception):
+    """Rows of a batch did not land verified: `failures` lists
+    (shard, peer, cause) for each, where cause is PeerCorruptError (the
+    holder serves rot) or a transport failure that outlived its retries
+    (PeerFetchTransient / RetryError)."""
 
-    The native plane (`fetch_into` provided, native_io enabled, fault
-    registry disarmed) lands each chunk DIRECTLY in a pooled aligned
-    buffer with the granule CRC fused into the copy-in; the Python
-    plane is the bit-identical `fetch`-based fallback, which also
-    carries every stream whenever chaos is armed (byte-mutating fault
-    points need materialized bytes)."""
-    if fetch_into is not None:
+    def __init__(self, failures: list[tuple[int, str, BaseException]]):
+        super().__init__(
+            "; ".join(f"shard {s} <- {p}: {e}" for s, p, e in failures)
+        )
+        self.failures = failures
+
+
+class _Landing:
+    """One batch's remote rows on their way (PeerSources.start)."""
+
+    def __init__(self, sources: "PeerSources", futures: dict, local_bytes: int):
+        self._sources = sources
+        self._futures = futures
+        self._local_bytes = local_bytes
+
+    def join(self) -> None:
+        """Wait for every row, landed or failed: the wait for the peers
+        beyond the local read is the heal's `source_land`."""
+        with trace.stage(self._sources.span, "source_land"):
+            wait(self._futures.values())
+
+    def check(self) -> None:
+        failures = [
+            (sid, self._sources.plan[sid], f.exception())
+            for sid, f in self._futures.items()
+            if f.exception() is not None
+        ]
+        if failures:
+            raise PeerSourceError(failures)
+        self._sources.booked("local", self._local_bytes)
+
+
+class PeerSources:
+    """The sources of one heal that are read from peers, streamed batch
+    by batch into the rows of the pipeline's batch matrix (the `remote`
+    of rebuild._attempt_rebuild): `plan` maps each source shard to the
+    holder it is read from. Every granule is verified against the
+    `.ecsum` sidecar as it lands; a mismatch is re-read once at granule
+    width (wire noise) and a repeat is the holder serving rot.
+
+    A row rides the holder's native shard plane (`fetch_into`: straight
+    into the matrix row, the granule CRCs rolled during the copy-in)
+    where the native plane is enabled, the fault registry is disarmed
+    and the holder has a plane; the `fetch` stream (bytes, verified,
+    copied into the row) where not. Nothing is written to disk.
+
+    Armed, each source has an `ec.peer_fetch` span (stages `peer_fetch`,
+    `crc_verify`) that its landing threads hold current, so the holders'
+    `rpc.ec_shard_read` roots join the heal's trace; the heal's span gets
+    `source_land` and the `source_bytes_*` attributes, and
+    `sw_ec_peer_rebuild_source_bytes_total{plane}` counts always."""
+
+    def __init__(
+        self, plan: dict[int, str], n_local: int, prot: BitrotProtection,
+        fetch, fetch_into, policy: RetryPolicy, span,
+    ):
         from . import native_io
 
-        if native_io.enabled() and not faults.active():
-            try:
-                _fetch_shard_stream_native(
-                    sbase, peer, sid, prot, ctx, fetch, fetch_into, policy
-                )
-                return "native"
-            except PeerPlaneUnavailable as e:
-                log.info(
-                    "peer %s has no native shard plane (%s); falling back "
-                    "to the python fetch", peer, e,
-                )
-    _fetch_shard_stream_python(sbase, peer, sid, prot, ctx, fetch, policy)
-    return "python"
+        self.plan = dict(plan)
+        self.sids = sorted(plan)
+        self.n_local = n_local
+        self.prot = prot
+        self.fetch = fetch
+        self.fetch_into = fetch_into
+        self.policy = policy
+        self.span = span
+        self.native = (
+            fetch_into is not None and native_io.enabled()
+            and not faults.active()
+        )
+        self.planes = {
+            sid: ("native" if self.native else "python") for sid in self.sids
+        }
+        self._no_plane: set[str] = set()
+        self._context = contextvars.copy_context()
+        self._spans = {
+            sid: trace.start(
+                "ec.peer_fetch", name=f"shard {sid} <- {peer}", parent=span,
+                peer=peer, shard=sid, bytes=prot.shard_sizes[sid],
+            )
+            for sid, peer in self.plan.items()
+        }
+        self._pool = ThreadPoolExecutor(
+            max_workers=max(len(plan), 1), thread_name_prefix="ec-peer-source"
+        )
 
+    def close(self) -> None:
+        self._pool.shutdown(wait=True)
+        for sp in self._spans.values():
+            trace.finish(sp)
 
-def _fetch_shard_stream_native(
-    sbase: str,
-    peer: str,
-    sid: int,
-    prot: BitrotProtection,
-    ctx: ECContext,
-    fetch,
-    fetch_into,
-    policy: RetryPolicy,
-) -> None:
-    """Native ingress: `fetch_into(peer, sid, off, size, dst, granule)`
-    lands each granule-aligned chunk straight into a pooled 4096-aligned
-    buffer and returns the granule CRCs rolled DURING the copy-in, so
-    the verify-and-exclude pass below compares integers against the
-    sidecar instead of re-reading bytes. A mismatched granule gets one
-    immediate byte-level re-read through `fetch` (the transient-wire-
-    corruption escape); a repeat mismatch excludes the holder. The
-    staging file is written with raw unbuffered I/O straight from the
-    landing buffer — socket to matrix to disk, one userspace copy
-    total."""
-    gsize, gcrcs = prot.verify_granularity(sid)
-    size = prot.shard_sizes[sid]
-    chunk = max(NATIVE_FETCH_CHUNK - NATIVE_FETCH_CHUNK % gsize, gsize)
-    dest = sbase + ctx.to_ext(sid)
-    tmp = dest + ".fetching"
-    from .native_io import landing_pool
+    def booked(self, plane: str, n: int) -> None:
+        if n <= 0:
+            return
+        M.ec_peer_rebuild_source_bytes_total.inc(n, plane=plane)
+        if self.span is not None:
+            self.span.count(_SOURCE_ATTR[plane], n)
 
-    pool = landing_pool()
-    buf = pool.get(chunk)
-    sp = trace.start(
-        "ec.peer_fetch", name=f"shard {sid} <- {peer}",
-        peer=peer, shard=sid, bytes=size, plane="native",
-    )
-    try:
-        with open(tmp, "wb", buffering=0) as f:
-            off = 0
-            gi = 0
-            while off < size:
-                n = min(chunk, size - off)
-                row = buf[0, :n]
+    def start(self, off: int, width: int, rows: dict) -> _Landing:
+        futures = {
+            sid: self._pool.submit(
+                self._context.copy().run, self._land, sid, off, rows[sid]
+            )
+            for sid in self.sids
+        }
+        return _Landing(self, futures, self.n_local * width)
 
-                def attempt(off=off, n=n, row=row):
-                    return fetch_into(peer, sid, off, n, row, gsize)
-
-                with trace.stage(sp, "peer_fetch"):
-                    crcs = retry_call(
-                        attempt, policy, retry_on=(PeerFetchTransient,),
-                        describe=f"peer fetch {peer} shard {sid}",
+    def _land(self, sid: int, off: int, row: np.ndarray) -> None:
+        peer = self.plan[sid]
+        sp = self._spans.get(sid)
+        with trace.activate(sp):
+            if self.native and peer not in self._no_plane:
+                try:
+                    self._land_native(sid, peer, off, row, sp)
+                    return
+                except PeerPlaneUnavailable as e:
+                    log.info(
+                        "peer %s has no native shard plane (%s); falling "
+                        "back to the python fetch", peer, e,
                     )
-                with trace.stage(sp, "crc_verify"):
-                    ngr = (n + gsize - 1) // gsize
-                    if crcs is None or len(crcs) != ngr:
-                        raise PeerFetchTransient(
-                            f"native ingress returned {0 if crcs is None else len(crcs)} "
-                            f"granule CRCs for {ngr} granules"
-                        )
-                    for j in range(ngr):
-                        if gi + j < len(gcrcs) and int(crcs[j]) == gcrcs[gi + j]:
-                            continue
-                        # one immediate byte-level re-read of ONLY this
-                        # granule rules out transient wire corruption; a
-                        # repeat mismatch is the peer serving rot
-                        lo = j * gsize
-                        glen = min(gsize, n - lo)
+                    self._no_plane.add(peer)
+            self.planes[sid] = "python"
+            self._land_python(sid, peer, off, row, sp)
 
-                        def reread(off=off, lo=lo, glen=glen):
-                            return fetch(peer, sid, off + lo, glen)
+    def _reread(self, peer: str, sid: int, at: int, n: int, gi: int, sp) -> bytes:
+        """One immediate byte-level re-read of ONE granule (the
+        transient-wire-corruption escape); a repeat mismatch is the peer
+        serving rot."""
+        _gsize, gcrcs = self.prot.verify_granularity(sid)
+        g2 = self._get(peer, sid, at, n, sp)
+        if gi >= len(gcrcs) or crc32c(g2) != gcrcs[gi]:
+            raise PeerCorruptError(peer, sid, gi)
+        return g2
 
-                        g2 = retry_call(
-                            reread, policy, retry_on=(PeerFetchTransient,),
-                            describe=f"peer fetch {peer} shard {sid}",
-                        )
-                        if gi + j >= len(gcrcs) or crc32c(g2) != gcrcs[gi + j]:
-                            raise PeerCorruptError(peer, sid, gi + j)
-                        row[lo : lo + glen] = np.frombuffer(g2, dtype=np.uint8)
-                    gi += ngr
-                with trace.stage(sp, "write_sink"):
-                    mv = memoryview(row)
-                    while mv:
-                        mv = mv[f.write(mv):]
-                off += n
-            with trace.stage(sp, "fsync_publish"):
-                os.fsync(f.fileno())
-        os.replace(tmp, dest)
-    finally:
-        pool.put(buf)
-        if os.path.exists(tmp):
-            os.unlink(tmp)
-        trace.finish(sp)
+    def _land_native(self, sid, peer, off, row, sp) -> None:
+        gsize, gcrcs = self.prot.verify_granularity(sid)
+        chunk = max(NATIVE_FETCH_CHUNK - NATIVE_FETCH_CHUNK % gsize, gsize)
+        pos = 0
+        while pos < len(row):
+            n = min(chunk, len(row) - pos)
+            at = off + pos
+            dst = row[pos : pos + n]
 
+            def attempt(at=at, n=n, dst=dst):
+                return self.fetch_into(peer, sid, at, n, dst, gsize)
 
-def _fetch_shard_stream_python(
-    sbase: str,
-    peer: str,
-    sid: int,
-    prot: BitrotProtection,
-    ctx: ECContext,
-    fetch,
-    policy: RetryPolicy,
-) -> None:
-    """Python-plane whole-shard stream (the PR 6 byte path, unchanged):
-    `fetch` materializes bytes, the granule CRC is rolled over them as
-    they land, and byte-mutating chaos points apply at the seams."""
-    gsize, gcrcs = prot.verify_granularity(sid)
-    size = prot.shard_sizes[sid]
-    chunk = max(FETCH_CHUNK - FETCH_CHUNK % gsize, gsize)
-    dest = sbase + ctx.to_ext(sid)
-    tmp = dest + ".fetching"
-    # Child span per fetched shard stream (parent: the ec.peer_rebuild
-    # root active in this thread) — wire time vs CRC time per peer.
-    sp = trace.start(
-        "ec.peer_fetch", name=f"shard {sid} <- {peer}",
-        peer=peer, shard=sid, bytes=size,
-    )
-
-    def get(off: int, n: int) -> bytes:
-        def attempt() -> bytes:
-            try:
-                # Named client-side chaos point: a raised IOError is a
-                # transient fetch failure; a mutate corrupts the stream
-                # the way a rotten peer (or a bad NIC) would, which the
-                # granule CRC below must catch.
-                faults.fire(
-                    "ec.peer_fetch.read", peer=peer, shard=sid, offset=off
+            with trace.stage(sp, "peer_fetch"):
+                crcs = retry_call(
+                    attempt, self.policy, retry_on=(PeerFetchTransient,),
+                    describe=f"peer fetch {peer} shard {sid}",
                 )
-                data = fetch(peer, sid, off, n)
-            except (PeerFetchTransient, PeerCorruptError):
-                raise
-            except (IOError, OSError) as e:
-                raise PeerFetchTransient(str(e)) from e
-            data = faults.mutate(
-                "ec.peer_fetch.read", data, peer=peer, shard=sid, offset=off
-            )
-            if len(data) != n:
-                raise PeerFetchTransient(
-                    f"short read from {peer} for shard {sid}: "
-                    f"{len(data)}/{n} bytes at {off}"
-                )
-            return data
+            self.booked("native", n)
+            with trace.stage(sp, "crc_verify"):
+                ngr = (n + gsize - 1) // gsize
+                if crcs is None or len(crcs) != ngr:
+                    raise PeerFetchTransient(
+                        f"native ingress returned "
+                        f"{0 if crcs is None else len(crcs)} granule CRCs "
+                        f"for {ngr} granules"
+                    )
+                g0 = at // gsize
+                for j in range(ngr):
+                    if g0 + j < len(gcrcs) and int(crcs[j]) == gcrcs[g0 + j]:
+                        continue
+                    lo = j * gsize
+                    glen = min(gsize, n - lo)
+                    g2 = self._reread(peer, sid, at + lo, glen, g0 + j, sp)
+                    dst[lo : lo + glen] = np.frombuffer(g2, dtype=np.uint8)
+            pos += n
 
+    def _get(self, peer: str, sid: int, at: int, n: int, sp) -> bytes:
+        """`n` bytes of shard `sid` at `at` over the `fetch` stream."""
         with trace.stage(sp, "peer_fetch"):
-            return retry_call(
-                attempt, policy, retry_on=(PeerFetchTransient,),
-                describe=f"peer fetch {peer} shard {sid}",
-            )
+            data = _fetch_exact(self.fetch, self.policy, peer, sid, at, n)
+        self.booked("stream", n)
+        return data
 
-    try:
-        with open(tmp, "wb") as f:
-            off = 0
-            gi = 0
-            while off < size:
-                n = min(chunk, size - off)
-                data = get(off, n)
-                # granule-level sidecar verdict while the chunk is hot
-                with trace.stage(sp, "crc_verify"):
-                    for j in range(0, n, gsize):
-                        g = data[j : j + gsize]
-                        if gi >= len(gcrcs) or crc32c(g) != gcrcs[gi]:
-                            # one immediate re-read rules out transient
-                            # wire corruption; a repeat mismatch is the
-                            # PEER serving rot. Re-read ONLY this
-                            # granule's byte range: the rest of `data`
-                            # already passed its CRCs, and re-pulling
-                            # the whole chunk would cost up to
-                            # chunk/gsize times the wire traffic to
-                            # splice out one granule.
-                            g2 = get(off + j, len(g))
-                            if gi >= len(gcrcs) or crc32c(g2) != gcrcs[gi]:
-                                raise PeerCorruptError(peer, sid, gi)
-                            data = data[:j] + g2 + data[j + gsize :]
-                        gi += 1
-                with trace.stage(sp, "write_sink"):
-                    f.write(data)
-                off += n
-            with trace.stage(sp, "fsync_publish"):
-                f.flush()
-                os.fsync(f.fileno())
-        os.replace(tmp, dest)
-    finally:
-        if os.path.exists(tmp):
-            os.unlink(tmp)
-        trace.finish(sp)
+    def _land_python(self, sid, peer, off, row, sp) -> None:
+        gsize, gcrcs = self.prot.verify_granularity(sid)
+        chunk = max(FETCH_CHUNK - FETCH_CHUNK % gsize, gsize)
+        pos = 0
+        while pos < len(row):
+            n = min(chunk, len(row) - pos)
+            at = off + pos
+            data = self._get(peer, sid, at, n, sp)
+            with trace.stage(sp, "crc_verify"):
+                for j in range(0, n, gsize):
+                    gi = (at + j) // gsize
+                    g = data[j : j + gsize]
+                    if gi >= len(gcrcs) or crc32c(g) != gcrcs[gi]:
+                        # re-read ONLY this granule's byte range: the rest
+                        # of the chunk already passed its CRCs
+                        g2 = self._reread(peer, sid, at + j, len(g), gi, sp)
+                        data = data[:j] + g2 + data[j + gsize :]
+            row[pos : pos + n] = np.frombuffer(data, dtype=np.uint8)
+            pos += n
+
+
+# span attribute of the heal (`ec.peer_rebuild`) for each source plane
+_SOURCE_ATTR = {
+    "native": "source_bytes_plane",
+    "stream": "source_bytes_stream",
+    "local": "source_bytes_local",
+}
+
+
+def _plan_sources(
+    want_more: int, candidates: list[int], holders: dict[int, list[str]],
+    excluded: set[str], unreachable: set[tuple[str, int]],
+) -> dict[int, str] | None:
+    """The first `want_more` candidate shards (lowest ids first) that
+    some holder not excluded and not found unreachable for it serves,
+    each with that holder; None where fewer are left."""
+    plan: dict[int, str] = {}
+    for sid in candidates:
+        if len(plan) >= want_more:
+            break
+        peer = next(
+            (p for p in holders[sid]
+             if p not in excluded and (p, sid) not in unreachable),
+            None,
+        )
+        if peer is not None:
+            plan[sid] = peer
+    return plan if len(plan) >= want_more else None
+
+
+def _staged_source_bytes(sdir: str, sbase: str, ctx: ECContext,
+                         targets: list[int]) -> int:
+    """Bytes under the staging directory that are not a target's: what
+    a heal wrote of its sources to disk (0: they went to the chip)."""
+    outs = {
+        os.path.basename(sbase + ctx.to_ext(i)) + suffix
+        for i in targets for suffix in ("", ".rebuilding")
+    }
+    total = 0
+    with os.scandir(sdir) as it:
+        for e in it:
+            if e.name not in outs and e.is_file(follow_symlinks=False):
+                total += e.stat(follow_symlinks=False).st_size
+    return total
+
+
+def _fetch_exact(fetch, policy: RetryPolicy, peer: str, sid: int, at: int,
+                 n: int) -> bytes:
+    """Exactly `n` bytes of shard `sid` at `at` from `peer` over the
+    `fetch` stream, transient failures retried under `policy`, with the
+    client-side chaos point: a raised IOError is a transient fetch
+    failure; a mutate corrupts the stream the way a rotten peer (or a
+    bad NIC) would, which the caller's granule CRC must catch."""
+
+    def attempt() -> bytes:
+        try:
+            faults.fire("ec.peer_fetch.read", peer=peer, shard=sid, offset=at)
+            data = fetch(peer, sid, at, n)
+        except (PeerFetchTransient, PeerCorruptError):
+            raise
+        except (IOError, OSError) as e:
+            raise PeerFetchTransient(str(e)) from e
+        data = faults.mutate(
+            "ec.peer_fetch.read", data, peer=peer, shard=sid, offset=at
+        )
+        if len(data) != n:
+            raise PeerFetchTransient(
+                f"short read from {peer} for shard {sid}: "
+                f"{len(data)}/{n} bytes at {at}"
+            )
+        return data
+
+    return retry_call(
+        attempt, policy, retry_on=(PeerFetchTransient,),
+        describe=f"peer fetch {peer} shard {sid}",
+    )
 
 
 def _fetch_range_verified(
@@ -425,7 +486,7 @@ def _fetch_range_verified(
 ) -> bytes:
     """Fetch ONE leaf-aligned byte range [lo, lo+size) of a sibling
     shard from `peer`, verifying every granule against the sidecar as
-    it lands — the ranged analog of `_fetch_shard_verified`. A granule
+    it lands, as `PeerSources` verifies a source's rows. A granule
     that mismatches gets one immediate re-read (transient wire
     corruption); a repeat mismatch raises PeerCorruptError so the
     caller excludes the holder. `counter[0]` accumulates the bytes
@@ -433,31 +494,8 @@ def _fetch_range_verified(
     gsize, _ = prot.verify_granularity(sid)
 
     def get(off: int, n: int) -> bytes:
-        def attempt() -> bytes:
-            try:
-                faults.fire(
-                    "ec.peer_fetch.read", peer=peer, shard=sid, offset=off
-                )
-                data = fetch(peer, sid, off, n)
-            except (PeerFetchTransient, PeerCorruptError):
-                raise
-            except (IOError, OSError) as e:
-                raise PeerFetchTransient(str(e)) from e
-            data = faults.mutate(
-                "ec.peer_fetch.read", data, peer=peer, shard=sid, offset=off
-            )
-            if len(data) != n:
-                raise PeerFetchTransient(
-                    f"short read from {peer} for shard {sid}: "
-                    f"{len(data)}/{n} bytes at {off}"
-                )
-            return data
-
         with trace.stage(sp, "repair_fetch"):
-            got = retry_call(
-                attempt, policy, retry_on=(PeerFetchTransient,),
-                describe=f"peer range fetch {peer} shard {sid}",
-            )
+            got = _fetch_exact(fetch, policy, peer, sid, off, n)
         counter[0] += len(got)
         return got
 
@@ -490,6 +528,7 @@ def rebuild_from_peers(
     priority: str = "recovery",
     policy: RetryPolicy = DEFAULT_FETCH_POLICY,
     fetch_into=None,
+    span=None,
 ) -> PeerRebuildReport:
     """Regenerate `targets` for the volume at `base`, fetching sibling
     source shards from peer holders when fewer than k verified-good
@@ -502,7 +541,7 @@ def rebuild_from_peers(
     OPTIONAL native-plane transport (ec/net_plane.make_fetch_into):
     lands the range directly in `dst` and returns the granule CRCs
     rolled during the copy-in, raises PeerPlaneUnavailable for peers
-    without the plane — whole-shard streams then ride it whenever the
+    without the plane — the sources' rows then ride it whenever the
     native plane is enabled and the fault registry is disarmed, with
     the `fetch` path as the bit-identical fallback.
     `targets=None` regenerates every shard that is not locally
@@ -514,6 +553,10 @@ def rebuild_from_peers(
     Fail-closed: no (or malformed) .ecsum refuses — peer bytes cannot
     be trusted unverified; fewer than k reachable verified sources
     refuses with nothing published and staging wiped.
+
+    `span`: the heal's `ec.peer_rebuild` span where the caller opened
+    it (and finishes it: the volume server times its distribution on
+    it); None opens one here.
     """
     ecsum = base + ".ecsum"
     if not os.path.exists(ecsum):
@@ -546,10 +589,15 @@ def rebuild_from_peers(
     # when the holder's RPC span is active in this thread): per-peer
     # fetch child spans, the nested local rebuild, and the publish
     # renames all hang off it, so one cluster heal reads as one tree.
-    sp = trace.start(
+    own = span is None
+    sp = span if not own else trace.start(
         "ec.peer_rebuild", name=os.path.basename(base), base=base,
         targets=("auto" if targets is None else sorted(targets)),
     )
+    if sp is not None:
+        for attr in _SOURCE_ATTR.values():
+            sp.count(attr, 0)
+        sp.count("source_bytes_staged", 0)
     try:
         with trace.activate(sp):
             return _rebuild_from_peers_span(
@@ -557,7 +605,8 @@ def rebuild_from_peers(
                 priority, policy, prot, ecsum, k, sp, fetch_into,
             )
     finally:
-        trace.finish(sp)
+        if own:
+            trace.finish(sp)
 
 
 def _rebuild_from_peers_span(
@@ -689,81 +738,110 @@ def _rebuild_from_peers_span(
 
     # `excluded` carries over from the ranged-repair stage: a holder
     # that served rot for a 64 KiB range serves rot, full stop.
+    unreachable: set[tuple[str, int]] = set()
+    gsize = max(prot.verify_granularity(i)[0] for i in range(ctx.total))
+    batch = max(DEFAULT_BATCH - DEFAULT_BATCH % gsize, gsize)
+    # The pipeline's stages hang off an `ec.rebuild` child, as a local
+    # rebuild's do; the wait for the peers' rows is the heal's own.
+    rsp = trace.start(
+        "ec.rebuild", name=os.path.basename(base), base=sbase,
+        present=len(good_local), missing=list(want), priority=priority,
+    )
     try:
-        # ---- assemble k verified sources: local links + peer streams --
-        sources = set(good_local)
-        candidates = sorted(
-            sid
-            for sid, peers in holders.items()
-            if peers and sid not in sources and sid not in want
-            and 0 <= sid < ctx.total
-        )
-        for sid in candidates:
-            if len(sources) >= k:
-                break
-            for peer in holders[sid]:
-                if peer in excluded:
-                    continue
-                try:
-                    plane = _fetch_shard_verified(
-                        sbase, peer, sid, prot, ctx, fetch, policy,
-                        fetch_into=fetch_into,
-                    )
-                except PeerCorruptError as e:
-                    # verify-and-exclude across the wire: this holder
-                    # serves rot; nothing it sends is trustworthy
-                    log.warning("excluding peer: %s", e)
-                    trace.event(sp, "peer_excluded", peer=peer, shard=sid)
-                    excluded.add(peer)
-                    continue
-                except (PeerFetchTransient, RetryError) as e:
-                    log.warning(
-                        "peer %s unreachable for shard %d: %s", peer, sid, e
-                    )
-                    continue
-                sources.add(sid)
-                report.fetched[sid] = peer
-                report.fetched_plane[sid] = plane
-                break
-        report.excluded_peers = sorted(excluded)
-        if len(sources) < k:
-            raise ECError(
-                f"peer-fetch rebuild for {base}: only {len(sources)} "
-                f"verified source shards reachable (local "
-                f"{sorted(good_local)}, fetched "
-                f"{sorted(report.fetched)}, excluded peers "
-                f"{sorted(excluded)}); need {k} — refusing, nothing "
-                f"published"
+        # ---- plan k verified sources, stream them through the chip ----
+        # Local sources are read where they lie; the rest stream from
+        # their holders into the batch matrix, never to disk. A holder
+        # that fails a batch (rot, or a transport that outlived its
+        # retries) is dropped from the plan and the heal starts over
+        # with another: nothing is published before every batch landed.
+        while True:
+            local = sorted(good_local)[:k]
+            candidates = sorted(
+                sid
+                for sid, peers in holders.items()
+                if peers and sid not in good_local and sid not in want
+                and 0 <= sid < ctx.total
             )
-
-        # ---- stage local sources + sidecars, rebuild, publish ---------
-        # exactly k staged inputs: linking surplus local shards would
-        # only buy extra verification reads inside the rebuild
-        for sid in sorted(good_local)[: k - len(report.fetched)]:
-            os.link(base + ctx.to_ext(sid), sbase + ctx.to_ext(sid))
-        os.link(ecsum, sbase + ".ecsum")
-        if os.path.exists(base + ".vif"):
-            os.link(base + ".vif", sbase + ".vif")
-
-        rebuilt = rebuild_ec_files(
-            sbase,
-            ctx,
-            backend=backend,
-            only_shards=want,
-            scheduler=scheduler,
-            priority=priority,
-        )
+            plan = _plan_sources(
+                k - len(local), candidates, holders, excluded, unreachable
+            )
+            report.excluded_peers = sorted(excluded)
+            if plan is None:
+                reachable = {
+                    sid for sid in candidates
+                    if any(p not in excluded and (p, sid) not in unreachable
+                           for p in holders[sid])
+                }
+                raise ECError(
+                    f"peer-fetch rebuild for {base}: only "
+                    f"{len(local) + len(reachable)} verified source shards "
+                    f"reachable (local {sorted(good_local)}, from peers "
+                    f"{sorted(reachable)}, excluded peers "
+                    f"{sorted(excluded)}); need {k} — refusing, nothing "
+                    f"published"
+                )
+            sources = None
+            if plan:
+                sources = PeerSources(
+                    plan, len(local), prot, fetch, fetch_into, policy, sp
+                )
+            try:
+                bad = _attempt_rebuild(
+                    sbase, ctx, backend, prot, local + sorted(plan), want,
+                    prot.shard_sizes[local[0] if local else min(plan)],
+                    batch, faults.active(),
+                    inline_verify=not faults.active(),
+                    priority=priority, scheduler=scheduler, span=rsp,
+                    src_base=base, remote=sources,
+                )
+            except PeerSourceError as e:
+                for sid, peer, cause in e.failures:
+                    if isinstance(cause, PeerCorruptError):
+                        # verify-and-exclude across the wire: this holder
+                        # serves rot; nothing it sends is trustworthy
+                        log.warning("excluding peer: %s", cause)
+                        trace.event(sp, "peer_excluded", peer=peer, shard=sid)
+                        excluded.add(peer)
+                    else:
+                        log.warning(
+                            "peer %s unreachable for shard %d: %s",
+                            peer, sid, cause,
+                        )
+                        unreachable.add((peer, sid))
+                continue
+            finally:
+                if sources is not None:
+                    sources.close()
+            if bad:
+                # confirmed on-disk rot in a local source: it is a
+                # target now, and the plan goes without it
+                for sid in bad:
+                    good_local.remove(sid)
+                    report.corrupt_local.append(sid)
+                report.local_sources = sorted(good_local)
+                want = sorted(set(want) | set(bad))
+                continue
+            break
+        report.fetched = dict(plan)
+        report.fetched_plane = dict(sources.planes) if sources else {}
+        report.excluded_peers = sorted(excluded)
+        if sp is not None:
+            sp.count(
+                "source_bytes_staged",
+                _staged_source_bytes(sdir, sbase, ctx, want),
+            )
 
         # Crash window: regenerated targets are durable in staging but
         # not yet at the canonical paths. A crash here (or between the
         # per-target renames below) republishes idempotently on re-run:
         # already-renamed targets verify good and drop out of `want`.
         faults.fire("ec.peer_rebuild.before_publish", base=base)
-        for sid in sorted(rebuilt):
+        for sid in want:
             os.replace(sbase + ctx.to_ext(sid), base + ctx.to_ext(sid))
             faults.fire("ec.peer_rebuild.after_publish", base=base, shard=sid)
             report.rebuilt.append(sid)
         fsync_dir(base + ".ecsum")
     finally:
+        trace.finish(rsp)
         _clear_staging(sdir)
     return report

@@ -27,6 +27,7 @@ disk copy means transient read corruption, which fails closed.
 
 from __future__ import annotations
 
+import contextlib
 import os
 from concurrent.futures import ThreadPoolExecutor
 
@@ -342,14 +343,30 @@ def _attempt_rebuild(
     priority: str = "recovery",
     scheduler=None,
     span=None,
+    src_base: str | None = None,
+    remote=None,
 ) -> list[int]:
     """One pipelined reconstruction attempt. Publishes and returns []
     on success; returns confirmed-corrupt source ids for the caller to
     exclude and retry (inline-clean sources are recorded in
     `verified_ok` so a retry never re-reads them); raises fail-closed
-    otherwise."""
+    otherwise.
+
+    `src_base` is where the local sources lie (default `base`, where the
+    targets are written and published). `remote` streams the sources
+    that are not on this disk (ec/peer_rebuild.py `PeerSources`): its
+    `sids` are source ids, and `start(off, width, rows)` begins landing
+    each one's batch in its row of the batch matrix and returns a
+    landing whose `join()` waits for the rows and `check()` raises where
+    one did not land verified. The local rows come first in the matrix,
+    so one batched read fills them; no remote byte touches the disk."""
     k = ctx.data_shards
-    fds = {i: os.open(base + ctx.to_ext(i), os.O_RDONLY) for i in src}
+    src_base = base if src_base is None else src_base
+    remote_ids = list(remote.sids) if remote is not None else []
+    local = [i for i in src if i not in remote_ids]
+    src = local + remote_ids  # the matrix's row order
+    nl = len(local)
+    fds = {i: os.open(src_base + ctx.to_ext(i), os.O_RDONLY) for i in local}
     tmp_paths = {i: base + ctx.to_ext(i) + ".rebuilding" for i in targets}
     # buffering=0: the fused native sink writes via raw fds; the Python
     # fallback writes whole >=1MiB batches where a userspace buffer
@@ -378,11 +395,35 @@ def _attempt_rebuild(
     ncrc_lists: list[list[int]] | None = None
     if inline_verify:
         if use_native:
-            ncrc_state = np.zeros(k, np.uint32)
-            ncrc_filled = np.zeros(k, np.uint64)
-            ncrc_lists = [[] for _ in range(k)]
+            ncrc_state = np.zeros(nl, np.uint32)
+            ncrc_filled = np.zeros(nl, np.uint64)
+            ncrc_lists = [[] for _ in range(nl)]
         else:
-            rollers = {i: _BlockCrcRoller(crc_block) for i in src}
+            rollers = {i: _BlockCrcRoller(crc_block) for i in local}
+
+    # With remote sources the producer times its own parts: the local
+    # read as `disk_read`, the wait for the peers' rows as the
+    # landing's `source_land`.
+    read_stage = "disk_read" if remote is None else None
+
+    def local_read():
+        if remote is None:
+            return contextlib.nullcontext()
+        return trace.stage(span, "disk_read")
+
+    @contextlib.contextmanager
+    def landing(off: int, width: int, rows: dict):
+        """The remote rows of one batch land while the block is open
+        (the local read), and have landed, verified, after it."""
+        if remote is None:
+            yield
+            return
+        land = remote.start(off, width, rows)
+        try:
+            yield
+        finally:
+            land.join()
+        land.check()
 
     if chaos:
         # PR1-faithful byte path: per-shard pread -> fault mutate ->
@@ -390,19 +431,22 @@ def _attempt_rebuild(
         def produce():
             for off in range(0, shard_size, batch_size):
                 width = min(batch_size, shard_size - off)
-                block = {
-                    i: np.frombuffer(
-                        faults.mutate(
-                            "ec.rebuild.read_shard",
-                            os.pread(fds[i], width, off),
-                            base=base, shard=i, offset=off,
-                        ),
-                        dtype=np.uint8,
-                    )
-                    for i in src
-                }
+                rows = {i: np.empty(width, np.uint8) for i in remote_ids}
+                with landing(off, width, rows), local_read():
+                    block = {
+                        i: np.frombuffer(
+                            faults.mutate(
+                                "ec.rebuild.read_shard",
+                                os.pread(fds[i], width, off),
+                                base=src_base, shard=i, offset=off,
+                            ),
+                            dtype=np.uint8,
+                        )
+                        for i in local
+                    }
                 if any(len(b) != width for b in block.values()):
                     raise ECError(f"short shard read at offset {off}")
+                block.update(rows)
                 yield off, block
 
         def transform(item):
@@ -439,53 +483,57 @@ def _attempt_rebuild(
         pool = native_io.batch_pool() if use_native else None
 
         def produce():
-            src_fds = [fds[i] for i in src]
+            src_fds = [fds[i] for i in local]
             out_crcs = out_counts = None
             if ncrc_lists is not None:
                 out_crcs = np.empty(
-                    (k, batch_size // crc_block + 2), np.uint32
+                    (nl, batch_size // crc_block + 2), np.uint32
                 )
-                out_counts = np.empty(k, np.int32)
+                out_counts = np.empty(nl, np.int32)
             for off in range(0, shard_size, batch_size):
                 width = min(batch_size, shard_size - off)
-                trace.count("read_bytes", k * width)
                 if use_native:
                     # a matrix that an earlier batch or operation of the
                     # process filled: its pages are mapped already
                     buf, held = pool.get(k, width)
+                else:
+                    buf, held = np.empty((k, width), dtype=np.uint8), False
+                rows = {i: buf[nl + j] for j, i in enumerate(remote_ids)}
+                with landing(off, width, rows), local_read():
+                    trace.count("read_bytes", k * width)
                     if held:
                         trace.count("read_reused_bytes", k * width)
-                    nxt = off + width
-                    if nxt < shard_size:
-                        nw = min(batch_size, shard_size - nxt)
-                        for fd in src_fds:
-                            native_io.prefetch(fd, nxt, nw)
-                    try:
-                        native_io.read_batch(
-                            src_fds, [off] * k, buf, pad_eof=False,
-                            granule=crc_block if ncrc_lists is not None else 0,
-                            crc_state=ncrc_state, filled_state=ncrc_filled,
-                            out_crcs=out_crcs, out_counts=out_counts,
-                        )
-                    except OSError as e:
-                        raise _SourceReadError(
-                            [src[getattr(e, "sn_row", 0)]]
-                        ) from e
-                    if ncrc_lists is not None:
-                        for row in range(k):
-                            c = int(out_counts[row])
-                            ncrc_lists[row].extend(
-                                int(x) for x in out_crcs[row, :c]
-                            )
-                else:
-                    buf = np.empty((k, width), dtype=np.uint8)
-                    for row, i in enumerate(src):
+                    if use_native and nl:
+                        nxt = off + width
+                        if nxt < shard_size:
+                            nw = min(batch_size, shard_size - nxt)
+                            for fd in src_fds:
+                                native_io.prefetch(fd, nxt, nw)
                         try:
-                            _pread_exact(fds[i], buf[row], off)
+                            native_io.read_batch(
+                                src_fds, [off] * nl, buf[:nl], pad_eof=False,
+                                granule=crc_block if ncrc_lists is not None else 0,
+                                crc_state=ncrc_state, filled_state=ncrc_filled,
+                                out_crcs=out_crcs, out_counts=out_counts,
+                            )
                         except OSError as e:
-                            raise _SourceReadError([i]) from e
-                        if rollers is not None:
-                            rollers[i].update(buf[row])
+                            raise _SourceReadError(
+                                [local[getattr(e, "sn_row", 0)]]
+                            ) from e
+                        if ncrc_lists is not None:
+                            for row in range(nl):
+                                c = int(out_counts[row])
+                                ncrc_lists[row].extend(
+                                    int(x) for x in out_crcs[row, :c]
+                                )
+                    elif not use_native:
+                        for row, i in enumerate(local):
+                            try:
+                                _pread_exact(fds[i], buf[row], off)
+                            except OSError as e:
+                                raise _SourceReadError([i]) from e
+                            if rollers is not None:
+                                rollers[i].update(buf[row])
                 yield buf, buf
 
         def consume(buf, out):
@@ -514,7 +562,7 @@ def _attempt_rebuild(
                 try:
                     still_bad = bool(
                         prot.verify_shard_file(
-                            base + ctx.to_ext(i), i, stop_early=True
+                            src_base + ctx.to_ext(i), i, stop_early=True
                         )
                     )
                 except OSError:
@@ -545,7 +593,7 @@ def _attempt_rebuild(
                 join_timeout=join_timeout,
                 describe="ec rebuild pipeline",
                 span=span,
-                stage_names=("disk_read", "reconstruct", "write_sink"),
+                stage_names=(read_stage, "reconstruct", "write_sink"),
             )
         else:
             run_staged_apply(
@@ -566,6 +614,7 @@ def _attempt_rebuild(
                 # would multiply MTTR exactly while redundancy is
                 # reduced; same source-bytes threshold as encode
                 wide=k * shard_size >= WIDE_STREAM_BYTES,
+                read_stage=read_stage,
             )
     except _SourceReadError as e:
         _cleanup_temps()
@@ -587,19 +636,20 @@ def _attempt_rebuild(
     if rollers is not None or ncrc_lists is not None:
         if ncrc_lists is not None:
             # flush partial-tail CRC state (the native roller's finish)
-            for row in range(k):
+            for row in range(nl):
                 if ncrc_filled[row]:
                     ncrc_lists[row].append(int(ncrc_state[row]))
                     ncrc_filled[row] = 0
-            got = {i: ncrc_lists[row] for row, i in enumerate(src)}
+            got = {i: ncrc_lists[row] for row, i in enumerate(local)}
         else:
-            got = {i: rollers[i].finish() for i in src}
-        suspects = [i for i in src if got[i] != prot.shard_crcs[i]]
+            got = {i: rollers[i].finish() for i in local}
+        # remote rows were verified granule by granule as they landed
+        suspects = [i for i in local if got[i] != prot.shard_crcs[i]]
         if verified_ok is not None:
             # the inline roller IS the block-CRC check _verify_full
             # performs — a retry after an exclusion must not re-read
             # the sources that just verified clean
-            verified_ok.update(i for i in src if i not in suspects)
+            verified_ok.update(i for i in local if i not in suspects)
         if suspects:
             _cleanup_temps()
             return _confirm_from_disk(suspects)

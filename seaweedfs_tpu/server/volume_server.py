@@ -1749,10 +1749,11 @@ class VolumeServer:
             M.net_bytes_copied_total.inc(len(buf), plane="python", direction="read")
             return bytes(buf)
 
-        # Native ingress (ec/net_plane.py): sibling streams land
-        # directly in pooled aligned buffers on the peer's shard byte
-        # plane (grpc addr + port offset); peers without the plane are
-        # memoized and their streams ride the gRPC fetch above.
+        # Native ingress (ec/net_plane.py), the GET reader's transport:
+        # each source's rows land straight in the rebuild's batch matrix
+        # from the peer's shard byte plane (grpc addr + port offset);
+        # peers without the plane are memoized and their rows ride the
+        # gRPC fetch above, which is also the granule re-read.
         np_client = None
         fetch_into = None
         try:
@@ -1770,44 +1771,54 @@ class VolumeServer:
             ctx.data_shards,
             ctx.parity_shards,
         )
+        # The heal's span is opened here so that the hand-off of what it
+        # regenerated (`distribute`) is timed on it too.
+        sp = trace.start(
+            "ec.peer_rebuild", name=os.path.basename(loc_base),
+            base=loc_base, targets=targets,
+        )
         try:
-            with M.request_seconds.time(server="volume", op="ec_peer_rebuild"):
-                report = rebuild_from_peers(
-                    loc_base,
-                    holders,
-                    fetch,
-                    ctx=ctx,
-                    targets=targets,
-                    backend=backend,
-                    scheduler=self.store.ec_scheduler,
-                    fetch_into=fetch_into,
+            with trace.activate(sp):
+                with M.request_seconds.time(server="volume", op="ec_peer_rebuild"):
+                    report = rebuild_from_peers(
+                        loc_base,
+                        holders,
+                        fetch,
+                        ctx=ctx,
+                        targets=targets,
+                        backend=backend,
+                        scheduler=self.store.ec_scheduler,
+                        fetch_into=fetch_into,
+                        span=sp,
+                    )
+                M.ec_ops_total.inc(
+                    op="peer_rebuild", backend=backend_name or self.store.ec_backend
                 )
+                # Locally-owned regenerated shards re-enter service: swap the
+                # mounted fds onto the fresh inodes (quarantined shards come
+                # back too) and advertise via heartbeat. legit already covers
+                # every corrupt shard this server may mount — served rot is in
+                # shard_fds, quarantined rot rides legitimate_shards(); a
+                # corrupt NON-legit file is a rotten handoff leftover, and
+                # mounting it here would advertise a holder that the distribute
+                # step below then unlinks.
+                owned = sorted(sid for sid in report.rebuilt if sid in legit)
+                if owned:
+                    ev.reopen_shards(owned)
+                    self.notify_new_ec_shards(vid, collection)
+                # Leaf-repaired shards were patched IN PLACE on the canonical
+                # inode: the serving fd stays valid, but cached reconstructions
+                # over the patched byte ranges are stale — drop exactly those.
+                for sid, ranges in report.patched_ranges.items():
+                    ev.invalidate_shard_ranges(sid, ranges)
+                with trace.stage(sp, "distribute"):
+                    distributed = self._distribute_lost_shards(
+                        vid, collection, loc_base, ctx, legit
+                    )
         finally:
+            trace.finish(sp)
             if np_client is not None:
                 np_client.close()
-        M.ec_ops_total.inc(
-            op="peer_rebuild", backend=backend_name or self.store.ec_backend
-        )
-        # Locally-owned regenerated shards re-enter service: swap the
-        # mounted fds onto the fresh inodes (quarantined shards come
-        # back too) and advertise via heartbeat. legit already covers
-        # every corrupt shard this server may mount — served rot is in
-        # shard_fds, quarantined rot rides legitimate_shards(); a
-        # corrupt NON-legit file is a rotten handoff leftover, and
-        # mounting it here would advertise a holder that the distribute
-        # step below then unlinks.
-        owned = sorted(sid for sid in report.rebuilt if sid in legit)
-        if owned:
-            ev.reopen_shards(owned)
-            self.notify_new_ec_shards(vid, collection)
-        # Leaf-repaired shards were patched IN PLACE on the canonical
-        # inode: the serving fd stays valid, but cached reconstructions
-        # over the patched byte ranges are stale — drop exactly those.
-        for sid, ranges in report.patched_ranges.items():
-            ev.invalidate_shard_ranges(sid, ranges)
-        distributed = self._distribute_lost_shards(
-            vid, collection, loc_base, ctx, legit
-        )
         return {
             "rebuilt": sorted(report.rebuilt),
             "fetched": sorted(report.fetched),

@@ -380,6 +380,14 @@ ec_plane_serves_total = REGISTRY.counter(
     "sw_ec_plane_serves_total",
     "shard ranges a shard plane served, by path", ("path",),
 )
+# Source bytes of a peer-fetch rebuild (ec/peer_rebuild.py), by where
+# they came from: native (a holder's shard plane, into the batch
+# matrix), stream (the fetch transport: VolumeEcShardRead), local (this
+# server's own shard files). None of them is written to disk.
+ec_peer_rebuild_source_bytes_total = REGISTRY.counter(
+    "sw_ec_peer_rebuild_source_bytes_total",
+    "source bytes a peer-fetch EC rebuild read, by plane", ("plane",),
+)
 ec_leaf_repairs_total = REGISTRY.counter(
     "sw_ec_leaf_repairs_total",
     "leaf-granular in-place EC shard repairs by outcome "

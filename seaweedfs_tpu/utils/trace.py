@@ -188,6 +188,10 @@ STAGES = frozenset({
     "sibling_read", "h2d_dispatch", "device_drain", "write_sink",
     "crc_verify", "verify", "reconstruct", "fsync_publish", "stream",
     "index_sort", "peer_fetch",
+    # a heal from peers (ec/peer_rebuild.py): the wait for a batch's
+    # rows from the holders beyond the local read, and the hand-off of
+    # the regenerated shards to their planned holders
+    "source_land", "distribute",
     # an EC read's wait for bytes of a shard that a peer holds
     # (ec/ec_volume.py: an interval of a needle, a reconstruction's rows)
     "peer_read",

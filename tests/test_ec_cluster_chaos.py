@@ -297,6 +297,41 @@ def test_flight_recorder_one_trace_spans_cluster_heal(cluster):
         trace.reset()
 
 
+def test_an_armed_heal_over_real_servers_times_its_sources_and_handoff(cluster):
+    """The heal's `ec.peer_rebuild` span, under the holder's RPC root:
+    the wait for the peers' rows (`source_land`), the hand-off of what
+    it regenerated (`distribute`), and the source bytes by where they
+    came from: the seven from the other server's shard plane, the three
+    from the holder's own disk, none through the staging directory."""
+    from seaweedfs_tpu.utils import trace
+
+    vid, fid, payload, holder, other, ground = split_ec_volume(cluster)
+    base = holder.service._ec_base(vid, "")
+    quarantine(holder, vid, base, 0)
+    wait_for(lambda: not cluster.locs(vid).get(0), msg="hb")
+    trace.configure(enabled=True, ring_size=512)
+    try:
+        trace.reset()
+        r = rebuild_from_peers_rpc(cluster, holder, vid)
+        root = next(
+            d for d in trace.traces() if d["op"] == "rpc.ec_shards_rebuild"
+        )
+    finally:
+        trace.configure(enabled=False)
+        trace.reset()
+    assert list(r.rebuilt_shard_ids) == [0]
+    assert open(base + ".ec00", "rb").read() == ground[0]
+    (heal,) = [c for c in root["children"] if c["op"] == "ec.peer_rebuild"]
+    assert {"verify", "source_land", "distribute"} <= set(heal["stages"])
+    size = len(ground[0])
+    attrs = heal["attrs"]
+    assert attrs["source_bytes_plane"] == 7 * size
+    assert attrs["source_bytes_stream"] == 0
+    assert attrs["source_bytes_local"] == 3 * size
+    assert attrs["source_bytes_staged"] == 0
+    assert {c["op"] for c in heal["children"]} == {"ec.peer_fetch", "ec.rebuild"}
+
+
 # ------------------------------------------- armed RPC faults (tier-1)
 
 

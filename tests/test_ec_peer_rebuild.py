@@ -8,6 +8,7 @@ tests/test_ec_cluster_chaos.py.
 from __future__ import annotations
 
 import os
+import re
 
 import numpy as np
 import pytest
@@ -308,3 +309,328 @@ def test_stale_staging_leftovers_are_swept(tmp_path):
 def test_peer_corrupt_error_carries_context():
     e = PeerCorruptError("p1", 7, 3)
     assert e.peer == "p1" and e.shard == 7 and "granule 3" in str(e)
+
+
+# ------------------------------------------ the sources stream to the chip
+
+
+def reference_shards(blobs):
+    """The plain encode of the data shards (ops/gf256.py, numpy): what
+    every regenerated shard must equal byte for byte."""
+    from seaweedfs_tpu.ops.gf256 import ReedSolomon
+
+    data = np.stack([
+        np.frombuffer(blobs[i], dtype=np.uint8) for i in range(CTX.data_shards)
+    ])
+    rs = ReedSolomon(CTX.data_shards, CTX.parity_shards)
+    full = np.concatenate([data, rs.encode(data)], axis=0)
+    return {i: full[i].tobytes() for i in range(CTX.total)}
+
+
+def landing_fetch_into(blobs, rot=None, no_plane=(), log=None):
+    """A shard plane in process: lands the range in `dst` and returns
+    the granule CRCs of what it landed, as the native ingress does.
+    `rot(peer, sid, off, chunk) -> chunk` bends what a holder sends;
+    peers in `no_plane` have no plane."""
+    from seaweedfs_tpu.ec.peer_rebuild import PeerPlaneUnavailable
+    from seaweedfs_tpu.utils.crc import crc32c
+
+    def fetch_into(peer, sid, off, size, dst, granule):
+        if peer in no_plane:
+            raise PeerPlaneUnavailable(f"{peer}: connection refused")
+        chunk = blobs[sid][off : off + size]
+        if rot is not None:
+            chunk = rot(peer, sid, off, chunk)
+        if log is not None:
+            log.append((peer, sid, off, size))
+        dst[:size] = np.frombuffer(chunk, dtype=np.uint8)
+        return np.array(
+            [crc32c(chunk[i : i + granule]) for i in range(0, size, granule)],
+            dtype=np.uint32,
+        )
+
+    return fetch_into
+
+
+@pytest.fixture
+def batches_of_two_granules(monkeypatch):
+    """A heal of two batches (two granules, then one and a ragged tail),
+    so that a source streams batch by batch."""
+    from seaweedfs_tpu.ec import peer_rebuild as pr
+
+    monkeypatch.setattr(pr, "DEFAULT_BATCH", 2 * BLOCK)
+
+
+@pytest.mark.parametrize("plane", ["native", "stream"])
+def test_streamed_heal_is_the_reference_and_stages_no_source(
+    tmp_path, batches_of_two_granules, plane
+):
+    """Both targets of a heal whose sources are two local shards and two
+    peers' come out as the reference encode, byte for byte, and the
+    staging directory never holds a source: at every request a peer
+    answers, it holds at most the targets' temporaries."""
+    base, blobs = synth(tmp_path, local=(0, 1), seed=5)
+    sdir = staging_dir(base)
+    seen: list[set] = []
+
+    def look():
+        seen.append(set(os.listdir(sdir)) if os.path.isdir(sdir) else set())
+
+    def fetch(peer, sid, off, size):
+        look()
+        return blobs[sid][off : off + size]
+
+    inner = landing_fetch_into(blobs)
+
+    def fetch_into(*a):
+        look()
+        return inner(*a)
+
+    rep = rebuild_from_peers(
+        base, ALL_PEERS, fetch, targets=[4, 5], backend=CpuBackend(CTX),
+        policy=FAST, fetch_into=fetch_into if plane == "native" else None,
+    )
+    want = reference_shards(blobs)
+    assert rep.rebuilt == [4, 5] and sorted(rep.fetched) == [2, 3]
+    for sid in (4, 5):
+        assert open(base + CTX.to_ext(sid), "rb").read() == want[sid]
+    assert set(rep.fetched_plane.values()) == {
+        "native" if plane == "native" else "python"
+    }
+    assert len(seen) >= 4  # two sources, two batches each
+    temps = {os.path.basename(base + CTX.to_ext(s)) + ".rebuilding" for s in (4, 5)}
+    assert all(names <= temps for names in seen), seen
+    for sid in (2, 3):  # nothing of a source was published either
+        assert not os.path.exists(base + CTX.to_ext(sid))
+    assert not os.path.exists(sdir)
+
+
+@pytest.mark.parametrize("plane", ["native", "stream"])
+def test_rot_mid_stream_excludes_the_holder_and_replans(
+    tmp_path, batches_of_two_granules, plane
+):
+    """A holder that serves one rotten granule in the heal's SECOND
+    batch, on either plane: the granule is re-read once, rot again, the
+    holder is excluded after a batch of its rows had landed, and the heal
+    starts over from another holder: still the reference, byte for
+    byte, and nothing of the first try left behind."""
+    base, blobs = synth(tmp_path, local=(0, 1), seed=6)
+    log: list = []
+
+    def rot(peer, sid, off, chunk):
+        lo = 2 * BLOCK + 9 - off  # a byte of the second batch
+        if peer == "rotten" and 0 <= lo < len(chunk):
+            return chunk[:lo] + bytes([chunk[lo] ^ 0x40]) + chunk[lo + 1 :]
+        return chunk
+
+    def fetch(peer, sid, off, size):
+        log.append((peer, sid, off, size))
+        return rot(peer, sid, off, blobs[sid][off : off + size])
+
+    rep = rebuild_from_peers(
+        base, {2: ["rotten", "clean"], 3: ["clean"]}, fetch,
+        targets=[5], backend=CpuBackend(CTX), policy=FAST,
+        fetch_into=(
+            landing_fetch_into(blobs, rot=rot, log=log)
+            if plane == "native" else None
+        ),
+    )
+    assert rep.rebuilt == [5] and rep.excluded_peers == ["rotten"]
+    assert rep.fetched == {2: "clean", 3: "clean"}
+    # the rotten holder answered the first batch before the second was rot
+    assert ("rotten", 2, 0, 2 * BLOCK) in log
+    assert ("rotten", 2, 2 * BLOCK, BLOCK) in log  # the one re-read
+    assert open(base + CTX.to_ext(5), "rb").read() == reference_shards(blobs)[5]
+    assert not os.path.exists(staging_dir(base))
+
+
+def test_holders_dying_mid_stream_refuse_clean(tmp_path, batches_of_two_granules):
+    """The holders of the sources a heal needs answer its first batch
+    and then die: fewer than k sources are left, and the heal refuses
+    with nothing published, nothing staged, the locals untouched."""
+    base, blobs = synth(tmp_path, local=(0, 1), seed=7)
+
+    def dying(peer, sid, off, size):
+        if off >= 2 * BLOCK:
+            raise PeerFetchTransient("connection reset by peer")
+        return blobs[sid][off : off + size]
+
+    with pytest.raises(ECError, match="refusing, nothing published"):
+        rebuild_from_peers(
+            base, {2: ["peerB"], 3: ["peerB"], 4: ["peerC"]}, dying,
+            targets=[5], backend=CpuBackend(CTX), policy=FAST,
+        )
+    assert not os.path.exists(base + CTX.to_ext(5))
+    assert not os.path.exists(staging_dir(base))
+    assert sorted(os.listdir(tmp_path)) == ["1.ec00", "1.ec01", "1.ecsum"]
+
+
+def test_a_holder_without_a_plane_is_read_over_the_stream(
+    tmp_path, batches_of_two_granules
+):
+    """One holder has a shard plane, the other none: each source rides
+    what its holder offers, both land verified, and the target is the
+    reference."""
+    base, blobs = synth(tmp_path, local=(0, 1), seed=8)
+    streamed: list = []
+
+    def fetch(peer, sid, off, size):
+        streamed.append((peer, sid))
+        return blobs[sid][off : off + size]
+
+    rep = rebuild_from_peers(
+        base, {2: ["planeless"], 3: ["withplane"]}, fetch,
+        targets=[5], backend=CpuBackend(CTX), policy=FAST,
+        fetch_into=landing_fetch_into(blobs, no_plane=("planeless",)),
+    )
+    assert rep.fetched_plane == {2: "python", 3: "native"}
+    assert {p for p, _s in streamed} == {"planeless"}
+    assert open(base + CTX.to_ext(5), "rb").read() == reference_shards(blobs)[5]
+
+
+def test_an_armed_heal_books_where_its_sources_came_from(
+    tmp_path, batches_of_two_granules
+):
+    """Armed, the heal's span holds the wait for the peers' rows
+    (`source_land`) and how many source bytes came over each plane, from
+    the local disk and to staging (none); the pipeline's stages hang off
+    its `ec.rebuild` child and each source has an `ec.peer_fetch` span.
+    Always on, `sw_ec_peer_rebuild_source_bytes_total{plane}` counts the
+    same bytes."""
+    from seaweedfs_tpu.utils import metrics as M
+    from seaweedfs_tpu.utils import trace
+
+    base, blobs = synth(tmp_path, local=(0,), seed=9)
+    before = dict(M.ec_peer_rebuild_source_bytes_total.snapshot())
+    trace.configure(enabled=True)
+    try:
+        trace.reset()
+        rep = rebuild_from_peers(
+            base, {1: ["pA"], 2: ["pA"], 3: ["pB"]},
+            serving_fetch(blobs), targets=[5], backend=CpuBackend(CTX),
+            policy=FAST,
+            fetch_into=landing_fetch_into(blobs, no_plane=("pB",)),
+        )
+        doc = next(d for d in trace.traces() if d["op"] == "ec.peer_rebuild")
+    finally:
+        trace.configure(enabled=False)
+        trace.reset()
+    assert rep.rebuilt == [5]
+    attrs = doc["attrs"]
+    assert attrs["source_bytes_plane"] == 2 * SHARD_SIZE
+    assert attrs["source_bytes_stream"] == SHARD_SIZE
+    assert attrs["source_bytes_local"] == SHARD_SIZE
+    assert attrs["source_bytes_staged"] == 0
+    assert doc["stages"]["source_land"]["count"] == 2  # one a batch
+    kids = {}
+    for ch in doc["children"]:
+        kids.setdefault(ch["op"], []).append(ch)
+    assert len(kids["ec.peer_fetch"]) == 3
+    assert all("peer_fetch" in c["stages"] for c in kids["ec.peer_fetch"])
+    (rb,) = kids["ec.rebuild"]
+    assert {"disk_read", "write_sink", "fsync_publish"} <= set(rb["stages"])
+    after = M.ec_peer_rebuild_source_bytes_total.snapshot()
+    grew = {k[0]: after[k] - before.get(k, 0) for k in after}
+    assert grew == {
+        "native": 2 * SHARD_SIZE, "stream": SHARD_SIZE, "local": SHARD_SIZE,
+    }
+
+
+@pytest.mark.parametrize("batch", [0, 1])
+def test_a_transient_plane_failure_mid_stream_is_retried_on_the_plane(
+    tmp_path, batches_of_two_granules, batch
+):
+    """A holder's plane drops the connection on the first request of one
+    batch: the request is asked again on the plane, the holder stays in
+    the plan, and the heal is the reference."""
+    base, blobs = synth(tmp_path, local=(0, 1), seed=10 + batch)
+    inner = landing_fetch_into(blobs)
+    dropped: list = []
+
+    def fetch_into(peer, sid, off, size, dst, granule):
+        if off == 2 * BLOCK * batch and (sid, off) not in dropped:
+            dropped.append((sid, off))
+            raise PeerFetchTransient("connection reset by peer")
+        return inner(peer, sid, off, size, dst, granule)
+
+    rep = rebuild_from_peers(
+        base, {2: ["pA"], 3: ["pB"]}, serving_fetch(blobs), targets=[4, 5],
+        backend=CpuBackend(CTX), policy=FAST, fetch_into=fetch_into,
+    )
+    assert sorted(dropped) == [(2, 2 * BLOCK * batch), (3, 2 * BLOCK * batch)]
+    assert rep.rebuilt == [4, 5] and not rep.excluded_peers
+    assert rep.fetched_plane == {2: "native", 3: "native"}
+    want = reference_shards(blobs)
+    for sid in (4, 5):
+        assert open(base + CTX.to_ext(sid), "rb").read() == want[sid]
+
+
+def test_a_plane_that_goes_away_mid_stream_leaves_its_rows_to_the_stream(
+    tmp_path, batches_of_two_granules
+):
+    """A holder answers the first batch on its plane, then the plane
+    refuses: the rest of that source rides the `fetch` stream, the
+    holder is not excluded, and the heal is the reference."""
+    from seaweedfs_tpu.ec.peer_rebuild import PeerPlaneUnavailable
+
+    base, blobs = synth(tmp_path, local=(0, 1), seed=12)
+    inner = landing_fetch_into(blobs)
+    streamed: list = []
+
+    def fetch_into(peer, sid, off, size, dst, granule):
+        if peer == "pA" and off > 0:
+            raise PeerPlaneUnavailable("pA: connection refused")
+        return inner(peer, sid, off, size, dst, granule)
+
+    def fetch(peer, sid, off, size):
+        streamed.append((peer, sid, off))
+        return blobs[sid][off : off + size]
+
+    rep = rebuild_from_peers(
+        base, {2: ["pA"], 3: ["pB"]}, fetch, targets=[5],
+        backend=CpuBackend(CTX), policy=FAST, fetch_into=fetch_into,
+    )
+    assert streamed == [("pA", 2, 2 * BLOCK)]
+    assert rep.fetched_plane == {2: "python", 3: "native"} and not rep.excluded_peers
+    assert open(base + CTX.to_ext(5), "rb").read() == reference_shards(blobs)[5]
+
+
+def shard_files(d):
+    return sorted(n for n in os.listdir(d) if re.fullmatch(r"1\.ec\d\d", n))
+
+
+@pytest.mark.parametrize("point, nth", [
+    ("ec.peer_rebuild.before_publish", 1),
+    ("ec.peer_rebuild.after_publish", 1),
+    ("ec.peer_rebuild.after_publish", 2),
+])
+def test_a_crash_around_publish_reruns_to_the_reference(
+    tmp_path, batches_of_two_granules, point, nth
+):
+    """A heal of two targets whose sources stream from peers dies before
+    its first publish, or after its first or its second: the canonical
+    directory holds only whole targets, never a source, and a re-run
+    converges to the reference with nothing left in staging."""
+    base, blobs = synth(tmp_path, local=(0, 1), seed=13)
+    want = reference_shards(blobs)
+    with faults.injected(point, faults.crash(), when=faults.nth_call(nth)):
+        with pytest.raises(faults.InjectedCrash):
+            rebuild_from_peers(
+                base, ALL_PEERS, serving_fetch(blobs), targets=[4, 5],
+                backend=CpuBackend(CTX), policy=FAST,
+            )
+    on_disk = shard_files(tmp_path)
+    published = {"ec.peer_rebuild.before_publish": [], "ec.peer_rebuild.after_publish": [4, 5][:nth]}[point]
+    assert on_disk == ["1.ec00", "1.ec01"] + [f"1.ec{s:02d}" for s in published]
+    for sid in published:
+        assert open(base + CTX.to_ext(sid), "rb").read() == want[sid]
+    rep = rebuild_from_peers(
+        base, ALL_PEERS, serving_fetch(blobs), targets=[4, 5],
+        backend=CpuBackend(CTX), policy=FAST,
+        fetch_into=landing_fetch_into(blobs),
+    )
+    assert rep.rebuilt == sorted(set([4, 5]) - set(published))
+    for sid in (4, 5):
+        assert open(base + CTX.to_ext(sid), "rb").read() == want[sid]
+    assert not os.path.exists(staging_dir(base))
+    assert shard_files(tmp_path) == ["1.ec00", "1.ec01", "1.ec04", "1.ec05"]
