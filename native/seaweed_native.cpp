@@ -28,6 +28,7 @@
 
 #include <fcntl.h>
 #include <pthread.h>
+#include <sys/socket.h>
 #include <sys/stat.h>
 #include <sys/uio.h>
 #include <time.h>
@@ -44,8 +45,8 @@ extern "C" {
 // ---------------------------------------------------------------------------
 // The return stamp. Every call below that does real work without the
 // interpreter (sn_batch_pread, sn_crc32c_granules, sn_sendv,
-// sn_sink_append, sn_send_file, sn_recv_into) writes CLOCK_MONOTONIC
-// through `ret_ns` as its last
+// sn_sink_append, sn_send_file, sn_recv_into, sn_plane_fetch) writes
+// CLOCK_MONOTONIC through `ret_ns` as its last
 // act: Python's time.perf_counter_ns() is the same clock, so the wrapper
 // reads how long the calling thread then waited to hold the interpreter
 // again (utils/native.py books it when the tracer is armed; a caller
@@ -1544,6 +1545,307 @@ int sn_plane_serve(void* table, int conn, int timeout_ms, int native_ok,
     rec[PR_T_SENT] = (uint64_t)mono_ns();
     rec[PR_C_SENT] = (uint64_t)thread_cpu_ns();
     return rec[PR_WHAT] = PS_SERVED;
+}
+
+}  // extern "C"
+
+// ---------------------------------------------------------------------------
+// The reader's side of shard plane reads in ONE call. ec/net_plane.py's
+// NetPlaneClient checks a connection out of its pool for each range and
+// packs each request (header and metadata, the read's own trace context
+// in it); sn_plane_fetch sends them all, then multiplexes the sockets
+// with poll(2), parsing each 9-byte answer header as it comes and landing
+// each range in a destination handed out IN THE ORDER THE ANSWERS ARRIVE
+// (at the first payload byte: a header alone takes none), lowest index
+// first: a reconstruction lists its matrix's open rows first and its
+// spare rows after them, so the first answers land where they are used
+// and a spare is written only by a range that came later than the rows'
+// own. The granule CRCs roll while a range lands, as in
+// sn_recv_into. A refusal has its message read, so its connection stays
+// in frame. The call returns once `need` ranges have landed whole, or
+// when every range has finished or gone silent for timeout_ms; what
+// already sits in the socket buffers is landed on the way out, and a
+// range still in flight leaves its connection out of frame. The
+// interpreter is not taken once between the first request and the
+// return.
+// ---------------------------------------------------------------------------
+
+namespace {
+
+// what became of a range (its record's PF_STATUS): keep utils/native.py's
+// FETCH_STATUS in step
+enum {
+    PFS_LANDED = 0,     // the whole range landed; the connection is in frame
+    PFS_REFUSED = 1,    // refused (answer status != 0), message read: in frame
+    PFS_SHORT = 2,      // answered shorter than asked (the EOF clamp)
+    PFS_BAD_FRAME = 3,  // answered longer than asked, or a refusal too long
+    PFS_TORN = 4,       // the connection failed or closed before the end
+    PFS_TIMED_OUT = 5,  // silent for timeout_ms
+    PFS_IN_FLIGHT = 6,  // not done when `need` ranges had landed
+};
+// the slots of a range's record (i64 each): keep utils/native.py's
+// FETCH_REC in step. PF_ANSWER is the header's status byte (-1 before
+// one came); the stamps are CLOCK_MONOTONIC (time.perf_counter_ns), 0
+// where not reached
+enum {
+    PF_STATUS, PF_ANSWER, PF_N, PF_GOT, PF_DST, PF_CRCS, PF_TAIL_CRC,
+    PF_MSG_LEN, PF_T_SENT, PF_T_HDR, PF_T_LAND, PF_SLOTS,
+};
+
+const uint64_t kMaxRefusal = 1u << 16;  // net_plane._MAX_ERROR
+
+enum FetchPhase { FP_HEADER, FP_REFUSAL, FP_PAYLOAD, FP_DONE };
+
+struct FetchRange {
+    int fd;
+    uint64_t size;
+    FetchPhase phase;
+    uint8_t hdr[9];
+    uint32_t hgot;
+    uint64_t left;  // refusal bytes still to read
+    int dst;
+    uint64_t got;
+    uint32_t crc;
+    uint64_t filled;
+    int32_t ncrcs;
+    int64_t last_ns;  // the last byte that came, or the request sent
+};
+
+}  // namespace
+
+extern "C" {
+
+// n ranges, range i on connected plane socket fds[i], its request reqs[i]
+// (req_lens[i] bytes) asking sizes[i] bytes. n_dsts destinations, dsts[d]
+// of caps[d] bytes. granule > 0: range i's completed granule CRCs land at
+// out_crcs[i * max_out ..] (PF_CRCS of them), its partial tail's in
+// PF_TAIL_CRC. msgs: n * msg_cap bytes, where a refusal's message lands
+// (its first msg_cap bytes; PF_MSG_LEN). overlap_mode as sn_recv_into's:
+// a single range that wants it lands through the overlapped core. rec:
+// n * PF_SLOTS. Returns the number of ranges landed, or -EINVAL.
+int sn_plane_fetch(int n, const int* fds, const uint8_t* const* reqs,
+                   const uint32_t* req_lens, const uint64_t* sizes,
+                   int n_dsts, uint8_t* const* dsts, const uint64_t* caps,
+                   int need, uint32_t granule, uint32_t* out_crcs,
+                   int32_t max_out, uint8_t* msgs, uint32_t msg_cap,
+                   int timeout_ms, int32_t overlap_mode, int64_t* rec,
+                   int64_t* ret_ns) {
+    if (n <= 0 || n_dsts < 0) {
+        stamp_return(ret_ns);
+        return -EINVAL;
+    }
+    crc32c_table_init();
+    std::vector<FetchRange> r((size_t)n);
+    std::vector<char> taken((size_t)n_dsts, 0);
+    int landed = 0, active = 0;
+
+    auto slots = [&](int i) { return rec + (size_t)i * PF_SLOTS; };
+    auto finish = [&](int i, int status) {
+        FetchRange& f = r[i];
+        if (status != PFS_LANDED && f.dst >= 0) {
+            taken[f.dst] = 0;  // a later answer may take the destination
+            f.dst = -1;
+        }
+        int64_t* q = slots(i);
+        q[PF_STATUS] = status;
+        q[PF_DST] = f.dst;
+        q[PF_GOT] = (int64_t)f.got;
+        q[PF_CRCS] = f.ncrcs;
+        f.phase = FP_DONE;
+        active--;
+        if (status == PFS_LANDED) {
+            q[PF_TAIL_CRC] = f.crc;
+            q[PF_T_LAND] = mono_ns();
+            landed++;
+        }
+    };
+    // the first free destination, lowest index first, wide enough
+    auto hand_out = [&](uint64_t size) {
+        for (int d = 0; d < n_dsts; d++) {
+            if (!taken[d] && caps[d] >= size) {
+                taken[d] = 1;
+                return d;
+            }
+        }
+        return -1;
+    };
+    // a header has come: where range i goes from here
+    auto parse = [&](int i) {
+        FetchRange& f = r[i];
+        int64_t* q = slots(i);
+        q[PF_T_HDR] = mono_ns();
+        uint64_t len;
+        memcpy(&len, f.hdr + 1, 8);
+        q[PF_ANSWER] = f.hdr[0];
+        q[PF_N] = (int64_t)len;
+        if (f.hdr[0] != 0) {
+            if (len > kMaxRefusal) return finish(i, PFS_BAD_FRAME);
+            f.left = len;
+            f.phase = FP_REFUSAL;
+            if (len == 0) finish(i, PFS_REFUSED);
+            return;
+        }
+        if (len > f.size) return finish(i, PFS_BAD_FRAME);
+        if (len != f.size) return finish(i, PFS_SHORT);
+        f.phase = FP_PAYLOAD;
+        if (f.size == 0 || n == 1) {  // no other range to come before it
+            f.dst = hand_out(f.size);
+            if (f.dst < 0) return finish(i, PFS_BAD_FRAME);
+            q[PF_DST] = f.dst;
+        }
+        if (f.size == 0) return finish(i, PFS_LANDED);
+        if (n == 1 && granule && recv_overlap_wanted(f.size, overlap_mode)) {
+            // one large range: the socket reads on a helper thread with
+            // the CRC chasing them, as sn_recv_into lands it
+            int32_t count = 0;
+            int64_t got = recv_into(f.fd, dsts[f.dst], f.size, timeout_ms,
+                                    granule, &f.crc, &f.filled, out_crcs,
+                                    &count, max_out, overlap_mode);
+            f.ncrcs = count;
+            if (got == (int64_t)f.size) {
+                f.got = f.size;
+                return finish(i, PFS_LANDED);
+            }
+            if (got >= 0) f.got = (uint64_t)got;
+            return finish(i, got == -ETIMEDOUT ? PFS_TIMED_OUT
+                             : got == -EOVERFLOW ? PFS_BAD_FRAME : PFS_TORN);
+        }
+    };
+    // land what range i's socket holds, without waiting for more
+    auto step = [&](int i) {
+        FetchRange& f = r[i];
+        uint8_t scratch[4096];
+        while (f.phase != FP_DONE) {
+            uint8_t* to;
+            size_t want;
+            if (f.phase == FP_HEADER) {
+                to = f.hdr + f.hgot;
+                want = sizeof(f.hdr) - f.hgot;
+            } else if (f.phase == FP_REFUSAL) {
+                uint64_t kept = (uint64_t)slots(i)[PF_MSG_LEN];
+                if (kept < msg_cap) {
+                    to = msgs + (size_t)i * msg_cap + kept;
+                    want = (size_t)(msg_cap - kept);
+                } else {
+                    to = scratch;
+                    want = sizeof(scratch);
+                }
+                if (want > f.left) want = (size_t)f.left;
+            } else {
+                if (f.dst < 0) {
+                    // the first payload byte is here: the range takes the
+                    // first free destination now
+                    uint8_t b;
+                    ssize_t p = recv(f.fd, &b, 1, MSG_PEEK | MSG_DONTWAIT);
+                    if (p < 0) {
+                        if (errno == EINTR) continue;
+                        if (errno == EAGAIN || errno == EWOULDBLOCK) return;
+                        return finish(i, PFS_TORN);
+                    }
+                    if (p == 0) return finish(i, PFS_TORN);
+                    f.dst = hand_out(f.size);
+                    if (f.dst < 0) return finish(i, PFS_BAD_FRAME);
+                    slots(i)[PF_DST] = f.dst;
+                }
+                to = dsts[f.dst] + f.got;
+                want = (size_t)(f.size - f.got);
+            }
+            ssize_t got = recv(f.fd, to, want, MSG_DONTWAIT);
+            if (got < 0) {
+                if (errno == EINTR) continue;
+                if (errno == EAGAIN || errno == EWOULDBLOCK) return;
+                return finish(i, PFS_TORN);
+            }
+            if (got == 0) return finish(i, PFS_TORN);
+            f.last_ns = mono_ns();
+            if (f.phase == FP_HEADER) {
+                f.hgot += (uint32_t)got;
+                if (f.hgot == sizeof(f.hdr)) parse(i);
+            } else if (f.phase == FP_REFUSAL) {
+                if (to != scratch) slots(i)[PF_MSG_LEN] += got;
+                f.left -= (uint64_t)got;
+                if (f.left == 0) finish(i, PFS_REFUSED);
+            } else {
+                if (granule) {
+                    int added = roll_crc_blocks(
+                        &f.crc, &f.filled, granule, to, (size_t)got,
+                        out_crcs + (size_t)i * max_out + f.ncrcs,
+                        max_out - f.ncrcs);
+                    if (added < 0) return finish(i, PFS_BAD_FRAME);
+                    f.ncrcs += added;
+                }
+                f.got += (uint64_t)got;
+                if (f.got == f.size) finish(i, PFS_LANDED);
+            }
+        }
+    };
+
+    for (int i = 0; i < n; i++) {
+        int64_t* q = slots(i);
+        memset(q, 0, sizeof(int64_t) * PF_SLOTS);
+        q[PF_ANSWER] = -1;
+        q[PF_DST] = -1;
+        FetchRange& f = r[i];
+        f = FetchRange{};
+        f.fd = fds[i];
+        f.size = sizes[i];
+        f.dst = -1;
+        f.phase = FP_HEADER;
+        active++;
+        int rc = sn_net::write_full(fds[i], reqs[i], req_lens[i], timeout_ms);
+        f.last_ns = mono_ns();
+        q[PF_T_SENT] = f.last_ns;
+        if (rc != 0) {
+            finish(i, rc == -ETIMEDOUT ? PFS_TIMED_OUT : PFS_TORN);
+            continue;
+        }
+        q[PF_STATUS] = PFS_IN_FLIGHT;
+    }
+    std::vector<struct pollfd> pfd;
+    std::vector<int> who;
+    while (active > 0 && landed < need) {
+        pfd.clear();
+        who.clear();
+        int64_t now = mono_ns();
+        int wait_ms = -1;
+        for (int i = 0; i < n; i++) {
+            if (r[i].phase == FP_DONE) continue;
+            if (timeout_ms >= 0) {
+                int64_t left_ns = r[i].last_ns + (int64_t)timeout_ms * 1000000 - now;
+                if (left_ns <= 0) {
+                    finish(i, PFS_TIMED_OUT);
+                    continue;
+                }
+                int ms = (int)((left_ns + 999999) / 1000000);
+                if (wait_ms < 0 || ms < wait_ms) wait_ms = ms;
+            }
+            pfd.push_back({r[i].fd, POLLIN, 0});
+            who.push_back(i);
+        }
+        if (pfd.empty()) break;
+        int rc = poll(pfd.data(), (nfds_t)pfd.size(), wait_ms);
+        if (rc < 0) {
+            if (errno == EINTR) continue;
+            for (int i : who) finish(i, PFS_TORN);
+            break;
+        }
+        for (size_t k = 0; k < pfd.size(); k++) {
+            if (pfd[k].revents) step(who[k]);
+        }
+    }
+    // on the way out: whatever the socket buffers already hold
+    for (int i = 0; i < n; i++) {
+        if (r[i].phase != FP_DONE) step(i);
+    }
+    for (int i = 0; i < n; i++) {
+        if (r[i].phase != FP_DONE) {  // still in flight: out of frame
+            int64_t* q = slots(i);
+            q[PF_DST] = r[i].dst;
+            q[PF_GOT] = (int64_t)r[i].got;
+        }
+    }
+    stamp_return(ret_ns);
+    return landed;
 }
 
 }  // extern "C"

@@ -129,7 +129,10 @@ class EcVolume:
         only for shards that some peer holds; one with a `read_into(
         shard_id, offset, size, generation, dst, granule) -> (plane,
         crcs)|None` method (the volume server's) lands a range in the
-        buffer where it is used (`_read_from_peer`).
+        buffer where it is used (`_read_from_peer`); `peer_map(shard_ids)`
+        answers `peers` for all of a reconstruction's shards in one
+        look-up, and `fetch_many_into(...)` asks for all its rows in one
+        call (`_fill_in_one_call`).
 
         `interval_cache_bytes` bounds the LRU of verified reconstructed
         extents (0 disables): repeated reads of needles on a missing
@@ -395,6 +398,16 @@ class EcVolume:
         and no read; a bare callable cannot say and is taken to ask."""
         peers = getattr(self.remote_reader, "peers", None)
         return peers is None or bool(peers(shard_id))
+
+    def _askable(self, shard_ids: list[int]) -> list[int]:
+        """Those of `shard_ids` that `_peer_lists`: from ONE look-up of
+        the map where the reader offers `peer_map` (the volume server's),
+        else a shard at a time."""
+        peer_map = getattr(self.remote_reader, "peer_map", None)
+        if peer_map is None:
+            return [i for i in shard_ids if self._peer_lists(i)]
+        listed = peer_map(shard_ids)
+        return [i for i in shard_ids if listed[i]]
 
     def _read_from_peer(
         self, kind: str, shard_id: int, offset: int, size: int,
@@ -683,11 +696,9 @@ class EcVolume:
             admit(row, [i], "sibling_rows_single")
         missing = []
         if None in ids and self.remote_reader is not None:
-            missing = [
-                i
-                for i in range(self.ctx.total)
-                if i != shard_id and i not in ids and self._peer_lists(i)
-            ]
+            missing = self._askable(
+                [i for i in range(self.ctx.total) if i != shard_id and i not in ids]
+            )
         if missing:
             matrix = self._fill_from_peers(
                 matrix, ids, missing, offset, size, prot, sp
@@ -704,9 +715,176 @@ class EcVolume:
         offset: int, size: int, prot, sp,
     ) -> np.ndarray:
         """The open rows of a sibling matrix (`ids[r] is None`) from the
-        peers that hold the shards `missing`, every one asked at once and
-        every answer landed where it is to be used: the first fetches in
-        the open rows themselves, those beyond in spare rows. Returns the
+        peers that hold the shards `missing`. Where the reader offers it
+        (`fetch_many_into`) and the native plane serves with no fault
+        registry armed (the gate of the local sibling reads), every shard
+        is asked of its first listed peer in ONE call on this thread
+        (`_fill_in_one_call`); the rows that leaves open, and every row
+        without it, come from the fetch pool (`_fill_from_pool`). Counted
+        always as `sw_ec_peer_fetch_calls_total{path}` and, armed, as the
+        attribute `peer_fetch_path` of the reconstruction's span: `native`
+        where the one call filled every row, else `pool`."""
+        from . import native_io
+
+        path = "pool"
+        many = getattr(self.remote_reader, "fetch_many_into", None)
+        if many is not None and native_io.enabled() and not faults.active():
+            matrix, missing = self._fill_in_one_call(
+                many, matrix, ids, missing, offset, size, prot, sp
+            )
+            if None not in ids:
+                path = "native"
+        if None in ids and missing:
+            matrix = self._fill_from_pool(
+                matrix, ids, missing, offset, size, prot, sp
+            )
+        M.ec_peer_fetch_calls_total.inc(path=path)
+        if sp is not None:
+            sp.attrs["peer_fetch_path"] = path
+        return matrix
+
+    def _fill_in_one_call(
+        self, many, matrix: np.ndarray, ids: list, missing: list[int],
+        offset: int, size: int, prot, sp,
+    ) -> tuple[np.ndarray, list[int]]:
+        """Every shard of `missing` from its first listed peer, all asked
+        in one call of the reader's `fetch_many_into` (`many`) and landed
+        in the order their answers arrive: the first in the open rows
+        themselves, those after them in spare rows, so that a spare is
+        copied into a row (`matrix_regathers`) only where the row's own
+        answer came rotten or had not landed whole when a later one had.
+        Each landed row is checked against the sidecar by the granule
+        CRCs rolled as it landed (`prot`; None = no ground truth) before
+        it enters `ids`. Returns the matrix and the shards to ask again,
+        those that did not land (refused, short, torn, silent, still in
+        flight, or no plane): not the rotten ones.
+
+        Armed, each range asked is a span `ec.peer_read` (child of `sp`;
+        `kind` = `sibling`) whose stages lie on the call's clock readings:
+        `fetch_queue` from the call's start to its connection's check-out,
+        `conn_checkout`, `request_rtt` to its answer's header, then
+        `payload_land` to its last byte; a range still in flight when the
+        call returned ends there, `unused`, and counts as
+        `peer_reads_outlived`: its connection is closed with it."""
+        open_rows = [r for r, sid in enumerate(ids) if sid is None]
+        need = len(open_rows)
+        spare = np.empty((max(0, len(missing) - need), size), dtype=np.uint8)
+        dsts = [matrix[r] for r in open_rows] + list(spare)
+        granule = prot.verify_granularity(missing[0])[0] if prot is not None else 0
+        n_crcs = -(-size // granule) if granule else 0
+        spans: dict[int, trace.Span] = {}
+
+        def span_of(j):
+            # the range has its connection: its request carries this span
+            s = spans[j] = trace.start(
+                "ec.peer_read", name=f"v{self.volume_id}.{missing[j]:02d}",
+                kind="sibling", shard=missing[j], size=size,
+                peer="", plane="native", answered=0,
+            )
+            s.backdate(t0, cpu0)
+            return s
+
+        with trace.stage(sp, "peer_read"):
+            t0, cpu0 = time.perf_counter_ns(), time.thread_time_ns()
+            got = many(
+                missing, offset, size, self.encode_ts_ns, dsts, granule,
+                need, span_of if sp is not None else None,
+            )
+            t_ret = time.perf_counter_ns()
+
+        def good(j) -> bool:
+            crcs = got[j].crcs
+            return prot is None or (
+                crcs is not None and len(crcs) == n_crcs
+                and prot.granules_match(missing[j], offset, crcs)
+            )
+
+        by_dst = {o.dst: j for j, o in enumerate(got) if o.status == "landed"}
+        failed = sum(
+            o.status not in ("landed", "in_flight", "no_plane", "not_asked")
+            for o in got
+        )
+        looked_at = 0
+        for d in range(need):  # the rows that landed where they are used
+            j = by_dst.get(d)
+            if j is not None:
+                looked_at += 1
+                if good(j):
+                    ids[open_rows[d]] = missing[j]
+        regathered = False
+        for d in range(need, len(dsts)):  # spares, for rows still open
+            if None not in ids:
+                break
+            j = by_dst.get(d)
+            if j is not None:
+                looked_at += 1
+                if good(j):
+                    r = ids.index(None)
+                    matrix[r] = dsts[d]
+                    ids[r] = missing[j]
+                    regathered = True
+        filled = need - ids.count(None)
+        started = sum(1 for o in got if o.t_sent)
+        self.bytes_read += looked_at * size
+        if started:
+            M.ec_peer_reads_total.inc(started, kind="sibling", plane="native")
+            M.ec_peer_read_seconds_total.inc(
+                sum((o.t_landed or t_ret) - o.t_checkout for o in got if o.t_sent) / 1e9,
+                kind="sibling", plane="native",
+            )
+        if by_dst:
+            M.ec_peer_read_bytes_total.inc(
+                len(by_dst) * size, kind="sibling", plane="native"
+            )
+        dropped = sum(1 for o in got if o.status == "in_flight")
+        if dropped:
+            M.ec_peer_read_dropped_bytes_total.inc(dropped * size, kind="sibling")
+        if sp is not None:
+            sp.count("peer_fetches_started", started)
+            sp.count("peer_fetches_unused", started - looked_at - failed)
+            if looked_at:
+                # checked as they landed, with no call of their own
+                sp.count("sibling_rows_batched", looked_at)
+                sp.count("peer_reads_native", looked_at)
+            sp.count("matrix_regathers", 1 if regathered else 0)
+            if filled:
+                sp.count("sibling_rows_remote", filled)
+            if dropped:
+                sp.count("peer_reads_outlived", dropped)
+            for j, s in spans.items():
+                self._lay_fetch(s, got[j], t0, t_ret)
+        return matrix, [missing[j] for j, o in enumerate(got) if o.status != "landed"]
+
+    @staticmethod
+    def _lay_fetch(s, o, t0: int, t_ret: int) -> None:
+        """A range's `ec.peer_read` span laid on its call's clock readings
+        and closed: at its last byte where it landed, else where the call
+        returned."""
+        s.attrs["peer"] = o.peer
+        end = o.t_landed or t_ret
+        s.add_interval("fetch_queue", t0, o.t_checkout)
+        s.add_interval("conn_checkout", o.t_checkout, o.t_connected)
+        if o.payload_began:
+            s.add_interval("request_rtt", o.t_connected, o.t_header)
+            s.add_interval("payload_land", o.t_header, end)
+        else:
+            s.add_interval("request_rtt", o.t_connected, end)
+        if o.status == "landed":
+            s.finish(end, answered=1)
+        elif o.status == "in_flight":
+            s.finish(end, unused=1)  # dropped with its connection
+        else:
+            s.finish(end)
+
+    def _fill_from_pool(
+        self, matrix: np.ndarray, ids: list, missing: list[int],
+        offset: int, size: int, prot, sp,
+    ) -> np.ndarray:
+        """The open rows of a sibling matrix (`ids[r] is None`) from the
+        peers that hold the shards `missing`, every one asked at once on
+        the fetch pool and every answer landed where it is to be used: the
+        first fetches in the open rows themselves, those beyond in spare
+        rows. Returns the
         matrix: the same one where each open row's own fetch filled it;
         else a gathered copy, with spares in the place of rows whose fetch
         failed, came rotten or is still running (a running fetch owns its

@@ -154,13 +154,14 @@ def _pool_width(n: int) -> int:
     return max(64 * 1024, 1 << (max(1, n) - 1).bit_length())
 
 
-def _encode_meta(extra: dict | None = None) -> bytes:
-    """The active request-id / trace context as a metadata blob —
-    exactly what trace.grpc_metadata() would put on the RPC — plus any
-    opcode-specific key/value pairs (the write opcode's kind / flags /
-    name / jwt lines). Values must not contain tab or newline; binary
-    fields ride urlsafe base64 (see _b64)."""
-    md = list(trace.grpc_metadata() or [])
+def _encode_meta(extra: dict | None = None, span=None) -> bytes:
+    """The active request-id / trace context (that of `span` where one
+    is given) as a metadata blob — exactly what trace.grpc_metadata()
+    would put on the RPC — plus any opcode-specific key/value pairs (the
+    write opcode's kind / flags / name / jwt lines). Values must not
+    contain tab or newline; binary fields ride urlsafe base64 (see
+    _b64)."""
+    md = list(trace.grpc_metadata(span) or [])
     if extra:
         md.extend(
             (k, str(v)) for k, v in extra.items()
@@ -1039,6 +1040,54 @@ class ShardNetPlane:
 # ---------------------------------------------------------------------------
 
 
+class PlaneFetch:
+    """What became of one range of :meth:`NetPlaneClient.fetch_many_into`.
+
+    `status`: `landed`; `refused` (the holder's `message` read, the
+    connection in frame); `short` (answered shorter than asked: the EOF
+    clamp); `bad_frame` (longer than asked, or a refusal too long to be
+    one); `torn` (the connection failed or closed before the end);
+    `timed_out`; `in_flight` (the call returned before it was done);
+    `no_plane` (the holder serves no plane: `error` is the memoized
+    refusal); `not_asked`. `dst`: the index of the destination it landed
+    in; `crcs`: the granule CRCs rolled as it landed, the partial tail's
+    last (None without a granule); `n`: the length the holder answered;
+    `payload_began`: an answer of the asked length came, so its bytes
+    began to land. Clock readings (`time.perf_counter_ns`; 0 where not
+    reached): its connection asked for and in hand, its request sent,
+    its answer's header parsed, its last byte landed. `peer`: the
+    caller's name for the holder, where the caller gives one."""
+
+    __slots__ = (
+        "status", "dst", "crcs", "n", "got", "message", "error",
+        "payload_began", "t_checkout", "t_connected", "t_sent", "t_header",
+        "t_landed", "peer",
+    )
+
+    def __init__(self):
+        self.status = "not_asked"
+        self.dst = self.crcs = self.error = None
+        self.n = self.got = 0
+        self.message = ""
+        self.payload_began = False
+        self.t_checkout = self.t_connected = 0
+        self.t_sent = self.t_header = self.t_landed = 0
+        self.peer = ""
+
+    def failure(self, addr, size: int) -> Exception:
+        """What :meth:`NetPlaneClient.read_into` raises for a range that
+        did not land."""
+        if self.error is not None:
+            return self.error
+        why = {
+            "refused": self.message,
+            "short": f"short stream {self.n}/{size}",
+            "bad_frame": f"bad frame {self.n}/{size}",
+            "timed_out": f"timed out after {self.got}/{size}",
+        }.get(self.status, f"torn stream {self.got}/{size}")
+        return NetPlaneError(f"{addr}: {why}")
+
+
 class NetPlaneClient:
     """Pooled client connections to peers' shard net planes, landing
     payload bytes straight in caller buffers (``sn_recv_into``) with the
@@ -1247,18 +1296,143 @@ class NetPlaneClient:
         partial tail) as a u32 ndarray — the caller compares them
         against the .ecsum sidecar with no extra pass over the bytes.
         Calls to one address run side by side, each on a connection of
-        its own."""
+        its own. :meth:`fetch_many_into` of this one range: with the .so
+        one native call from the request to the last byte. Raises
+        :class:`NetPlaneUnavailable` for a holder without a plane and
+        :class:`NetPlaneError` for a range that did not land."""
+        (got,) = self.fetch_many_into(
+            [(addr, vid, sid, gen, off, size)], [dst], granule=granule
+        )
+        if got.status != "landed":
+            raise got.failure(addr, size)
+        return got.crcs
+
+    def fetch_many_into(
+        self, ranges: list, dsts: list[np.ndarray], *, granule: int = 0,
+        need: int | None = None, span_of=None,
+    ) -> list[PlaneFetch]:
+        """Ask for every range at once, each on a connection of its own,
+        and land the answers in `dsts` (1-D C-contiguous uint8 buffers),
+        handed out in the order the answers arrive, lowest index
+        first; return once `need` of them (all, by default) have landed
+        whole or none is left to wait for: one :class:`PlaneFetch` a
+        range, in order. `ranges`: (addr, vid, sid, gen, off, size) each,
+        or None (not asked). `span_of(i)`, where given, is the span whose
+        trace context request i carries (else the ambient one's), asked
+        for once the range's connection is in hand. Nothing raises for a
+        range: each says what became of it. A connection goes back to the
+        pool where its answer was read to the end (a refusal's too), and
+        is closed where not. With the .so ONE native call sends the
+        requests and lands the answers (`sn_plane_fetch`); without it
+        Python reads the same wire, a range after another.
+
+        Under a read from a peer (`ec.peer_read`, whose thread has one of
+        `trace.TURN_STAGES` open) the check-out is its `conn_checkout`,
+        from there to the answer's header its `request_rtt` and the rest
+        its `payload_land`."""
+        out = [PlaneFetch() for _ in ranges]
+        asked = []  # (index, addr, connection, request)
+        trace.turn("conn_checkout")
+        for i, rg in enumerate(ranges):
+            if rg is None:
+                continue
+            addr, vid, sid, gen, off, size = rg
+            o = out[i]
+            o.t_checkout = time.perf_counter_ns()
+            try:
+                s = self._checkout(addr)
+            except NetPlaneUnavailable as e:
+                o.status, o.error = "no_plane", e
+                continue
+            finally:
+                o.t_connected = time.perf_counter_ns()
+            meta = _encode_meta(span=span_of(i) if span_of is not None else None)
+            asked.append(
+                (i, addr, s, _REQ.pack(MAGIC, vid, sid, gen, off, size, len(meta)) + meta)
+            )
+        trace.turn("request_rtt")
+        if not asked:
+            return out
+        need = len(asked) if need is None else min(need, len(asked))
         native = _native_mod()
-        s, _n = self._request(addr, vid, sid, gen, off, size)
-        healthy = False
+        if native is None:
+            self._fetch_python(ranges, dsts, asked, out, granule, need)
+            return out
+        self._fetch_native(native, ranges, dsts, asked, out, granule, need)
+        if len(ranges) == 1 and out[0].payload_began:
+            trace.turn_at("payload_land", out[0].t_header)
+        return out
+
+    def _fetch_native(self, native, ranges, dsts, asked, out, granule, need):
+        """`fetch_many_into`'s requests sent and answers landed in one
+        `sn_plane_fetch`."""
+        healthy = [False] * len(asked)
         try:
-            crcs = self._land(addr, s, size, dst, granule, native)
-            healthy = True
-            return crcs
-        except OSError as e:
-            raise NetPlaneError(f"{addr}: {e}") from e
+            rec, crcs, msgs = native.plane_fetch(
+                [s.fileno() for _i, _a, s, _r in asked],
+                [req for _i, _a, _s, req in asked],
+                [ranges[i][5] for i, _a, _s, _r in asked],
+                dsts, need, granule=granule,
+                timeout_ms=int(self.timeout * 1000),
+            )
+            landed = 0
+            for k, (i, _addr, _s, _req) in enumerate(asked):
+                (status, answer, n, got, dst, ncrcs, tail, mlen,
+                 t_sent, t_hdr, t_land) = rec[k].tolist()
+                o = out[i]
+                o.status = native.FETCH_STATUS[status]
+                o.n, o.got = n, got
+                o.t_sent, o.t_header, o.t_landed = t_sent, t_hdr, t_land
+                o.payload_began = answer == 0 and n == ranges[i][5]
+                if o.status == "landed":
+                    o.dst = dst
+                    landed += got
+                    if granule:
+                        o.crcs = crcs[k, :ncrcs].copy()
+                        if got % granule:
+                            o.crcs = np.append(o.crcs, np.uint32(tail))
+                elif o.status == "refused":
+                    o.message = msgs[k, :mlen].tobytes().decode(errors="replace")
+                healthy[k] = o.status in ("landed", "refused")
+            if landed:
+                M.net_bytes_received_total.inc(landed, plane="native", direction="read")
         finally:
-            self._release(addr, s, healthy)
+            for k, (_i, addr, s, _r) in enumerate(asked):
+                self._release(addr, s, healthy[k])
+
+    def _fetch_python(self, ranges, dsts, asked, out, granule, need):
+        """`fetch_many_into` without the .so: the same wire in Python, a
+        range after another, until `need` have landed (those after them
+        are not asked)."""
+        free = list(range(len(dsts)))
+        landed = 0
+        for i, addr, s, req in asked:
+            o, size = out[i], ranges[i][5]
+            healthy = landed >= need  # not asked: in frame as it was
+            try:
+                if healthy:
+                    continue
+                s.sendall(req)
+                o.t_sent = time.perf_counter_ns()
+                status, n = _RESP.unpack(_recv_exact(s, _RESP.size))
+                o.t_header, o.n = time.perf_counter_ns(), n
+                if status != 0:
+                    o.message = self._read_refusal(addr, s, n)
+                    o.status, healthy = "refused", True
+                elif n != size:
+                    o.status = "short" if n < size else "bad_frame"
+                else:
+                    o.payload_began = True
+                    d = next(d for d in free if dsts[d].nbytes >= size)
+                    o.crcs = self._land(addr, s, size, dsts[d], granule, None)
+                    free.remove(d)
+                    o.status, o.dst, o.got, healthy = "landed", d, size, True
+                    o.t_landed = time.perf_counter_ns()
+                    landed += 1
+            except (OSError, NetPlaneError):
+                o.status = "torn"
+            finally:
+                self._release(addr, s, healthy)
 
     def _land(self, addr, s, size, dst, granule, native):
         """`size` payload bytes of the response `s` stands at, into

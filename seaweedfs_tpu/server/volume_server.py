@@ -1128,14 +1128,54 @@ class _PeerShardReader:
         shard, from the client's cached map: none where it lists none,
         so a caller can tell a read that asks a peer from a look-up
         that asks nobody."""
+        return self.peer_map([shard_id])[shard_id]
+
+    def peer_map(self, shard_ids) -> dict[int, list[str]]:
+        """`peers` of each shard, from ONE look-up of the map."""
         vs = self.server
         try:
-            locs = vs._master_client().lookup_ec(self.vid).get(shard_id, [])
+            located = vs._master_client().lookup_ec(self.vid)
         except (LookupError, grpc.RpcError):
-            return []
+            return {sid: [] for sid in shard_ids}
         me = f"{vs.ip}:{vs.grpc_port}"
-        addrs = (f"{loc.url.split(':')[0]}:{loc.grpc_port}" for loc in locs)
-        return [peer for peer in addrs if peer != me]
+        return {
+            sid: [
+                peer for peer in (
+                    f"{loc.url.split(':')[0]}:{loc.grpc_port}"
+                    for loc in located.get(sid, [])
+                ) if peer != me
+            ]
+            for sid in shard_ids
+        }
+
+    def fetch_many_into(
+        self, shard_ids: list[int], offset: int, size: int, generation: int,
+        dsts: list[np.ndarray], granule: int = 0, need: int | None = None,
+        span_of=None,
+    ):
+        """[offset, offset+size) of each shard from its first listed peer,
+        all asked at once over the peers' shard planes in ONE call of the
+        plane client (`NetPlaneClient.fetch_many_into`: the answers land in
+        `dsts` in the order they arrive, and the call returns once `need`
+        have landed): one `PlaneFetch` a shard, its `peer` the
+        holder asked, `not_asked` where no live server holds the shard.
+        Nothing else is tried here: what did not land is the caller's to
+        ask again (`read_into`: the next peer, the stream)."""
+        from ..ec import net_plane as _netp
+
+        peers = self.peer_map(shard_ids)
+        firsts = [(peers[sid] or [""])[0] for sid in shard_ids]
+        got = self.server._net_plane_client().fetch_many_into(
+            [
+                (_netp.net_addr(peer), self.vid, sid, generation, offset, size)
+                if peer else None
+                for sid, peer in zip(shard_ids, firsts)
+            ],
+            dsts, granule=granule, need=need, span_of=span_of,
+        )
+        for fetched, peer in zip(got, firsts):
+            fetched.peer = peer
+        return got
 
     def read_into(
         self, shard_id: int, offset: int, size: int, generation: int,

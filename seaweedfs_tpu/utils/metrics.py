@@ -373,6 +373,23 @@ ec_peer_read_seconds_total = REGISTRY.counter(
     "seconds EC reads waited for peers' shard ranges (a reconstruction's "
     "fetches run side by side: each counts its own)", ("kind", "plane"),
 )
+# bytes asked in ranges that the reader's one call (ec/net_plane.py
+# fetch_many_into) returned without, still in flight: their connections
+# are closed, and the holders sent some or all of them to nobody. With
+# sw_ec_peer_read_bytes_total{plane="native"} it bounds what the holders'
+# planes sent: landed <= sent <= landed + dropped.
+ec_peer_read_dropped_bytes_total = REGISTRY.counter(
+    "sw_ec_peer_read_dropped_bytes_total",
+    "bytes of peers' shard ranges an EC read closed in flight", ("kind",),
+)
+# reconstructions that asked peers for rows, by how the rows went out:
+# native (all in one call of the reader's shard plane client), pool (a
+# fetch thread a row: an armed fault registry, no native plane, or rows
+# the one call left open)
+ec_peer_fetch_calls_total = REGISTRY.counter(
+    "sw_ec_peer_fetch_calls_total",
+    "reconstructions that asked peers for sibling rows, by path", ("path",),
+)
 # range requests a holder's shard plane served, by the code that served
 # them: `native` inside one call (ec/net_plane.py's table), `python` the
 # connection thread's own code (chaos armed, no .so, a table miss)

@@ -267,6 +267,28 @@ _lib.sn_plane_serve.argtypes = [
     ctypes.c_void_p,  # rec (u64[PLANE_REC_SLOTS])
     ctypes.c_void_p,  # meta (u8[PLANE_META_MAX])
 ]
+# the reader's side of shard plane reads in one call (ec/net_plane.py)
+_lib.sn_plane_fetch.restype = ctypes.c_int
+_lib.sn_plane_fetch.argtypes = [
+    ctypes.c_int,                     # n ranges
+    ctypes.POINTER(ctypes.c_int),     # fds
+    ctypes.POINTER(ctypes.c_void_p),  # reqs
+    ctypes.POINTER(ctypes.c_uint32),  # req_lens
+    ctypes.POINTER(ctypes.c_uint64),  # sizes
+    ctypes.c_int,                     # n_dsts
+    ctypes.POINTER(ctypes.c_void_p),  # dsts
+    ctypes.POINTER(ctypes.c_uint64),  # caps
+    ctypes.c_int,                     # need
+    ctypes.c_uint32,                  # granule
+    ctypes.c_void_p,                  # out_crcs (u32[n, max_out])
+    ctypes.c_int32,                   # max_out
+    ctypes.c_void_p,                  # msgs (u8[n, msg_cap])
+    ctypes.c_uint32,                  # msg_cap
+    ctypes.c_int,                     # timeout_ms
+    ctypes.c_int32,                   # overlap_mode
+    ctypes.c_void_p,                  # rec (i64[n, len(FETCH_REC)])
+    ctypes.c_void_p,                  # ret_ns
+]
 # the core-wait probe (utils/interp_probe.py owns its life)
 _lib.sn_probe_start.restype = ctypes.c_int
 _lib.sn_probe_start.argtypes = [ctypes.c_int64]
@@ -282,10 +304,10 @@ _lib.sn_probe_read.argtypes = [
 ]
 
 
-# The six calls that do real work without the interpreter (batch_pread,
-# crc32c_granules, sendv, NativeSink.append, and the two that carry every
-# byte of a read from a peer: send_file at the holder, recv_into at the
-# reader) end by writing
+# The calls that do real work without the interpreter (batch_pread,
+# crc32c_granules, sendv, NativeSink.append, and those that carry every
+# byte of a read from a peer: send_file at the holder, plane_fetch and
+# recv_into at the reader) end by writing
 # CLOCK_MONOTONIC where their last argument, `ret_ns`, points: how long
 # the thread then waits to hold the interpreter again is
 # `perf_counter_ns()` on return less that stamp. Armed, the call gets a
@@ -572,6 +594,58 @@ class PlaneTable:
             self._t, conn_fd, timeout_ms, 1 if native_ok else 0,
             rec.ctypes.data, meta.ctypes.data,
         )
+
+
+# sn_plane_fetch: what became of a range (its record's "status") ...
+FETCH_STATUS = (
+    "landed", "refused", "short", "bad_frame", "torn", "timed_out", "in_flight",
+)
+# ... and the slots of its record, as the C side numbers them
+FETCH_REC = (
+    "status", "answer", "n", "got", "dst", "crcs", "tail_crc", "msg_len",
+    "t_sent", "t_header", "t_landed",
+)
+FETCH_MSG_MAX = 1024
+
+
+def plane_fetch(
+    fds: list[int], requests: list[bytes], sizes: list[int],
+    dsts: list[np.ndarray], need: int, *, granule: int = 0,
+    timeout_ms: int = -1,
+) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """Send request i on connected plane socket fds[i] for every i at
+    once and land the answers (sizes[i] bytes each) in `dsts` (1-D
+    C-contiguous uint8), handed out in the order the answers arrive,
+    lowest index first; return once `need` ranges have landed
+    or none is left to wait for (see native/seaweed_native.cpp). ->
+    (record i64[n, len(FETCH_REC)], granule CRCs u32[n, max_out], refusal
+    messages u8[n, FETCH_MSG_MAX]). One GIL-releasing call."""
+    n = len(fds)
+    assert len(requests) == n == len(sizes) and n > 0
+    for d in dsts:
+        assert d.dtype == np.uint8 and d.ndim == 1 and d.flags.c_contiguous
+    rec = np.zeros((n, len(FETCH_REC)), np.int64)
+    max_out = (max(sizes) // granule + 2) if granule else 1
+    out_crcs = np.zeros((n, max_out), np.uint32)
+    msgs = np.zeros((n, FETCH_MSG_MAX), np.uint8)
+    keep: list = []
+    reqs = (ctypes.c_void_p * n)()
+    lens = (ctypes.c_uint32 * n)()
+    for i, req in enumerate(requests):
+        reqs[i], lens[i] = _part_ptr_len(req, keep)
+    m = len(dsts)
+    rc = _stamped(
+        _lib.sn_plane_fetch, n, (ctypes.c_int * n)(*fds), reqs, lens,
+        (ctypes.c_uint64 * n)(*sizes), m,
+        (ctypes.c_void_p * m)(*(d.ctypes.data for d in dsts)),
+        (ctypes.c_uint64 * m)(*(d.nbytes for d in dsts)),
+        need, granule, ctypes.c_void_p(out_crcs.ctypes.data), max_out,
+        ctypes.c_void_p(msgs.ctypes.data), FETCH_MSG_MAX, timeout_ms,
+        _overlap_mode(), ctypes.c_void_p(rec.ctypes.data),
+    )
+    if rc < 0:
+        raise OSError(-rc, f"sn_plane_fetch: {os.strerror(-rc)}")
+    return rec, out_crcs, msgs
 
 
 def plane_set_admissible(yes: bool) -> None:

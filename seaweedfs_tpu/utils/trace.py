@@ -146,10 +146,11 @@ arguments so the disarmed path cannot even box a kwargs dict.
 
 from __future__ import annotations
 
+import os
+import random
 import sys
 import threading
 import time
-import uuid
 from collections import deque
 from contextvars import ContextVar
 
@@ -158,6 +159,19 @@ from . import request_id as _rid
 from .glog import logger
 
 _log = logger("trace")
+
+# Span and trace ids: 64 bits from a generator of the process's own,
+# seeded from the OS once (and again in a forked child). `uuid.uuid4()`
+# reads os.urandom, whose getrandom(2) lets the interpreter go, so in a
+# saturated interpreter every span made waited for it again: a call that
+# makes a span for each range it asks for, before it sends them, paid
+# that wait a range.
+_ids = random.Random()
+os.register_at_fork(after_in_child=_ids.seed)
+
+
+def _new_id() -> str:
+    return f"{_ids.getrandbits(64):016x}"
 
 # gRPC metadata keys (lowercase: gRPC normalizes ASCII keys).
 TRACE_ID_KEY = "x-sw-trace-id"
@@ -496,8 +510,8 @@ class Span:
         attrs: dict | None = None,
         local_root: bool = True,
     ):
-        self.trace_id = trace_id or uuid.uuid4().hex[:16]
-        self.span_id = uuid.uuid4().hex[:16]
+        self.trace_id = trace_id or _new_id()
+        self.span_id = _new_id()
         self.parent_id = parent_id
         self.op = op
         self.name = name or op
@@ -1032,6 +1046,21 @@ def turn(name: str) -> None:
     ):
         return
     timer._turn(name, time.perf_counter_ns(), time.thread_time_ns())
+
+
+def turn_at(name: str, at_ns: int) -> None:
+    """:func:`turn` at the clock reading `at_ns`, taken earlier by a
+    native call that ran without the interpreter (never before the open
+    stage began); the CPU read now is the closed stage's."""
+    if not armed:
+        return
+    timer = _open_stage.get()
+    if (
+        timer is None or timer.name == name
+        or timer.name not in TURN_STAGES or name not in TURN_STAGES
+    ):
+        return
+    timer._turn(name, max(at_ns, timer._t0), time.thread_time_ns())
 
 
 def count(name: str, n: int) -> None:

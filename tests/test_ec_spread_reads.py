@@ -428,6 +428,18 @@ def grown(before, kind, plane=None):
     return {name: now[name] - before[name] for name in now}
 
 
+def dropped():
+    """Bytes of the ranges that readers closed in flight: a
+    reconstruction's one call returned once its rows had landed."""
+    return sum(metrics.ec_peer_read_dropped_bytes_total.snapshot().values())
+
+
+def fetch_calls():
+    """Reconstructions that asked peers for rows, by path."""
+    snap = metrics.ec_peer_fetch_calls_total.snapshot()
+    return {path: snap.get((path,), 0) for path in ("native", "pool")}
+
+
 @pytest.mark.parametrize("armed", [True, False], ids=["armed", "disarmed"])
 def test_a_healthy_gets_reads_from_peers_are_counted_at_the_reader_and_seen_at_the_holders(
     spread, disarmed, armed
@@ -476,7 +488,7 @@ def test_a_reconstructions_fetches_are_counted_started_used_and_unused(spread, d
     trace.configure(enabled=True)
     trace.reset()
     quiet(spread)
-    before = counted("sibling")
+    before, drop0 = counted("sibling"), dropped()
     status, body = spread.get(entry, i)
     assert status == 200 and body == vol.body(i)
     (root,) = spread.roots(vol.fid(i))
@@ -499,20 +511,17 @@ def test_a_reconstructions_fetches_are_counted_started_used_and_unused(spread, d
         # the one batched read of the two rows that lie here
         assert d["stages"]["peer_read"]["seconds"] > 0
         assert d["stages"]["sibling_read"]["count"] == 1
-    # a stream that runs ends whether it is read or not: every fetch that
-    # started is a read at the reader's counter and a span at its holder,
-    # and both sides count the same bytes
+    # every fetch that started is a read at the reader's counter and a
+    # span at its holder; the holders' sizes are the bytes the reader
+    # landed and those it closed in flight, its rows in hand
     started = sum(d["attrs"]["peer_fetches_started"] for d in reads)
-    deadline = time.time() + 10
-    while grown(before, "sibling")["reads"] < started and time.time() < deadline:
-        time.sleep(0.05)
     got = grown(before, "sibling")
     assert got["reads"] == started and got["seconds"] > 0
     served = served_under(root)
     assert not {d["attrs"]["shard"] for d in served} & (set(LOST) | set(PLACED[entry]))
     assert len(served) == started + root["attrs"].get("peer_reads", 0)
     assert sum(d["attrs"]["size"] for d in served) == (
-        got["bytes"] + root["attrs"].get("peer_read_bytes", 0)
+        got["bytes"] + root["attrs"].get("peer_read_bytes", 0) + dropped() - drop0
     )
 
 
@@ -575,6 +584,13 @@ def settled(fn, timeout=10.0):
     return fn()
 
 
+def sent_as_landed(spread, sent0, before, drop0):
+    """The holders' planes sent what the readers landed on the native
+    plane, to the byte, and perhaps some of what they closed in flight."""
+    sent, landed = planes_sent(spread) - sent0, bytes_grown(before, "native")
+    return landed <= sent <= landed + dropped() - drop0
+
+
 def quiet(spread, still_s=0.3):
     """(the readers' counters, the planes' bytes) once neither moves any
     more: the fetches that earlier GETs left running have ended."""
@@ -599,10 +615,12 @@ def test_interval_reads_and_sibling_rows_travel_the_native_shard_plane(spread, d
     trace.configure(enabled=armed)
     trace.reset()
     before, sent0 = quiet(spread)
+    drop0 = dropped()
     for i in (healthy, lost):
         status, body = spread.get(entry, i)
         assert status == 200 and body == vol.body(i)
-    assert settled(lambda: planes_sent(spread) - sent0 == bytes_grown(before, "native") > 0)
+    assert settled(lambda: sent_as_landed(spread, sent0, before, drop0))
+    assert bytes_grown(before, "native") > 0
     assert bytes_grown(before, "stream") == 0 and reads_grown(before, "stream") == 0
     now = by_plane()["native"]
     assert all(now[kind]["reads"] > before["native"][kind]["reads"]
@@ -686,6 +704,7 @@ def test_with_a_holders_plane_gone_its_ranges_come_over_the_stream_and_every_get
     trace.configure(enabled=True)
     trace.reset()
     before, sent0 = quiet(spread)
+    drop0 = dropped()
     try:
         on_holder = [i for sid in PLACED[holder]
                      for i in spread.healthy_on(sid)][:4]
@@ -698,7 +717,7 @@ def test_with_a_holders_plane_gone_its_ranges_come_over_the_stream_and_every_get
         assert bytes_grown(before, "stream") > 0 and bytes_grown(before, "native") > 0
         # the stopped or refusing plane sent nothing; the others' bytes
         # are the readers' native bytes, still to the byte
-        assert settled(lambda: planes_sent(spread) - sent0 == bytes_grown(before, "native"))
+        assert settled(lambda: sent_as_landed(spread, sent0, before, drop0))
         if how == "stopped":
             for entry in (0, 5):
                 assert addr in cl.servers[entry]._net_plane_client()._no_plane
@@ -795,10 +814,12 @@ def test_a_row_that_a_peer_sends_rotten_over_the_plane_never_enters_the_matrix(s
     assert reads
     for d in reads:
         attrs = d["attrs"]
-        # ten fetches over the plane: eight rows kept, and each rotten one
-        # either landed, was looked at and dropped, or came after the eighth
-        assert attrs["peer_fetches_started"] == 10 and attrs["sibling_rows_remote"] == 8
-        looked_at = 10 - attrs["peer_fetches_unused"]
+        # ten fetches over the plane in one call (a rotten row's shard that
+        # the call left in flight is asked again, on the pool): eight rows
+        # kept, and each rotten one either landed, was looked at and
+        # dropped, or came after the eighth
+        assert attrs["peer_fetches_started"] >= 10 and attrs["sibling_rows_remote"] == 8
+        looked_at = attrs["peer_fetches_started"] - attrs["peer_fetches_unused"]
         assert 8 <= looked_at == attrs["peer_reads_native"]
         assert attrs["sibling_rows_batched"] == len(PLACED[entry]) + looked_at
         assert "sibling_rows_single" not in attrs
@@ -816,12 +837,26 @@ def test_the_threads_that_carry_peers_bytes_have_names_and_classes_and_live_with
     from seaweedfs_tpu.utils import interp_probe
 
     vol, entry = spread.vol, 2
-    spread.drop_caches()
-    assert spread.get(entry, spread.on_lost[-1])[0] == 200
 
     def named(prefix):
         return {t for t in threading.enumerate() if t.name.startswith(prefix)}
 
+    # one pool per store, made once: the threads of one reconstruction
+    # are there for the next
+    pool = spread.cl.servers[entry].store.ec_fetch_pool
+    assert spread.ev(entry)._fetch_pool is pool
+    assert pool._max_workers == ec_volume.PEER_FETCH_THREADS == 32
+    # with every plane answering, a reconstruction's rows go out in one
+    # call on the GET's own worker: no thread of the pool is started
+    quiet(spread)
+    spread.drop_caches()
+    idle = set(pool._threads)
+    assert spread.get(entry, spread.on_lost[-1])[0] == 200
+    assert set(pool._threads) == idle
+    # an armed fault registry sends the rows to the pool, a thread a row
+    spread.drop_caches()
+    with faults.injected("server.ec_shard_read", lambda ctx, data: data):
+        assert spread.get(entry, spread.on_lost[-1])[0] == 200
     fetchers = named(ec_volume.PEER_FETCH_THREAD_PREFIX)
     conns = named(net_plane.CONN_THREAD_PREFIX)
     assert fetchers and conns
@@ -830,15 +865,169 @@ def test_the_threads_that_carry_peers_bytes_have_names_and_classes_and_live_with
     assert {t.name.rsplit("-", 1)[1] for t in conns} <= {
         str(spread.cl.servers[s].net_plane.port) for s in LIVE
     }
-    # one pool per store, made once: the threads of one reconstruction
-    # are there for the next
-    pool = spread.cl.servers[entry].store.ec_fetch_pool
-    assert spread.ev(entry)._fetch_pool is pool
-    assert pool._max_workers == ec_volume.PEER_FETCH_THREADS == 32
     mine = {t for t in fetchers if t in pool._threads}
     assert mine
     quiet(spread)
     spread.drop_caches()
-    assert spread.get(entry, spread.on_lost[-2])[0] == 200
+    with faults.injected("server.ec_shard_read", lambda ctx, data: data):
+        assert spread.get(entry, spread.on_lost[-2])[0] == 200
     now = {t for t in named(ec_volume.PEER_FETCH_THREAD_PREFIX) if t in pool._threads}
     assert mine <= now and len(now) <= ec_volume.PEER_FETCH_THREADS
+
+
+# ------------------------------------------- a reconstruction's one call
+
+
+class watched:
+    """Records, for the with-block, the entry server's calls of its
+    reader's `fetch_many_into` (the shards asked, the calling thread) and
+    the tasks submitted to its fetch pool."""
+
+    def __init__(self, spread, entry):
+        self.reader = spread.ev(entry).remote_reader
+        self.pool = spread.ev(entry)._peer_fetch_pool()
+        self.calls, self.submitted = [], []
+
+    def __enter__(self):
+        import threading
+
+        many, submit = self.reader.fetch_many_into, self.pool.submit
+
+        def fetch_many_into(shard_ids, *a, **kw):
+            self.calls.append((list(shard_ids), threading.current_thread().name))
+            return many(shard_ids, *a, **kw)
+
+        def counted_submit(*a, **kw):
+            self.submitted.append(a)
+            return submit(*a, **kw)
+
+        self.reader.fetch_many_into, self.pool.submit = fetch_many_into, counted_submit
+        return self
+
+    def __exit__(self, *exc):
+        del self.reader.fetch_many_into, self.pool.submit
+
+
+def reconstructions(root):
+    return [d for d in root["children"]
+            if d["op"] == "ec.degraded_read" and "reconstruct" in d["stages"]]
+
+
+def test_with_every_plane_answering_a_reconstructions_rows_go_out_in_one_call(spread, disarmed):
+    """Every live peer's shard plane answers: a reconstruction asks all ten
+    shards it can ask in ONE call on the GET's own worker, no task reaches
+    the fetch pool, the rows land where they are used, and the always-on
+    counter says `native` once a reconstruction."""
+    vol, entry = spread.vol, 4
+    i = min(spread.on_lost, key=lambda i: vol.sizes[i])  # one granule a row
+    spread.drop_caches()
+    trace.configure(enabled=True)
+    trace.reset()
+    quiet(spread)
+    calls0, before = fetch_calls(), counted("sibling", "native")
+    with watched(spread, entry) as w:
+        status, body = spread.get(entry, i)
+    assert status == 200 and body == vol.body(i)
+    (root,) = spread.roots(vol.fid(i))
+    recons = reconstructions(root)
+    assert recons and len(w.calls) == len(recons) and w.submitted == []
+    askable = CTX.total - len(PLACED[entry]) - len(LOST)
+    for shard_ids, thread in w.calls:
+        assert len(shard_ids) == askable and thread == root["thread"]
+        assert not set(shard_ids) & (set(LOST) | set(PLACED[entry]))
+    for d in recons:
+        a = d["attrs"]
+        assert a["peer_fetch_path"] == "native" and a["matrix_regathers"] == 0
+        assert a["sibling_rows_remote"] == a["peer_reads_native"] == CTX.data_shards - 2
+        assert a["peer_fetches_started"] == askable
+    calls = fetch_calls()
+    assert calls["native"] - calls0["native"] == len(recons)
+    assert calls["pool"] == calls0["pool"]
+    assert grown(before, "sibling", "native")["reads"] == askable * len(recons)
+
+
+def test_with_two_holders_planes_gone_only_their_rows_leave_the_one_call(spread, disarmed):
+    """The reader takes servers 3's and 5's planes for absent (its memo of
+    a refused connect): the one call asks the six shards that the other
+    peers hold and lands them; the two rows still open come from the fetch
+    pool, over the absent planes' `VolumeEcShardRead` streams, and only
+    from those holders."""
+    from seaweedfs_tpu.ec import net_plane
+
+    cl, vol, entry, gone = spread.cl, spread.vol, 0, (3, 5)
+    client = cl.servers[entry]._net_plane_client()
+    addrs = [net_plane.net_addr(cl.grpc_addr(s)) for s in gone]
+    i = spread.on_lost[3]
+    spread.drop_caches()
+    trace.configure(enabled=True)
+    trace.reset()
+    quiet(spread)
+    calls0 = fetch_calls()
+    native0, stream0 = counted("sibling", "native"), counted("sibling", "stream")
+    with client._lock:
+        for addr in addrs:
+            client._no_plane[addr] = time.monotonic()
+    try:
+        with watched(spread, entry) as w:
+            status, body = spread.get(entry, i)
+    finally:
+        for addr in addrs:
+            client.reset(addr)
+    assert status == 200 and body == vol.body(i)
+    (root,) = spread.roots(vol.fid(i))
+    recons = reconstructions(root)
+    assert recons and len(w.calls) == len(recons)
+    on_gone = {sid for s in gone for sid in PLACED[s]}
+    for d in recons:
+        a = d["attrs"]
+        assert a["peer_fetch_path"] == "pool" and a["sibling_rows_remote"] == 8
+    calls = fetch_calls()
+    assert calls["pool"] - calls0["pool"] == len(recons) and calls["native"] == calls0["native"]
+    # the one call started six ranges a reconstruction; the streams
+    # carried rows of the two absent planes' shards and nothing else
+    assert grown(native0, "sibling", "native")["reads"] == 6 * len(recons)
+    assert 2 * len(recons) <= grown(stream0, "sibling", "stream")["reads"] <= 4 * len(recons)
+    streamed = [d for d in served_under(root) if d["attrs"].get("plane") != "native"]
+    assert streamed and {d["attrs"]["shard"] for d in streamed} <= on_gone
+
+
+def test_with_faults_armed_a_reconstructions_rows_take_the_pool_as_before(spread, disarmed):
+    """An armed fault registry (here one that bends nothing) keeps the
+    rows on the fetch pool, a thread a row, byte for byte: no one call,
+    and the counter says `pool`."""
+    vol, entry = spread.vol, 6
+    ev = spread.ev(entry)
+    seen = []
+    real = ev._sibling_matrix
+
+    def recording(shard_id, offset, size, prot, sp):
+        matrix, ids = real(shard_id, offset, size, prot, sp)
+        seen.append((offset, matrix.copy(), ids))
+        return matrix, ids
+
+    spread.drop_caches()
+    trace.configure(enabled=True)
+    trace.reset()
+    quiet(spread)
+    calls0 = fetch_calls()
+    i = spread.on_lost[4]
+    ev._sibling_matrix = recording
+    try:
+        with watched(spread, entry) as w, faults.injected(
+            "server.ec_shard_read", lambda ctx, data: data
+        ):
+            status, body = spread.get(entry, i)
+    finally:
+        del ev._sibling_matrix
+    assert status == 200 and body == vol.body(i)
+    assert w.calls == [] and len(w.submitted) >= 8 * len(seen) > 0
+    for offset, matrix, ids in seen:
+        for row, sid in zip(matrix, ids):
+            assert np.array_equal(row, spread.shards[sid][offset : offset + matrix.shape[1]])
+    (root,) = spread.roots(vol.fid(i))
+    for d in reconstructions(root):
+        assert d["attrs"]["peer_fetch_path"] == "pool"
+        rows = [c for c in d["children"] if c["op"] == "ec.peer_read"]
+        assert rows and all(c["thread"].startswith("ec-peer-fetch") for c in rows)
+    calls = fetch_calls()
+    assert calls["pool"] - calls0["pool"] == len(seen) and calls["native"] == calls0["native"]

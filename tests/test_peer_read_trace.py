@@ -14,6 +14,7 @@ seven it asks of server 1.
 
 from __future__ import annotations
 
+import contextlib
 import http.client
 import json
 import os
@@ -26,6 +27,7 @@ import pytest
 from ecbench import data as D
 from ecbench import harness
 from ecbench.spread_cluster import SpreadCluster
+from seaweedfs_tpu import faults
 from seaweedfs_tpu.ec import net_plane
 from seaweedfs_tpu.ec.placement import node_view_for
 from seaweedfs_tpu.pb import cluster_pb2 as pb
@@ -259,26 +261,55 @@ def test_a_reconstructions_rows_are_children_of_its_span_and_begin_at_their_subm
     (recon,) = [d for d in walk(root) if d["op"] == "ec.degraded_read" and "reconstruct" in d["stages"]]
     reads = [c for c in recon["children"] if c["op"] == "ec.peer_read"]
     a = recon["attrs"]
-    # (a fetch that found the matrix full before a thread took it up was
-    # cancelled, and is neither started nor a span)
-    assert 4 <= len(reads) == a["peer_fetches_started"] <= len(PLACED[1])
-    assert {r["attrs"]["shard"] for r in reads} <= set(PLACED[1])
-    waits = [iv for iv in recon["intervals"] if iv[0] == "peer_read"]
+    # every shard the peer holds is asked in ONE call, a span a range
+    assert a["peer_fetch_path"] == "native"
+    assert len(reads) == a["peer_fetches_started"] == len(PLACED[1])
+    assert {r["attrs"]["shard"] for r in reads} == set(PLACED[1])
+    (call,) = [iv for iv in recon["intervals"] if iv[0] == "peer_read"]
     for r in reads:
-        assert r["attrs"]["kind"] == "sibling" and r["thread"].startswith("ec-peer-fetch")
+        # on the GET's own worker: no pool between the reconstruction and its rows
+        assert r["attrs"]["kind"] == "sibling" and r["thread"] == recon["thread"]
         assert r["parent_span_id"] == recon["span_id"]
         assert recon["start_ns"] <= r["start_ns"] and r["end_ns"] <= recon["end_ns"]
+        # the queue begins with the call, inside the parent's `peer_read`,
+        # and is the check-outs ahead of the range's own: no thread to wait for
+        (queued,) = [iv for iv in r["intervals"] if iv[0] == "fetch_queue"]
+        assert queued[1] == r["start_ns"] and call[1] <= queued[1] <= call[2]
+        assert queued[3] == recon["thread"] and r["stages"]["fetch_queue"]["seconds"] < 0.05
         if r["attrs"].get("unused"):
             continue
         assert r["attrs"]["answered"] == 1 and r["attrs"]["plane"] == "native"
         end_to_end(r, READ_STAGES)
-        # the queue begins inside the parent's first `peer_read` wait,
-        # which is the submits, and ends in the fetch's own thread
-        (queued,) = [iv for iv in r["intervals"] if iv[0] == "fetch_queue"]
-        assert waits[0][1] <= queued[1] <= waits[0][2] and queued[3].startswith("ec-peer-fetch")
     answered = [r for r in reads if r["attrs"]["answered"] == 1]
     assert len(answered) >= a["sibling_rows_remote"] == 4
     assert a.get("peer_reads_outlived", 0) == sum(1 for r in reads if r["attrs"].get("unused"))
+
+
+def test_under_one_call_the_rows_book_one_return_on_their_reconstruction(armed, monkeypatch):
+    """The rows' native call returns to the interpreter once, and that
+    return is booked once, on the reconstruction's span (whose stage
+    `peer_read` is open around the call): the rows' spans book none."""
+    p, i = armed, armed.on_lost[0]
+    booked = []
+    real = native._stamped
+
+    def stamped(fn, *args):
+        timer = trace._open_stage.get()  # where book_return books it
+        span = timer.span if timer is not None else trace.current()
+        booked.append((fn.__name__, span.span_id if span is not None else None))
+        return real(fn, *args)
+
+    monkeypatch.setattr(native, "_stamped", stamped)
+    p.get(0, i)
+    root = p.root(i)
+    recons = [d for d in walk(root) if d["op"] == "ec.degraded_read" and "reconstruct" in d["stages"]]
+    assert recons
+    for recon in recons:
+        mine = [name for name, sid in booked if sid == recon["span_id"]]
+        assert mine.count("sn_plane_fetch") == 1
+        assert recon["attrs"]["interp_returns"] == len(mine)
+        for r in recon["children"]:
+            assert "interp_returns" not in r["attrs"] and "interp_wait_ns" not in r["attrs"]
 
 
 # ------------------------------------------------------ the holder's span
@@ -422,7 +453,7 @@ def test_disarmed_the_seams_get_null_and_a_get_makes_no_span(pair, disarmed, mon
     p = pair
     p.quiet()
     p.drop_caches()
-    stamps: dict[str, list] = {"sn_send_file": [], "sn_recv_into": []}
+    stamps: dict[str, list] = {"sn_send_file": [], "sn_plane_fetch": []}
     for name in stamps:
         real = getattr(native._lib, name)
         monkeypatch.setattr(
@@ -438,8 +469,9 @@ def test_disarmed_the_seams_get_null_and_a_get_makes_no_span(pair, disarmed, mon
         p.get(0, p.on_peer[0])
         p.get(0, p.on_lost[0])
         p.quiet()
-    assert len(stamps["sn_recv_into"]) >= 5 and len(stamps["sn_send_file"]) >= 5
-    assert set(stamps["sn_recv_into"]) == {None} and set(stamps["sn_send_file"]) == {None}
+    # the reader's side: an interval's call, and ONE for a reconstruction's rows
+    assert len(stamps["sn_plane_fetch"]) >= 2 and len(stamps["sn_send_file"]) >= 5
+    assert set(stamps["sn_plane_fetch"]) == {None} and set(stamps["sn_send_file"]) == {None}
     assert made == [] and trace.traces() == []
     # armed, each call gets a word to stamp
     trace.configure(enabled=True)
@@ -448,7 +480,7 @@ def test_disarmed_the_seams_get_null_and_a_get_makes_no_span(pair, disarmed, mon
     p.drop_caches()
     with holder_plane(p, "python"):
         p.get(0, p.on_peer[0])
-    assert stamps["sn_recv_into"] and all(isinstance(w, int) and w for w in stamps["sn_recv_into"])
+    assert stamps["sn_plane_fetch"] and all(isinstance(w, int) and w for w in stamps["sn_plane_fetch"])
     assert stamps["sn_send_file"] and all(isinstance(w, int) and w for w in stamps["sn_send_file"])
     assert made
 
@@ -495,8 +527,10 @@ def test_an_unread_answer_closes_unused_and_leaves_its_parents_document_well_for
     (late,) = [c for c in unused if c["attrs"]["shard"] == 13]
     a = late["attrs"]
     assert (a["shard"], a["unused"], a["answered"], a["kind"]) == (13, 1, 0, "sibling")
-    # it has the length it had when its parent closed, and nothing after
-    assert late["end_ns"] == recon["end_ns"] and late["start_ns"] >= recon["start_ns"]
+    # it ends where the call that asked for it returned without it, and
+    # has nothing after
+    (call,) = [iv for iv in recon["intervals"] if iv[0] == "peer_read"]
+    assert call[1] <= late["end_ns"] <= call[2] and late["start_ns"] >= recon["start_ns"]
     assert late["duration_s"] < 0.9  # not the second its holder slept
     assert set(late["stages"]) <= set(READ_STAGES)
     assert all(late["start_ns"] <= iv[1] <= iv[2] <= late["end_ns"] for iv in late["intervals"])
@@ -514,20 +548,24 @@ def test_an_unread_answer_closes_unused_and_leaves_its_parents_document_well_for
     assert len(reader.reads(obs)) == len(peer_reads(root)) - len(unused)
 
 
-@pytest.mark.parametrize("slow,regathers", [
-    ((11, 12, 13), 0),  # the four open rows' own fetches come first
-    ((7,), 1),          # a spare stands in for the first open row
-], ids=["rows_land_where_they_are_used", "a_spare_stands_in"])
-def test_matrix_regathers_counts_the_gathered_copy(armed, slow, regathers):
+@pytest.mark.parametrize("slow,regathers,pool", [
+    ((11, 12, 13), 0, False),  # the four open rows' own answers come first
+    ((7,), 1, True),           # on the pool, a spare stands in for the first open row
+    ((7,), 0, False),          # in one call the late answer is the spare: nothing is copied
+], ids=["rows_land_where_they_are_used", "a_spare_stands_in", "a_late_answer_is_the_spare"])
+def test_matrix_regathers_counts_the_gathered_copy(armed, slow, regathers, pool):
     p, i = armed, armed.on_lost[0]
-    with slow_shards(p, slow, 0.6):
+    # an armed fault registry (bending nothing) sends the rows to the pool
+    bend = faults.injected("server.ec_shard_read", lambda ctx, data: data)
+    with slow_shards(p, slow, 0.6), (bend if pool else contextlib.nullcontext()):
         p.get(0, i)
         root = p.root(i)
         p.quiet()
     (recon,) = [d for d in walk(root) if d["op"] == "ec.degraded_read" and "reconstruct" in d["stages"]]
     a = recon["attrs"]
     assert a["matrix_regathers"] == regathers and a["sibling_rows_remote"] == 4
-    assert a["peer_reads_outlived"] >= (1 if regathers else 0)
+    assert a["peer_reads_outlived"] >= (1 if regathers or len(slow) == 1 else 0)
+    assert a["peer_fetch_path"] == ("pool" if pool else "native")
     reader = harness.load_module("layers", "matrix_regather_share")
     assert reader.read(type("Obs", (), {"spans": [root]}), None) == 100.0 * regathers
 
