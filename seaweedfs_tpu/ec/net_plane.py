@@ -1237,18 +1237,11 @@ class NetPlaneClient:
         releases the connection. Whatever raises here has released it.
         With `exact` (the default) a server-side EOF clamp raises —
         range callers sized their landing buffer; `exact=False` accepts
-        the clamp (whole-shard fetches discover the size this way).
-
-        Under a read from a peer (`ec.peer_read`, whose thread has one
-        of `trace.TURN_STAGES` open) the check-out is that span's
-        `conn_checkout` and everything from the request's `sendall` to
-        the parsed header its `request_rtt`."""
-        trace.turn("conn_checkout")
+        the clamp (whole-shard fetches discover the size this way)."""
         s = self._checkout(addr)
         healthy = False
         try:
             meta = _encode_meta()
-            trace.turn("request_rtt")
             try:
                 s.sendall(
                     _REQ.pack(MAGIC, vid, sid, gen, off, size, len(meta))

@@ -621,9 +621,12 @@ def plane_fetch(
     (record i64[n, len(FETCH_REC)], granule CRCs u32[n, max_out], refusal
     messages u8[n, FETCH_MSG_MAX]). One GIL-releasing call."""
     n = len(fds)
-    assert len(requests) == n == len(sizes) and n > 0
+    if not (len(requests) == n == len(sizes) and n > 0):
+        raise ValueError("plane_fetch wants one request and size per socket")
     for d in dsts:
-        assert d.dtype == np.uint8 and d.ndim == 1 and d.flags.c_contiguous
+        # the C side writes caps = nbytes from each buffer's first byte
+        if not (d.dtype == np.uint8 and d.ndim == 1 and d.flags.c_contiguous):
+            raise ValueError("plane_fetch lands in 1-D C-contiguous uint8 buffers")
     rec = np.zeros((n, len(FETCH_REC)), np.int64)
     max_out = (max(sizes) // granule + 2) if granule else 1
     out_crcs = np.zeros((n, max_out), np.uint32)
